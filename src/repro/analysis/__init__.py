@@ -32,8 +32,7 @@ Layers:
   contracts     the contract-point registry (decode tick, bucketed prefill,
                 spec tick, generate loop) + the family x form x mode sweep
   hlo           post-SPMD HLO text analysis (collective bytes, cost /
-                memory summaries) — the compiled-artifact backend, formerly
-                ``repro.launch.hlo_analysis``
+                memory summaries) — the compiled-artifact backend
 
 Run the sweep: ``python -m repro.analysis --check`` (JSON report; CI gate).
 """

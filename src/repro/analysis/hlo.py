@@ -1,7 +1,6 @@
 """Post-SPMD HLO analysis: collective-bytes extraction + cost decomposition.
 
-The compiled-artifact backend of ``repro.analysis`` (formerly
-``repro.launch.hlo_analysis``; that module re-exports from here). The jaxpr
+The compiled-artifact backend of ``repro.analysis``. The jaxpr
 passes in ``repro.analysis.passes`` see graphs BEFORE compilation; this
 module reads what XLA actually produced.
 

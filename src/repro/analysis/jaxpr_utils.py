@@ -11,10 +11,11 @@ from __future__ import annotations
 from typing import Dict, Iterator, List, Tuple
 
 import jax.numpy as jnp
-from jax.core import ClosedJaxpr, Jaxpr
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 __all__ = ["subjaxprs", "as_jaxpr", "iter_eqns", "eqn_label",
-           "float_shapes_outside_pallas", "find_pallas_eqns"]
+           "pallas_kernel_name", "float_shapes_outside_pallas",
+           "find_pallas_eqns"]
 
 
 def subjaxprs(val) -> Iterator[Jaxpr]:
@@ -55,14 +56,22 @@ def _aval_str(aval) -> str:
     return str(aval)
 
 
+def pallas_kernel_name(eqn) -> str:
+    """The kernel function's name for a pallas_call eqn: the explicit
+    ``name=`` if one was given, else the traced kernel body's function."""
+    if eqn.params.get("name"):
+        return eqn.params["name"]
+    src = getattr(eqn.params["jaxpr"].debug_info, "func_src_info", "") or ""
+    return src.split(" at ")[0]
+
+
 def eqn_label(eqn) -> str:
     """Short human label naming an equation: primitive -> result avals."""
     outs = ", ".join(_aval_str(v.aval) for v in eqn.outvars
                      if hasattr(v, "aval"))
     name = eqn.primitive.name
     if name == "pallas_call":
-        info = eqn.params.get("name_and_src_info")
-        kname = getattr(info, "name", None) or eqn.params.get("name", "")
+        kname = pallas_kernel_name(eqn)
         name = f"pallas_call[{kname}]" if kname else name
     return f"{name} -> {outs}" if outs else name
 

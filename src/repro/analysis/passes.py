@@ -96,18 +96,24 @@ def check_no_quadratic_scores(jaxpr, t: int, s: int, *, min_rank: int = 2,
 # placement/transfer ops. A jitted serving tick containing one of these
 # cannot be async — it re-introduces the per-token host sync.
 _TRANSFER_PRIMS = ("device_put", "infeed", "outfeed")
+# callbacks traced under a primitive of their own: jax.debug.print is a
+# debug_callback that appears in the jaxpr as ``debug_print``
+_CALLBACK_ALIASES = {"debug_print": "debug_callback"}
 
 
 def check_no_host_callback(jaxpr) -> List[Violation]:
     out = []
     for eqn in iter_eqns(jaxpr, descend_pallas=True):
         name = eqn.primitive.name
-        if "callback" in name or name in _TRANSFER_PRIMS:
-            out.append(Violation(
-                "no_host_callback",
-                f"host-sync primitive '{name}' inside a jitted serving "
-                f"graph (breaks the async no-per-token-sync contract)",
-                eqn=eqn_label(eqn)))
+        kind = _CALLBACK_ALIASES.get(name, name)
+        if not ("callback" in kind or kind in _TRANSFER_PRIMS):
+            continue
+        what = f"'{name}'" + (f" (a {kind})" if kind != name else "")
+        out.append(Violation(
+            "no_host_callback",
+            f"host-sync primitive {what} inside a jitted serving "
+            f"graph (breaks the async no-per-token-sync contract)",
+            eqn=eqn_label(eqn)))
     return out
 
 
@@ -196,9 +202,12 @@ def check_donation(fn, args: Sequence, donate_argnums: Sequence[int], *,
                    point: str = "") -> List[Violation]:
     """Lower a FRESH ``jax.jit(fn, donate_argnums=...)`` over the given
     (possibly abstract) args and require the donation to take: every
-    "donated buffers were not usable" warning is a violation (the aliasing
-    fallback path — the tick would silently copy the whole cache), and at
-    least one input must actually alias an output in the lowered module.
+    donated input the lowering could not pair with an output of the same
+    shape and dtype is a violation (the aliasing fallback path — the tick
+    would silently copy the whole cache). The lowering leaves such inputs
+    in the module as ``jax.buffer_donor``, or warns "donated buffers were
+    not usable" where it drops them outright. At least one input must
+    actually alias an output in the lowered module.
     Building a private jit keeps the check from polluting the caller's jit
     caches (trace-count budgets stay honest)."""
     label = point or getattr(fn, "__name__", "fn")
@@ -217,6 +226,12 @@ def check_donation(fn, args: Sequence, donate_argnums: Sequence[int], *,
             viols.append(Violation(
                 "donation",
                 f"{label}: donation fell back to a copy — {msg[:300]}"))
+    unpaired = text.count("jax.buffer_donor = true")
+    if unpaired:
+        viols.append(Violation(
+            "donation",
+            f"{label}: donation fell back to a copy — {unpaired} donated "
+            f"input(s) alias no output of the same shape and dtype"))
     if "tf.aliasing_output" not in text:
         viols.append(Violation(
             "donation",

@@ -17,6 +17,8 @@ from typing import Any, Dict, List
 
 import jax.numpy as jnp
 
+from repro.analysis.jaxpr_utils import pallas_kernel_name
+
 __all__ = ["DEFAULT_VMEM_BUDGET", "pallas_vmem_estimate"]
 
 # one TPU core's VMEM (~16 MiB): the hard on-chip ceiling the double-
@@ -62,7 +64,6 @@ def pallas_vmem_estimate(eqn) -> Dict[str, Any]:
         else:
             vmem += b * mult
         refs.append((kind, tuple(aval.shape), jnp.dtype(aval.dtype).name, b))
-    info = eqn.params.get("name_and_src_info")
-    name = getattr(info, "name", None) or eqn.params.get("name", "pallas_call")
-    return {"name": name, "grid": tuple(gm.grid), "vmem_bytes": int(vmem),
+    return {"name": pallas_kernel_name(eqn) or "pallas_call",
+            "grid": tuple(gm.grid), "vmem_bytes": int(vmem),
             "smem_bytes": int(smem), "refs": refs}

@@ -28,7 +28,10 @@ of the paper's on-chip dataflow (weights/scores never leave the chip):
 
 Grid: (B/bm, KV, S/bs), S innermost ("arbitrary" — sequential accumulation
 into the scratch carry); B and KV are parallel. One q block is (bm, G, D)
-for a single kv head (GQA group G = H // KV), K/V blocks are (bm, bs, D).
+for a single kv head (GQA group G = H // KV), K/V blocks are (bm, bs, D):
+kv head j read as lane block j of the cache's free (B, S, KV*D) view, so
+the block's trailing dims are TPU tiles (``D`` must be a multiple of 128
+on the chip; interpret mode takes any ``D``).
 
 Numerics match ``attn_decode_ref`` (ref.py): fp32 scores and softmax
 statistics, probabilities cast to the compute dtype for PV, fp32
@@ -54,9 +57,10 @@ def _kernel(lmax_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, len_ref, o_ref,
             acc_ref, m_ref, l_ref, *, bs: int, quantized: bool):
     """One (bm, G) q tile against one (bm, bs) cache block.
 
-    Refs: q (bm, 1, G, D); k/v (bm, bs, 1, D); ks/vs (bm, bs) fp32 scales
-    (None when not quantized); len (bm, 1) int32; out (bm, 1, G, D).
-    Scratch: acc (bm, G, D) fp32; m/l (bm, G) fp32 — the online-softmax
+    Refs: q (bm, 1, G, D); k/v (bm, bs, D) — one kv head's lanes of the
+    (B, S, KV*D) cache view; ks/vs (bm, 1, bs) fp32 scales (None when not
+    quantized); len (bm, 1, 1) int32; out (bm, 1, G, D).
+    Scratch: acc (bm, G, D) fp32; m/l (bm, G, 1) fp32 — the online-softmax
     carry, valid across the innermost S grid dimension.
     """
     i = pl.program_id(0)
@@ -74,41 +78,39 @@ def _kernel(lmax_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, len_ref, o_ref,
     @pl.when(start < lmax_ref[i])
     def _compute():
         q = q_ref[:, 0]                                 # (bm, G, D)
-        k = k_ref[:, :, 0]                              # (bm, bs, D)
+        k = k_ref[...]                                  # (bm, bs, D)
         sc = jax.lax.dot_general(                       # (bm, G, bs) fp32
             q, k.astype(q.dtype),
             dimension_numbers=(((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
         if quantized:
-            sc = sc * ks_ref[...].astype(jnp.float32)[:, None, :]
+            sc = sc * ks_ref[...]                       # (bm, 1, bs) bcast
         pos = start + jax.lax.broadcasted_iota(
-            jnp.int32, (sc.shape[0], bs), 1)            # (bm, bs)
-        valid = pos < len_ref[...]                      # len (bm, 1) bcast
-        sc = jnp.where(valid[:, None, :], sc, NEG_INF)
+            jnp.int32, (sc.shape[0], 1, bs), 2)         # (bm, 1, bs)
+        sc = jnp.where(pos < len_ref[...], sc, NEG_INF)  # len (bm, 1, 1)
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1))
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
         # `alive` guards rows with no valid position yet: m_new == NEG_INF
         # there, and exp(sc - m_new) would be exp(0) = 1 for masked slots
-        alive = m_new > NEG_INF / 2
-        p = jnp.where(alive[..., None],
-                      jnp.exp(sc - m_new[..., None]), 0.0)  # (bm, G, bs)
+        alive = m_new > NEG_INF / 2                     # (bm, G, 1)
+        p = jnp.where(alive, jnp.exp(sc - m_new), 0.0)  # (bm, G, bs)
         corr = jnp.where(alive, jnp.exp(m_prev - m_new), 1.0)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-        v = v_ref[:, :, 0]                              # (bm, bs, D)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        v = v_ref[...]                                  # (bm, bs, D)
         if quantized:
-            p = (p * vs_ref[...].astype(jnp.float32)[:, None, :]).astype(q.dtype)
+            p = (p * vs_ref[...]).astype(q.dtype)
             v = v.astype(q.dtype)
         else:
             p = p.astype(v.dtype)
-        acc_ref[...] = acc_ref[...] * corr[..., None] + jax.lax.dot_general(
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             p, v, dimension_numbers=(((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(s_blk == pl.num_programs(2) - 1)
     def _flush():
-        l = jnp.maximum(l_ref[...], 1e-30)              # (bm, G)
-        o_ref[...] = (acc_ref[...] / l[..., None])[:, None].astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[...], 1e-30)              # (bm, G, 1)
+        o_ref[:, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -154,29 +156,33 @@ def attn_decode_pallas(q: jnp.ndarray, k_cache: jnp.ndarray,
     # valid block — same index as the previous grid step => the pipeline
     # skips the HBM->VMEM copy (the "don't stream the whole ring" part)
     lmax = jnp.max(lens.reshape(nb, bm), axis=1)
-    len2 = lens[:, None]
+    len3 = lens[:, None, None]
+    # free row-major views whose trailing block dims the TPU accepts:
+    # kv head j of the (B, S, KV, D) cache is lane block j of (B, S, KV*D)
+    k_cache = k_cache.reshape(bp, sp, kv * d)
+    v_cache = v_cache.reshape(bp, sp, kv * d)
 
     def kv_idx(i, j, s_blk, lmax_ref):
         nblk = jnp.maximum((lmax_ref[i] + bs - 1) // bs, 1)
-        return (i, jnp.minimum(s_blk, nblk - 1), j, 0)
+        return (i, jnp.minimum(s_blk, nblk - 1), j)
 
     def sc_idx(i, j, s_blk, lmax_ref):
         nblk = jnp.maximum((lmax_ref[i] + bs - 1) // bs, 1)
-        return (i, jnp.minimum(s_blk, nblk - 1))
+        return (i, 0, jnp.minimum(s_blk, nblk - 1))
 
     in_specs = [
         pl.BlockSpec((bm, 1, g, d), lambda i, j, s_blk, lmax: (i, j, 0, 0)),
-        pl.BlockSpec((bm, bs, 1, d), kv_idx),
-        pl.BlockSpec((bm, bs, 1, d), kv_idx),
+        pl.BlockSpec((bm, bs, d), kv_idx),
+        pl.BlockSpec((bm, bs, d), kv_idx),
     ]
     args = [q, k_cache, v_cache]
     if quantized:
-        in_specs += [pl.BlockSpec((bm, bs), sc_idx),
-                     pl.BlockSpec((bm, bs), sc_idx)]
-        args += [k_scale, v_scale]
+        in_specs += [pl.BlockSpec((bm, 1, bs), sc_idx),
+                     pl.BlockSpec((bm, 1, bs), sc_idx)]
+        args += [k_scale[:, None], v_scale[:, None]]
     in_specs.append(
-        pl.BlockSpec((bm, 1), lambda i, j, s_blk, lmax: (i, 0)))
-    args.append(len2)
+        pl.BlockSpec((bm, 1, 1), lambda i, j, s_blk, lmax: (i, 0, 0)))
+    args.append(len3)
 
     if quantized:
         kernel = functools.partial(_kernel, bs=bs, quantized=True)
@@ -195,8 +201,8 @@ def attn_decode_pallas(q: jnp.ndarray, k_cache: jnp.ndarray,
                                lambda i, j, s_blk, lmax: (i, j, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((bm, g, d), jnp.float32),        # acc
-            pltpu.VMEM((bm, g), jnp.float32),           # running max
-            pltpu.VMEM((bm, g), jnp.float32),           # running sum
+            pltpu.VMEM((bm, g, 1), jnp.float32),        # running max
+            pltpu.VMEM((bm, g, 1), jnp.float32),        # running sum
         ],
     )
     out = pl.pallas_call(

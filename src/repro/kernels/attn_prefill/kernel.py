@@ -31,7 +31,9 @@ score storage and it never leaves VMEM:
 
 Grid: (B, T/bt, KV, S/bs), S innermost ("arbitrary" — sequential
 accumulation into the scratch carry). One q block is (bt, G, D) for a
-single kv head; K/V blocks are (bs, D).
+single kv head; K/V blocks are (bs, D), kv head j read as lane block j of
+the free (B, S, KV*D) view (``D`` must be a multiple of 128 on the chip;
+interpret mode takes any ``D``).
 
 Numerics match ``attn_prefill_ref`` (ref.py): fp32 scores and softmax
 statistics, probabilities cast to the compute dtype for PV, fp32
@@ -59,9 +61,10 @@ def _kernel(hmax_ref, lmin_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
             quantized: bool):
     """One (bt, G) q tile of one batch row against one (bs,) K/V block.
 
-    Refs: q (1, bt, 1, G, D); k/v (1, bs, 1, D); ks/vs (1, bs) fp32 scales
-    (None when not quantized); lo/hi (1, bt) int32; out (1, bt, 1, G, D).
-    Scratch: acc (bt, G, D) fp32; m/l (bt, G) fp32 — the online-softmax
+    Refs: q (1, bt, 1, G, D); k/v (1, bs, D) — one kv head's lanes of the
+    (B, S, KV*D) view; ks/vs (1, 1, bs) fp32 scales (None when not
+    quantized); lo/hi (1, bt, 1, 1) int32; out (1, bt, 1, G, D).
+    Scratch: acc (bt, G, D) fp32; m/l (bt, G, 1) fp32 — the online-softmax
     carry, valid across the innermost S grid dimension.
     """
     i = pl.program_id(0)
@@ -80,43 +83,40 @@ def _kernel(hmax_ref, lmin_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
     @pl.when((start < hmax_ref[i, t]) & (start + bs > lmin_ref[i, t]))
     def _compute():
         q = q_ref[0, :, 0]                              # (bt, G, D)
-        k = k_ref[0, :, 0]                              # (bs, D)
+        k = k_ref[0]                                    # (bs, D)
         sc = jax.lax.dot_general(                       # (bt, G, bs) fp32
             q, k.astype(q.dtype),
             dimension_numbers=(((2,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         if quantized:
-            sc = sc * ks_ref[0].astype(jnp.float32)[None, None, :]
+            sc = sc * ks_ref[0]                         # (1, bs) bcast
         pos = start + jax.lax.broadcasted_iota(
-            jnp.int32, (sc.shape[0], bs), 1)            # (bt, bs)
-        valid = (pos < hi_ref[0][:, None]) & (pos >= lo_ref[0][:, None])
-        sc = jnp.where(valid[:, None, :], sc, NEG_INF)
+            jnp.int32, (sc.shape[0], 1, bs), 2)         # (bt, 1, bs)
+        valid = (pos < hi_ref[0]) & (pos >= lo_ref[0])  # lo/hi (bt, 1, 1)
+        sc = jnp.where(valid, sc, NEG_INF)
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1))
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
         # `alive` guards rows with no valid position yet: m_new == NEG_INF
         # there, and exp(sc - m_new) would be exp(0) = 1 for masked slots
-        alive = m_new > NEG_INF / 2
-        p = jnp.where(alive[..., None],
-                      jnp.exp(sc - m_new[..., None]), 0.0)  # (bt, G, bs)
+        alive = m_new > NEG_INF / 2                     # (bt, G, 1)
+        p = jnp.where(alive, jnp.exp(sc - m_new), 0.0)  # (bt, G, bs)
         corr = jnp.where(alive, jnp.exp(m_prev - m_new), 1.0)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-        v = v_ref[0, :, 0]                              # (bs, D)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        v = v_ref[0]                                    # (bs, D)
         if quantized:
-            p = (p * vs_ref[0].astype(jnp.float32)[None, None, :]
-                 ).astype(q.dtype)
+            p = (p * vs_ref[0]).astype(q.dtype)
             v = v.astype(q.dtype)
         else:
             p = p.astype(v.dtype)
-        acc_ref[...] = acc_ref[...] * corr[..., None] + jax.lax.dot_general(
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             p, v, dimension_numbers=(((2,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(s_blk == pl.num_programs(3) - 1)
     def _flush():
-        l = jnp.maximum(l_ref[...], 1e-30)              # (bt, G)
-        o_ref[...] = (acc_ref[...] / l[..., None]
-                      )[None, :, None].astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[...], 1e-30)              # (bt, G, 1)
+        o_ref[0, :, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -173,29 +173,35 @@ def attn_prefill_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                            nhi - 1)
 
     def kv_idx(i, tt, j, s_blk, hmax_ref, lmin_ref):
-        return (i, _sblk(i, tt, s_blk, hmax_ref, lmin_ref), j, 0)
+        return (i, _sblk(i, tt, s_blk, hmax_ref, lmin_ref), j)
 
     def sc_idx(i, tt, j, s_blk, hmax_ref, lmin_ref):
-        return (i, _sblk(i, tt, s_blk, hmax_ref, lmin_ref))
+        return (i, 0, _sblk(i, tt, s_blk, hmax_ref, lmin_ref))
 
     def q_idx(i, tt, j, s_blk, hmax_ref, lmin_ref):
         return (i, tt, j, 0, 0)
 
     def b_idx(i, tt, j, s_blk, hmax_ref, lmin_ref):
-        return (i, tt)
+        return (i, tt, 0, 0)
 
+    # free row-major views whose trailing block dims the TPU accepts: kv
+    # head j of (B, S, KV, D) is lane block j of (B, S, KV*D); scales and
+    # bounds get unit trailing dims so each block spans the full array dim
+    k = k.reshape(b, sp, kv * d)
+    v = v.reshape(b, sp, kv * d)
     in_specs = [
         pl.BlockSpec((1, bt, 1, g, d), q_idx),
-        pl.BlockSpec((1, bs, 1, d), kv_idx),
-        pl.BlockSpec((1, bs, 1, d), kv_idx),
+        pl.BlockSpec((1, bs, d), kv_idx),
+        pl.BlockSpec((1, bs, d), kv_idx),
     ]
     args = [q, k, v]
     if quantized:
-        in_specs += [pl.BlockSpec((1, bs), sc_idx),
-                     pl.BlockSpec((1, bs), sc_idx)]
-        args += [k_scale, v_scale]
-    in_specs += [pl.BlockSpec((1, bt), b_idx), pl.BlockSpec((1, bt), b_idx)]
-    args += [lo, hi]
+        in_specs += [pl.BlockSpec((1, 1, bs), sc_idx),
+                     pl.BlockSpec((1, 1, bs), sc_idx)]
+        args += [k_scale[:, None], v_scale[:, None]]
+    in_specs += [pl.BlockSpec((1, bt, 1, 1), b_idx),
+                 pl.BlockSpec((1, bt, 1, 1), b_idx)]
+    args += [lo[..., None, None], hi[..., None, None]]
 
     if quantized:
         kernel = functools.partial(_kernel, bs=bs, quantized=True)
@@ -213,8 +219,8 @@ def attn_prefill_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         out_specs=pl.BlockSpec((1, bt, 1, g, d), q_idx),
         scratch_shapes=[
             pltpu.VMEM((bt, g, d), jnp.float32),        # acc
-            pltpu.VMEM((bt, g), jnp.float32),           # running max
-            pltpu.VMEM((bt, g), jnp.float32),           # running sum
+            pltpu.VMEM((bt, g, 1), jnp.float32),        # running max
+            pltpu.VMEM((bt, g, 1), jnp.float32),        # running sum
         ],
     )
     out = pl.pallas_call(

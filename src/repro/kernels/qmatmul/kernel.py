@@ -24,9 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed across jax releases (TPUCompilerParams <= 0.4.x < CompilerParams)
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 __all__ = ["qmatmul_pallas"]
 
 
@@ -55,9 +52,12 @@ def qmatmul_pallas(x: jnp.ndarray, w_q: jnp.ndarray, delta: jnp.ndarray,
     m, k = x.shape
     k2, n = w_q.shape
     assert k == k2, (x.shape, w_q.shape)
-    delta = jnp.broadcast_to(jnp.asarray(delta, jnp.float32), (n,))
-    bias = (jnp.zeros((n,), jnp.float32) if bias is None
-            else jnp.broadcast_to(jnp.asarray(bias, jnp.float32), (n,)))
+    # (1, N) rows: Mosaic tiles a 1-D operand differently from XLA
+    delta = jnp.broadcast_to(jnp.asarray(delta, jnp.float32).reshape(-1),
+                             (n,)).reshape(1, n)
+    bias = (jnp.zeros((1, n), jnp.float32) if bias is None
+            else jnp.broadcast_to(jnp.asarray(bias, jnp.float32).reshape(-1),
+                                  (n,)).reshape(1, n))
     out_dtype = out_dtype or x.dtype
     bm, bn, bk = min(bm, m), min(bn, n), min(bk, k)
     # pad to block multiples (zeros contribute nothing to the accumulation)
@@ -67,8 +67,8 @@ def qmatmul_pallas(x: jnp.ndarray, w_q: jnp.ndarray, delta: jnp.ndarray,
     if (kp, np_) != (k, n):
         w_q = jnp.pad(w_q, ((0, kp - k), (0, np_ - n)))
     if np_ != n:
-        delta = jnp.pad(delta, (0, np_ - n))
-        bias = jnp.pad(bias, (0, np_ - n))
+        delta = jnp.pad(delta, ((0, 0), (0, np_ - n)))
+        bias = jnp.pad(bias, ((0, 0), (0, np_ - n)))
 
     grid = (mp // bm, np_ // bn, kp // bk)
     out = pl.pallas_call(
@@ -77,13 +77,13 @@ def qmatmul_pallas(x: jnp.ndarray, w_q: jnp.ndarray, delta: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w_q, delta, bias)

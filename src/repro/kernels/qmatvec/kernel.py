@@ -28,9 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed across jax releases (TPUCompilerParams <= 0.4.x < CompilerParams)
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 __all__ = ["qmatvec_pallas", "FIELDS"]
 
 FIELDS = 10  # 3-bit fields per int32 container word
@@ -79,9 +76,12 @@ def qmatvec_pallas(x: jnp.ndarray, w_packed: jnp.ndarray, delta: jnp.ndarray,
     kp, n = w_packed.shape
     assert kp * FIELDS >= k, (x.shape, w_packed.shape)
     out_dtype = out_dtype or x.dtype
-    delta = jnp.broadcast_to(jnp.asarray(delta, jnp.float32), (n,))
-    bias = (jnp.zeros((n,), jnp.float32) if bias is None
-            else jnp.broadcast_to(jnp.asarray(bias, jnp.float32), (n,)))
+    # (1, N) rows: Mosaic tiles a 1-D operand differently from XLA
+    delta = jnp.broadcast_to(jnp.asarray(delta, jnp.float32).reshape(-1),
+                             (n,)).reshape(1, n)
+    bias = (jnp.zeros((1, n), jnp.float32) if bias is None
+            else jnp.broadcast_to(jnp.asarray(bias, jnp.float32).reshape(-1),
+                                  (n,)).reshape(1, n))
 
     bm = min(bm, m)
     bn = min(bn, n)
@@ -91,8 +91,8 @@ def qmatvec_pallas(x: jnp.ndarray, w_packed: jnp.ndarray, delta: jnp.ndarray,
     kppad = -(-kp // bkp) * bkp
     if npad != n:
         w_packed = jnp.pad(w_packed, ((0, 0), (0, npad - n)))
-        delta = jnp.pad(delta, (0, npad - n))
-        bias = jnp.pad(bias, (0, npad - n))
+        delta = jnp.pad(delta, ((0, 0), (0, npad - n)))
+        bias = jnp.pad(bias, ((0, 0), (0, npad - n)))
     if kppad != kp:
         w_packed = jnp.pad(w_packed, ((0, kppad - kp), (0, 0)))
     xk = kppad * FIELDS
@@ -105,13 +105,13 @@ def qmatvec_pallas(x: jnp.ndarray, w_packed: jnp.ndarray, delta: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((bm, bkp * FIELDS), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bkp, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mpad, npad), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w_packed, delta, bias)

@@ -12,6 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.qmatmul.ops import on_tpu
 from repro.kernels.qmatvec.kernel import qmatvec_pallas
 
 __all__ = ["qmatvec"]
@@ -27,7 +28,7 @@ def qmatvec(x: jnp.ndarray, w_packed: jnp.ndarray, delta: jnp.ndarray, *,
     per-channel delta rescale, in fp32); ``out_dtype`` overrides the output
     dtype (one cast from the fp32 accumulator)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     lead = x.shape[:-1]
     x2 = x.reshape(-1, k)
     out = qmatvec_pallas(x2, w_packed, delta, bias, out_dtype=out_dtype,

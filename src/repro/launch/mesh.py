@@ -10,27 +10,19 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["compat_make_mesh", "make_production_mesh", "make_host_mesh"]
+__all__ = ["make_production_mesh", "make_host_mesh"]
 
 
-def compat_make_mesh(shape, axes, *, devices=None):
-    """``jax.make_mesh`` across jax versions.
-
-    ``axis_types`` (and ``jax.sharding.AxisType``) only exist in jax >= 0.5;
-    on those versions we pin every axis to ``Auto`` — the pre-0.5 default —
-    so mesh semantics are identical either way.
-    """
-    kw = {} if devices is None else {"devices": devices}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        kw["axis_types"] = (axis_type.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kw)
+def _auto_mesh(shape, axes):
+    # Auto axes: shardings propagate from the rules in distributed/sharding
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -38,4 +30,4 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = min(model, n // data)
-    return compat_make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))

@@ -34,11 +34,15 @@ import jax
 from repro.configs import get_config, reduced
 from repro.core import quant_dense
 from repro.core.precision import FLOAT, W3A8
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
 from repro.serving.engine import ServingEngine
 
+# mixed prompt lengths: exercises the length-bucketed batched admission
+PROMPT_LENS = (4, 8, 5, 12, 3, 16, 7, 9)
 
-def main():
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -113,8 +117,13 @@ def main():
     ap.add_argument("--golden-dir", default=None,
                     help="also persist the golden weight copy + CRC "
                          "manifest here (checkpoint.integrity)")
-    args = ap.parse_args()
+    return ap
 
+
+def build_engine(args, **engine_kw) -> ServingEngine:
+    """Seeded init, export to the serve form ``args`` asks for, and the
+    engine. ``engine_kw`` overrides ServingEngine keywords (e.g. the
+    degradation ladder)."""
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
@@ -135,48 +144,57 @@ def main():
     else:
         policy = FLOAT
 
-    eng = ServingEngine(params, cfg, policy=policy, slots=args.slots,
-                        max_len=64 + args.max_new + args.spec_k,
-                        temperature=args.temperature, eos_id=args.eos_id,
-                        matmul_mode=args.matmul_mode,
-                        attn_mode=args.attn_mode,
-                        kv_bits=8 if args.kv8 else None,
-                        spec_k=args.spec_k, draft_params=draft_params,
-                        draft_cfg=draft_cfg,
-                        queue_limit=args.queue_limit,
-                        shed_policy=args.shed_policy,
-                        default_deadline=args.deadline,
-                        preempt_after=args.preempt,
-                        max_ticks=args.max_ticks,
-                        snapshot_dir=args.snapshot_dir,
-                        snapshot_every=args.snapshot_every,
-                        journal=args.journal,
-                        integrity_every=args.integrity_every,
-                        golden_dir=args.golden_dir)
+    kw = dict(slots=args.slots, max_len=64 + args.max_new + args.spec_k,
+              temperature=args.temperature, eos_id=args.eos_id,
+              matmul_mode=args.matmul_mode, attn_mode=args.attn_mode,
+              kv_bits=8 if args.kv8 else None,
+              spec_k=args.spec_k, draft_params=draft_params,
+              draft_cfg=draft_cfg,
+              queue_limit=args.queue_limit, shed_policy=args.shed_policy,
+              default_deadline=args.deadline, preempt_after=args.preempt,
+              max_ticks=args.max_ticks,
+              snapshot_dir=args.snapshot_dir,
+              snapshot_every=args.snapshot_every, journal=args.journal,
+              integrity_every=args.integrity_every,
+              golden_dir=args.golden_dir)
+    kw.update(engine_kw)
+    return ServingEngine(params, cfg, policy=policy, **kw)
+
+
+def mixed_prompts(requests: int) -> list:
+    """The CLI's ``requests`` prompts, cycling through ``PROMPT_LENS``."""
+    return [[(1 + i + j) % 50 + 1
+             for j in range(PROMPT_LENS[i % len(PROMPT_LENS)])]
+            for i in range(requests)]
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    eng = build_engine(args)
     if args.resume:
         stats = eng.recover()
         print(f"recovered: snapshot step {stats['restored_step']}, "
               f"{stats['replayed_events']} journal events replayed, "
               f"{stats['resubmitted']} requests resubmitted")
-    # mixed prompt lengths: exercises the length-bucketed batched admission
-    lens = [4, 8, 5, 12, 3, 16, 7, 9]
     t0 = time.time()
-    for i in range(args.requests):
-        plen = lens[i % len(lens)]
-        eng.submit([(1 + i + j) % 50 + 1 for j in range(plen)],
-                   max_new=args.max_new)
+    for prompt in mixed_prompts(args.requests):
+        eng.submit(prompt, max_new=args.max_new)
     done = eng.run_all()
     dt = time.time() - t0
     toks = sum(len(r.out) for r in done)
     spec = (f", spec accept rate {eng.spec_accept_rate:.2f} "
             f"(K={args.spec_k})" if args.spec_k else "")
     print(f"{len(done)} requests, {toks} tokens in {dt:.1f}s "
-          f"({toks / dt:.1f} tok/s on CPU), "
+          f"({toks / dt:.1f} tok/s on {jax.devices()[0].device_kind}, "
+          f"compile included), "
           f"{eng.decode_calls} batched decode ticks "
           f"({toks / max(eng.decode_calls, 1):.2f} tok/tick), "
           f"{eng.prefill_calls} bucketed prefill calls "
           f"({len(done) / max(eng.prefill_calls, 1):.2f} req/prefill)"
           f"{spec}")
+    if eng.fallback_events:
+        print(f"fallback_events (tick, ladder step): {eng.fallback_events}")
     if (args.queue_limit is not None or args.deadline is not None
             or args.preempt is not None):
         by_status: dict = {}

@@ -21,6 +21,7 @@ from repro.data.pipeline import HostLoader
 from repro.data.synthetic import lm_batch
 from repro.distributed.context import sharding_rules
 from repro.distributed import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import get_model
 from repro.training.loop import Trainer, make_train_step
@@ -40,6 +41,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--lr", type=float, default=1e-3)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -1,8 +1,7 @@
 """HLO collective parser + roofline reconstruction math.
 
 The parser lives in ``repro.analysis.hlo`` (the static-analysis
-subsystem's compiled-artifact backend); ``repro.launch.hlo_analysis``
-stays importable as a compat shim — both are exercised here."""
+subsystem's compiled-artifact backend)."""
 import numpy as np
 
 from benchmarks import roofline as rl
@@ -42,15 +41,6 @@ def test_shape_bytes_packed_dtypes():
     assert _shape_bytes("u8[100]") == 100
     assert _shape_bytes("f8e4m3fn[32,32]") == 32 * 32
     assert _shape_bytes("f8e5m2[64]") == 64
-
-
-def test_hlo_analysis_compat_shim():
-    """repro.launch.hlo_analysis re-exports the moved implementation."""
-    from repro.analysis import hlo
-    from repro.launch import hlo_analysis
-    assert hlo_analysis.collective_bytes is hlo.collective_bytes
-    assert hlo_analysis._shape_bytes is hlo._shape_bytes
-    assert hlo_analysis.DTYPE_BYTES is hlo.DTYPE_BYTES
 
 
 def test_collective_parser_counts_operands():

@@ -14,15 +14,15 @@ from jax.sharding import PartitionSpec as P
 from repro import checkpoint as ckpt_lib
 from repro.configs import get_config, reduced, ShapeConfig
 from repro.distributed import sharding as shd
-from repro.launch.mesh import compat_make_mesh
 from repro.models import get_model
 
 cfg = reduced(get_config("qwen2-1.5b"), layers=2, d_model=64, vocab=128)
 mod = get_model(cfg)
 params = mod.init(jax.random.PRNGKey(0), cfg)
 
-mesh_a = compat_make_mesh((2, 4), ("data", "model"))
-mesh_b = compat_make_mesh((4, 2), ("data", "model"))
+AUTO = (jax.sharding.AxisType.Auto,) * 2
+mesh_a = jax.make_mesh((2, 4), ("data", "model"), axis_types=AUTO)
+mesh_b = jax.make_mesh((4, 2), ("data", "model"), axis_types=AUTO)
 
 # place params on mesh A, checkpoint, restore onto mesh B
 specs_a = shd.param_specs(cfg, params, mesh_a)

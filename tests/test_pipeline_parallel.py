@@ -12,9 +12,9 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np
 from repro.distributed.pipeline import pipeline_apply
-from repro.launch.mesh import compat_make_mesh
 
-mesh = compat_make_mesh((4,), ("stage",))
+mesh = jax.make_mesh((4,), ("stage",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 key = jax.random.PRNGKey(0)
 S, M, B, D = 4, 6, 2, 8
 ws = jax.random.normal(key, (S, D, D)) * 0.3

@@ -1,0 +1,111 @@
+"""One general generator of serving traffic, read from a mix's data file.
+
+A mix (``bench/traffic/<name>.json``) gives the loop and the length
+distributions:
+
+    {"loop": "open", "rate_per_s": 6.0,
+     "prompt_len": {"median": 64, "sigma": 0.8, "min": 16, "max": 256},
+     "output_len": {"median": 256, "sigma": 0.7, "min": 32, "max": 1024}}
+
+    {"loop": "closed", "clients": 16, "pool": 64, "prompt_len": ..., ...}
+
+Lengths are log-normal (``median``, ``sigma`` of the log) clipped to
+[``min``, ``max``]. An open loop sends requests at ``rate_per_s`` with
+stratified exponential gaps (below); a closed loop keeps ``clients``
+requests outstanding, each client sending its next request when its last
+one finishes. A mix may list under ``assumed`` the parameters it takes
+from no published trace, with the reason.
+
+So that a seed changes the order of the work and not its amount, every
+seed draws the same multiset: the lengths are the distribution's quantiles
+at (i + 1/2)/n and the open loop's gaps are the exponential's quantiles, and
+the seed only orders them and draws the prompt's token ids. The order is
+shuffled in blocks of ``BLOCK`` consecutive requests: the sorted
+values are dealt round-robin into the blocks, so every block holds a
+spread of the whole distribution, and the seed shuffles the blocks and the
+values within each. Work and arrivals then progress evenly through a
+window whatever the seed, while gaps and lengths still come in a random
+order inside each block. The gaps are therefore exponential in their
+spread but not independent, as Poisson arrivals' would be: every block
+of eight holds one gap from each eighth of the distribution, so bursts of
+short gaps are rarer than under Poisson. An open loop makes n = rate x seconds requests; a closed loop
+cycles through a pool of ``pool`` requests.
+"""
+from __future__ import annotations
+
+import dataclasses
+import json
+import math
+from pathlib import Path
+from statistics import NormalDist
+from typing import List
+
+import numpy as np
+
+BLOCK = 8              # consecutive requests that hold a spread of the mix
+
+@dataclasses.dataclass
+class Item:
+    """One request of the mix: when it is due (seconds after the window
+    opens; open loop only), its prompt and how many tokens it asks for."""
+    due: float
+    prompt: List[int]
+    max_new: int
+
+
+def load(path) -> dict:
+    spec = json.loads(Path(path).read_text())
+    if spec["loop"] not in ("open", "closed"):
+        raise ValueError(f"{path}: loop must be open or closed")
+    return spec
+
+
+def quantile_lengths(dist: dict, n: int) -> np.ndarray:
+    """The n log-normal quantiles at (i + 1/2)/n, clipped and rounded."""
+    nd = NormalDist()
+    z = np.array([nd.inv_cdf((i + 0.5) / n) for i in range(n)])
+    x = np.exp(math.log(dist["median"]) + dist["sigma"] * z)
+    return np.clip(np.rint(x), dist["min"], dist["max"]).astype(np.int64)
+
+
+def exp_gaps(rate: float, n: int) -> np.ndarray:
+    """The n exponential quantiles at (i + 1/2)/n of mean 1/rate."""
+    u = (np.arange(n) + 0.5) / n
+    return -np.log1p(-u) / rate
+
+
+def blocked(values: np.ndarray, rng, block: int) -> np.ndarray:
+    """``values`` in the seed's order: dealt round-robin from sorted order
+    into ceil(n / block) blocks, blocks and their members shuffled."""
+    v = np.sort(values)
+    nb = max(1, -(-len(v) // block))
+    blocks = [rng.permutation(v[j::nb]) for j in range(nb)]
+    return np.concatenate([blocks[j] for j in rng.permutation(nb)])
+
+
+def count(spec: dict, seconds: float) -> int:
+    """Requests one run draws."""
+    if spec["loop"] == "open":
+        return max(1, int(round(spec["rate_per_s"] * seconds)))
+    return int(spec["pool"])
+
+
+def generate(spec: dict, seed: int, seconds: float, vocab: int) -> List[Item]:
+    """The run's requests in the order they are sent."""
+    n = count(spec, seconds)
+    rng = np.random.default_rng(int(seed) & ((1 << 63) - 1))
+    plen = blocked(quantile_lengths(spec["prompt_len"], n), rng, BLOCK)
+    olen = blocked(quantile_lengths(spec["output_len"], n), rng, BLOCK)
+    if spec["loop"] == "open":
+        due = np.cumsum(blocked(exp_gaps(spec["rate_per_s"], n), rng, BLOCK))
+    else:
+        due = np.zeros(n)
+    return [Item(float(due[i]),
+                 rng.integers(1, vocab, size=int(plen[i])).tolist(),
+                 int(olen[i])) for i in range(n)]
+
+
+def buckets(spec: dict, bucket_of) -> List[int]:
+    """Every admission bucket the mix's prompt lengths can fall in."""
+    d = spec["prompt_len"]
+    return sorted({bucket_of(n) for n in range(d["min"], d["max"] + 1)})

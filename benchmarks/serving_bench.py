@@ -11,16 +11,9 @@ reports tokens/sec per weight form (float ``w``, int8 levels ``q``, packed
 3-bit containers ``qp`` — the deployed form, where the per-tick cost is
 dominated by the batch-independent container unpack and the amortization is
 strongest), plus admission throughput: batched ``prefills`` issued and
-prompt tokens/sec (``ptok/s``) absorbed through them.
-
-Per-config timing is split into prefill vs decode seconds (engine profile
-timers): non-monotonic tok/s points are usually an admission effect — more
-slots means fewer, larger batched prefills — and the split pins down which
-phase moved. The profile wrapper blocks on each jitted call, trading the
-engine's async-drain overlap for phase attribution; on CPU (effectively
-synchronous execution) the measured overhead is nil, but pass
-``--no-profile`` to time the pure async path (no split in the artifact).
-``--matmul-mode`` selects the quantized-matmul dispatch
+prompt tokens/sec (``ptok/s``) absorbed through them. Non-monotonic tok/s
+points are usually an admission effect: more slots means fewer, larger
+batched prefills. ``--matmul-mode`` selects the quantized-matmul dispatch
 (auto/kernel/dequant; kernel is interpret-mode off-TPU), ``--attn-mode``
 the decode-attention dispatch (auto/kernel/ref — the fused Pallas
 ``attn_decode`` kernel vs the einsum path), and ``--kv8`` serves from an
@@ -31,7 +24,7 @@ kv8 halves (twice the slots per fixed cache budget).
 (admission buckets 1024/2048/4096), the regime where prefill attention
 dominates admission cost: the einsum path materializes an O(T^2) fp32 score
 tensor per sequence while the blocked Pallas kernel (``--attn-mode
-kernel``) keeps one (bt, G, bs) tile in VMEM — the ``pfill_s`` column is
+kernel``) keeps one (bt, G, bs) tile in VMEM — the ``ptok/s`` column is
 the number that moves. The long mix defaults to fewer slots/requests, one
 repeat and a 256-token ``--attn-chunk`` (caps the ref-mode chunked-prefill
 working set; the engine threads it through to ``chunked_attention``).
@@ -114,7 +107,7 @@ def _prompts(requests: int, lengths):
 
 def _engine(params, cfg, policy, slots, max_prompt, max_new,
             matmul_mode="auto", attn_mode="auto", kv_bits=None, spec_k=0,
-            draft=None, profile=True, attn_chunk=1024):
+            draft=None, attn_chunk=1024):
     return ServingEngine(params, cfg, policy=policy, slots=slots,
                          max_len=max_prompt + max_new + 1 + spec_k,
                          dtype=jnp.float32, matmul_mode=matmul_mode,
@@ -122,7 +115,7 @@ def _engine(params, cfg, policy, slots, max_prompt, max_new,
                          spec_k=spec_k,
                          draft_params=draft[1] if draft else None,
                          draft_cfg=draft[0] if draft else None,
-                         profile=profile, attn_chunk=attn_chunk)
+                         attn_chunk=attn_chunk)
 
 
 def _cache_bytes_per_slot(eng: ServingEngine) -> int:
@@ -137,14 +130,13 @@ def bench_form(params, cfg, policy, *, slots: int, requests: int,
                max_new: int, lengths, repeats: int = 3,
                matmul_mode: str = "auto", attn_mode: str = "auto",
                kv_bits=None, spec_k: int = 0, draft=None,
-               profile: bool = True, attn_chunk: int = 1024) -> dict:
+               attn_chunk: int = 1024) -> dict:
     # warmup on the SAME engine instance that gets timed: the jitted
     # prefill/tick closures are per-engine, so a throwaway warmup engine
     # would leave the timed run paying compile time. One prompt per
     # admission bucket the mix touches compiles every batched-prefill entry.
     eng = _engine(params, cfg, policy, slots, max(lengths), max_new,
-                  matmul_mode, attn_mode, kv_bits, spec_k, draft, profile,
-                  attn_chunk)
+                  matmul_mode, attn_mode, kv_bits, spec_k, draft, attn_chunk)
     for bucket in sorted({eng._bucket_len(n) for n in lengths}):
         eng.submit([1] * bucket, max_new=max_new)
     eng.run_all()
@@ -157,16 +149,12 @@ def bench_form(params, cfg, policy, *, slots: int, requests: int,
     best = None
     for _ in range(repeats):
         ticks0, prefills0 = eng.decode_calls, eng.prefill_calls
-        psecs0, dsecs0 = eng.prefill_secs, eng.decode_secs
         for p in prompts:
             eng.submit(p, max_new=max_new)
         t0 = time.perf_counter()
         done = eng.run_all()
         dt = time.perf_counter() - t0
         toks = sum(len(r.out) for r in done)
-        # the prefill/decode split makes per-phase regressions visible: a
-        # tok/s dip can hide admission cost (more slots => fewer, bigger
-        # batched prefills) behind decode amortization, and vice versa
         ticks = eng.decode_calls - ticks0
         # per-slot speculative win: decode-emitted tokens per request tick
         # (the admission sample rides prefill, so it is excluded). Exactly
@@ -177,8 +165,6 @@ def bench_form(params, cfg, policy, *, slots: int, requests: int,
              "tok_per_sec": toks / dt, "ticks": ticks,
              "prefills": eng.prefill_calls - prefills0,
              "prompt_tokens": ptoks, "prompt_tok_per_sec": ptoks / dt,
-             "prefill_secs": eng.prefill_secs - psecs0,
-             "decode_secs": eng.decode_secs - dsecs0,
              "attn_mode": attn_mode, "kv_bits": kv_bits,
              "spec_k": spec_k,
              "accepted_tok_per_tick": dec_toks / max(slot_ticks, 1),
@@ -376,10 +362,6 @@ def main():
     ap.add_argument("--draft-depth", type=float, default=1.0,
                     help="drafter depth fraction for --spec-k (0.5 = the "
                          "half-depth draft variant)")
-    ap.add_argument("--no-profile", action="store_true",
-                    help="disable the per-phase timers (they block on each "
-                         "jitted call): times the pure async engine, at the "
-                         "cost of the prefill/decode split in the artifact")
     ap.add_argument("--layers", type=int, default=4)
     ap.add_argument("--d-model", type=int, default=128)
     ap.add_argument("--vocab", type=int, default=512)
@@ -529,7 +511,7 @@ def main():
         return
 
     print(f"{'form':>4} {'slots':>5} {'tokens':>7} {'ticks':>6} "
-          f"{'prefills':>8} {'secs':>7} {'pfill_s':>7} {'dec_s':>7} "
+          f"{'prefills':>8} {'secs':>7} "
           f"{'tok/s':>8} {'ptok/s':>8} {'acc/tick':>8} {'KB/slot':>8}")
     for form in args.forms.split(","):
         p, pol = form_params[form]
@@ -541,12 +523,10 @@ def main():
                            matmul_mode=args.matmul_mode,
                            attn_mode=args.attn_mode, kv_bits=kv_bits,
                            spec_k=args.spec_k, draft=draft,
-                           profile=not args.no_profile,
                            attn_chunk=args.attn_chunk)
             results[form].append(r)
             print(f"{form:>4} {r['slots']:>5} {r['tokens']:>7} "
                   f"{r['ticks']:>6} {r['prefills']:>8} {r['secs']:>7.2f} "
-                  f"{r['prefill_secs']:>7.2f} {r['decode_secs']:>7.2f} "
                   f"{r['tok_per_sec']:>8.1f} {r['prompt_tok_per_sec']:>8.1f} "
                   f"{r['accepted_tok_per_tick']:>8.2f} "
                   f"{r['cache_bytes_per_slot'] / 1024:>8.1f}")
@@ -562,11 +542,6 @@ def main():
             "matmul_mode": args.matmul_mode,
             "attn_mode": args.attn_mode, "kv_bits": kv_bits,
             "spec_k": args.spec_k, "draft_depth": args.draft_depth,
-            # with --no-profile the per-phase timers never run, so the
-            # prefill_secs/decode_secs fields are 0.0-by-absence — this
-            # flag lets artifact consumers tell that apart from a
-            # measured-zero phase
-            "profile": not args.no_profile,
             "results": results,
         }
         with open(args.out, "w") as f:

@@ -10,6 +10,12 @@ optional remat.
 Every projection goes through ``quant_dense`` so the paper's W3A8 policy
 applies: wq/wk/wv/wo + FFN are role 'hidden' (3-bit), embed role 'embed',
 LM head role 'output' (8-bit, the paper's sensitive-layer rule).
+
+``prefill`` and ``decode_step`` name their parts with ``jax.named_scope``
+(``model.embed``, ``model.attn_qkv``, ``model.kv_write``,
+``model.attention``, ``model.attn_out``, ``model.mlp``,
+``model.final_norm``, ``model.readout``): the names reach the HLO ops'
+metadata only, so a profiler trace can say which part a device op serves.
 """
 from __future__ import annotations
 
@@ -130,16 +136,20 @@ def _layer_forward(lp, ld, h, cfg: ModelConfig, policy, positions, inv_freq,
     (j <= t AND j < lengths[row]); 'ref' (the training default) the chunked
     / SWA scans, causal-only."""
     b, s, _ = h.shape
-    hn = rmsnorm(lp["ln1"], h, cfg.norm_eps)
-    q, k, v = _qkv(lp, hn, cfg, policy, ld, positions, inv_freq, mm)
-    o = prefill_attention(q, k, v, lengths=lengths,
-                          window=cfg.sliding_window or 0, mode=attn_mode,
-                          chunk=min(attn_chunk, s))
-    h = h + _attn_out(lp, o, cfg, policy, ld, b, s, mm)
-    h = constrain(h, "act")
-    hn = rmsnorm(lp["ln2"], h, cfg.norm_eps)
-    f, aux = _ffn(lp, hn, cfg, policy, ld, mm)
-    h = constrain(h + f, "act")
+    with jax.named_scope("model.attn_qkv"):
+        hn = rmsnorm(lp["ln1"], h, cfg.norm_eps)
+        q, k, v = _qkv(lp, hn, cfg, policy, ld, positions, inv_freq, mm)
+    with jax.named_scope("model.attention"):
+        o = prefill_attention(q, k, v, lengths=lengths,
+                              window=cfg.sliding_window or 0, mode=attn_mode,
+                              chunk=min(attn_chunk, s))
+    with jax.named_scope("model.attn_out"):
+        h = h + _attn_out(lp, o, cfg, policy, ld, b, s, mm)
+        h = constrain(h, "act")
+    with jax.named_scope("model.mlp"):
+        hn = rmsnorm(lp["ln2"], h, cfg.norm_eps)
+        f, aux = _ffn(lp, hn, cfg, policy, ld, mm)
+        h = constrain(h + f, "act")
     return h, aux, (k, v)
 
 
@@ -249,7 +259,8 @@ def prefill(params, batch, cfg: ModelConfig, *, policy: QuantPolicy,
     :func:`repro.models.attention.prefill_attention`).
     """
     attn_mode = resolve_attn_mode(attn_mode)
-    h = _embed_input(params, batch, cfg, policy, deltas, dtype)
+    with jax.named_scope("model.embed"):
+        h = _embed_input(params, batch, cfg, policy, deltas, dtype)
     s = h.shape[1]
     max_len = max_len or s
     cs = cache_len_for(cfg, max_len)
@@ -265,33 +276,39 @@ def prefill(params, batch, cfg: ModelConfig, *, policy: QuantPolicy,
                                        inv_freq, attn_chunk, matmul_mode,
                                        attn_mode, lengths)
         # keep last `cs` positions (ring-start for SWA, whole seq otherwise)
-        return hh, (k[:, -cs:], v[:, -cs:])
+        with jax.named_scope("model.kv_write"):
+            return hh, (k[:, -cs:], v[:, -cs:])
 
     ld = deltas.get("layers") if deltas else None
     h, (ks, vs) = jax.lax.scan(body, h, (params["layers"], ld))
-    if lengths is not None:
-        lengths = jnp.asarray(lengths, jnp.int32)
-        h = jnp.take_along_axis(h, (lengths - 1)[:, None, None], axis=1)
-    else:
-        h = h[:, -1:]
-    h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
-    logits = _logits(params, h, cfg, policy, deltas, matmul_mode)
-    if cs > ks.shape[2]:
-        padw = cs - ks.shape[2]
-        ks = jnp.pad(ks, ((0, 0), (0, 0), (0, padw), (0, 0), (0, 0)))
-        vs = jnp.pad(vs, ((0, 0), (0, 0), (0, padw), (0, 0), (0, 0)))
-    elif cfg.sliding_window and s >= cs and s % cs:
-        # ring-buffer invariant: token t lives at slot t % cs. The slice put
-        # token s-cs+i at slot i; roll by s % cs so it sits at (s+i) % cs.
-        ks = jnp.roll(ks, s % cs, axis=2)
-        vs = jnp.roll(vs, s % cs, axis=2)
-    clen = jnp.asarray(s, jnp.int32) if lengths is None else lengths
-    if quantize_cache:
-        qk, sk = jax.vmap(_quantize_kv)(ks)       # over layer dim
-        qv, sv = jax.vmap(_quantize_kv)(vs)
-        cache = {"k": qk, "v": qv, "k_scale": sk, "v_scale": sv, "len": clen}
-    else:
-        cache = {"k": ks, "v": vs, "len": clen}
+    with jax.named_scope("model.final_norm"):
+        if lengths is not None:
+            lengths = jnp.asarray(lengths, jnp.int32)
+            h = jnp.take_along_axis(h, (lengths - 1)[:, None, None], axis=1)
+        else:
+            h = h[:, -1:]
+        h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
+    with jax.named_scope("model.readout"):
+        logits = _logits(params, h, cfg, policy, deltas, matmul_mode)
+    with jax.named_scope("model.kv_write"):
+        if cs > ks.shape[2]:
+            padw = cs - ks.shape[2]
+            ks = jnp.pad(ks, ((0, 0), (0, 0), (0, padw), (0, 0), (0, 0)))
+            vs = jnp.pad(vs, ((0, 0), (0, 0), (0, padw), (0, 0), (0, 0)))
+        elif cfg.sliding_window and s >= cs and s % cs:
+            # ring-buffer invariant: token t lives at slot t % cs. The slice
+            # put token s-cs+i at slot i; roll by s % cs so it sits at
+            # (s+i) % cs.
+            ks = jnp.roll(ks, s % cs, axis=2)
+            vs = jnp.roll(vs, s % cs, axis=2)
+        clen = jnp.asarray(s, jnp.int32) if lengths is None else lengths
+        if quantize_cache:
+            qk, sk = jax.vmap(_quantize_kv)(ks)       # over layer dim
+            qv, sv = jax.vmap(_quantize_kv)(vs)
+            cache = {"k": qk, "v": qv, "k_scale": sk, "v_scale": sv,
+                     "len": clen}
+        else:
+            cache = {"k": ks, "v": vs, "len": clen}
     return logits, cache
 
 
@@ -317,9 +334,10 @@ def decode_step(params, cache, tokens: jnp.ndarray, cfg: ModelConfig, *,
     b = tokens.shape[0]
     pos = jnp.broadcast_to(cache["len"], (b,)).astype(jnp.int32)   # (B,)
     quantized = "k_scale" in cache
-    h = embed_lookup(params["embed"], tokens, policy=policy,
-                     delta=_dget(deltas, "embed", "w"), dtype=dtype)
-    h = constrain(h, "dec_act")
+    with jax.named_scope("model.embed"):
+        h = embed_lookup(params["embed"], tokens, policy=policy,
+                         delta=_dget(deltas, "embed", "w"), dtype=dtype)
+        h = constrain(h, "dec_act")
     inv_freq = rope_freqs(cfg.head_dim, cfg.rope_theta)
     positions = pos[:, None]                                       # (B, 1)
     cs = cache["k"].shape[2]
@@ -332,27 +350,32 @@ def decode_step(params, cache, tokens: jnp.ndarray, cfg: ModelConfig, *,
         else:
             lp, ld, kc, vc = xs
             ks_ = vs_ = None
-        hn = rmsnorm(lp["ln1"], hh, cfg.norm_eps)
-        q, k, v = _qkv(lp, hn, cfg, policy, ld, positions, inv_freq,
-                       matmul_mode)
-        if quantized:
-            kq, ksc = _quantize_kv(k)
-            vq, vsc = _quantize_kv(v)
-            kc = kc.at[rows, slot].set(kq[:, 0])
-            vc = vc.at[rows, slot].set(vq[:, 0])
-            ks_ = ks_.at[rows, slot].set(ksc[:, 0])
-            vs_ = vs_.at[rows, slot].set(vsc[:, 0])
-        else:
-            kc = kc.at[rows, slot].set(k[:, 0].astype(kc.dtype))
-            vc = vc.at[rows, slot].set(v[:, 0].astype(vc.dtype))
-        valid = jnp.minimum(pos + 1, cs)
-        o = decode_attention(q, kc, vc, valid, k_scale=ks_, v_scale=vs_,
-                             mode=attn_mode)
-        hh = hh + _attn_out(lp, o, cfg, policy, ld, b, 1, matmul_mode)
-        hn = rmsnorm(lp["ln2"], hh, cfg.norm_eps)
-        f, _ = _ffn(lp, hn, cfg, policy, ld, matmul_mode)
-        out = (hh + f, (kc, vc, ks_, vs_) if quantized else (kc, vc))
-        return out
+        with jax.named_scope("model.attn_qkv"):
+            hn = rmsnorm(lp["ln1"], hh, cfg.norm_eps)
+            q, k, v = _qkv(lp, hn, cfg, policy, ld, positions, inv_freq,
+                           matmul_mode)
+        with jax.named_scope("model.kv_write"):
+            if quantized:
+                kq, ksc = _quantize_kv(k)
+                vq, vsc = _quantize_kv(v)
+                kc = kc.at[rows, slot].set(kq[:, 0])
+                vc = vc.at[rows, slot].set(vq[:, 0])
+                ks_ = ks_.at[rows, slot].set(ksc[:, 0])
+                vs_ = vs_.at[rows, slot].set(vsc[:, 0])
+            else:
+                kc = kc.at[rows, slot].set(k[:, 0].astype(kc.dtype))
+                vc = vc.at[rows, slot].set(v[:, 0].astype(vc.dtype))
+        with jax.named_scope("model.attention"):
+            valid = jnp.minimum(pos + 1, cs)
+            o = decode_attention(q, kc, vc, valid, k_scale=ks_, v_scale=vs_,
+                                 mode=attn_mode)
+        with jax.named_scope("model.attn_out"):
+            hh = hh + _attn_out(lp, o, cfg, policy, ld, b, 1, matmul_mode)
+        with jax.named_scope("model.mlp"):
+            hn = rmsnorm(lp["ln2"], hh, cfg.norm_eps)
+            f, _ = _ffn(lp, hn, cfg, policy, ld, matmul_mode)
+            hh = hh + f
+        return hh, (kc, vc, ks_, vs_) if quantized else (kc, vc)
 
     ld = deltas.get("layers") if deltas else None
     if quantized:
@@ -365,8 +388,10 @@ def decode_step(params, cache, tokens: jnp.ndarray, cfg: ModelConfig, *,
         h, (ks, vs) = jax.lax.scan(body, h, (params["layers"], ld, cache["k"],
                                              cache["v"]))
         new_cache = {"k": ks, "v": vs, "len": cache["len"] + 1}
-    h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
-    logits = _logits(params, h, cfg, policy, deltas, matmul_mode)
+    with jax.named_scope("model.final_norm"):
+        h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
+    with jax.named_scope("model.readout"):
+        logits = _logits(params, h, cfg, policy, deltas, matmul_mode)
     return logits, new_cache
 
 

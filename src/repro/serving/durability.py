@@ -54,19 +54,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import api as model_api
+from repro.serving.engine import COUNTERS
 
 __all__ = ["Journal", "snapshot_engine", "restore_engine", "recover"]
 
 FORMAT = 1
-
-# engine counters captured verbatim in a snapshot and restored verbatim —
-# a recovered engine reports the same totals the dead one had accumulated
-_COUNTERS = (
-    "decode_calls", "prefill_calls", "spec_drafted", "spec_accepted",
-    "shed_count", "deadline_miss_count", "preempt_count", "poisoned_count",
-    "queue_peak", "snapshots_written", "journal_events", "replayed_events",
-    "integrity_probes", "heal_count",
-)
 
 # terminal Request.status values that stay dead across recovery: their
 # outcome was already reported to the caller, so replay must not resurrect
@@ -184,7 +176,9 @@ def snapshot_engine(eng, snapshot_dir: str, *, keep: int = 3) -> str:
         "ticks_left": [int(x) for x in eng._ticks_left],
         "slot_ticks": [int(x) for x in eng._slot_ticks],
         "uid": eng._uid,
-        "counters": {k: int(getattr(eng, k)) for k in _COUNTERS},
+        # every engine counter, restored verbatim: a recovered engine
+        # reports the totals the dead one had accumulated
+        "counters": eng.counters(),
         "fallback_events": [[int(t), str(lbl)]
                             for t, lbl in eng.fallback_events],
     }
@@ -272,8 +266,8 @@ def restore_engine(eng, snapshot_dir: str,
     eng._slot_ticks = [int(x) for x in state["slot_ticks"]]
     eng._pending = []
     eng._uid = int(state["uid"])
-    for k in _COUNTERS:
-        setattr(eng, k, int(state["counters"][k]))
+    for k in COUNTERS:              # one added since the snapshot: 0
+        setattr(eng, k, int(state["counters"].get(k, 0)))
     eng.fallback_events = [(int(t), str(lbl))
                            for t, lbl in state["fallback_events"]]
     # a restored engine must not immediately re-snapshot the same tick

@@ -131,6 +131,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.precision import QuantPolicy
@@ -145,6 +146,16 @@ __all__ = ["generate", "Request", "ServingEngine", "FaultPlan",
 
 # smallest admission bucket: prompts of length 1..8 share one compilation
 _MIN_BUCKET = 8
+
+# every engine counter, a plain int attribute: ``counters()`` reads them,
+# the watchdog's diagnostics report them, snapshots carry them
+COUNTERS = (
+    "decode_calls", "prefill_calls", "admitted", "prefill_tokens",
+    "prefill_positions", "live_slot_ticks", "spec_drafted", "spec_accepted",
+    "shed_count", "deadline_miss_count", "preempt_count", "poisoned_count",
+    "queue_peak", "snapshots_written", "journal_events", "replayed_events",
+    "integrity_probes", "heal_count",
+)
 
 
 def _sample(key, logits: jnp.ndarray, temperature: float) -> jnp.ndarray:
@@ -397,6 +408,16 @@ class ServingEngine:
     Admission order is FIFO by bucket: each admission round serves the
     oldest queued request's bucket, and other same-bucket requests ride
     along (bounded queue-jumping in exchange for batched prefill).
+
+    Instrumentation: ``counters()`` returns every counter in ``COUNTERS``,
+    among them ``prefill_tokens`` over ``prefill_positions`` (the useful
+    share of admission prefill) and ``live_slot_ticks`` (occupied slots per
+    tick, summed). The host spans ``serve.admit`` (args ``bucket``,
+    ``rows``), ``serve.tick``, ``serve.sync.wait`` (the blocking
+    ``device_get``) and ``serve.sync.host`` (attribution) are
+    ``jax.profiler.TraceAnnotation``s: a profiler trace holds them beside
+    the device ops, on the same clock. The tick's sampling runs under the
+    ``tick.sample`` name scope, the model's parts under ``model.*``.
     """
 
     def __init__(self, params, cfg: ModelConfig, *, policy: QuantPolicy,
@@ -407,7 +428,7 @@ class ServingEngine:
                  attn_mode: str = "auto", kv_bits: Optional[int] = None,
                  spec_k: int = 0, draft_params=None,
                  draft_cfg: Optional[ModelConfig] = None,
-                 attn_chunk: int = 1024, profile: bool = False,
+                 attn_chunk: int = 1024,
                  queue_limit: Optional[int] = None,
                  shed_policy: str = "reject",
                  default_deadline: Optional[int] = None,
@@ -498,6 +519,14 @@ class ServingEngine:
         self._uid = 0
         self.decode_calls = 0                 # ticks == decode_step calls
         self.prefill_calls = 0                # batched prefill invocations
+        # admission and occupancy: requests admitted (a preempted request
+        # again on re-entry), their prefilled tokens, the positions prefill
+        # computed for them (padding rows and columns included), and the
+        # occupied slots at each tick dispatch, summed
+        self.admitted = 0
+        self.prefill_tokens = 0
+        self.prefill_positions = 0
+        self.live_slot_ticks = 0
         # resilience knobs + counters
         self.queue_limit = queue_limit
         self.shed_policy = shed_policy
@@ -541,13 +570,6 @@ class ServingEngine:
         # padded length <= window, so longer prompts take the solo path
         self._bucket_cap = (self.mod.cache_len_for(cfg, max_len)
                             if hasattr(self.mod, "cache_len_for") else max_len)
-        # optional phase timers: wall-clock split between admission (prefill)
-        # and decode ticks, for benchmarks. Wrapping blocks on each call's
-        # result, so it trades a little async overlap for attribution —
-        # off by default.
-        self.prefill_secs = 0.0
-        self.decode_secs = 0.0
-        self._profile = profile
         self._build_jits()
 
     def _build_jits(self):
@@ -584,10 +606,10 @@ class ServingEngine:
         self._free_fn = jax.jit(
             lambda c, idx: model_api.free_slots(self.cfg, c, idx),
             donate_argnums=(0,))
-        # the analysis registry's window into this engine: raw jitted fns
-        # (recorded BEFORE any profile wrapping), so trace/retrace budgets
-        # can be reported from the same place the contract passes run —
-        # repro.analysis.contracts.retrace_report reads trace_counts()
+        # the analysis registry's window into this engine: the jitted fns,
+        # so trace/retrace budgets can be reported from the same place the
+        # contract passes run — repro.analysis.contracts.retrace_report
+        # reads trace_counts()
         self._jits = {"tick": self._tick_fn, "prefill": self._prefill_fn,
                       "admit": self._admit_fn, "admit_many": self._admit_many_fn,
                       "free": self._free_fn}
@@ -595,19 +617,6 @@ class ServingEngine:
             self._jits.update(prefill_draft=self._prefill_draft_fn,
                               admit_draft=self._admit_draft_fn,
                               admit_draft_many=self._admit_draft_many_fn)
-        if self._profile:
-            self._tick_fn = self._timed(self._tick_fn, "decode_secs")
-            self._prefill_fn = self._timed(self._prefill_fn, "prefill_secs")
-            self._admit_fn = self._timed(self._admit_fn, "prefill_secs")
-            self._admit_many_fn = self._timed(self._admit_many_fn,
-                                              "prefill_secs")
-            if self._spec:
-                self._prefill_draft_fn = self._timed(self._prefill_draft_fn,
-                                                     "prefill_secs")
-                self._admit_draft_fn = self._timed(self._admit_draft_fn,
-                                                   "prefill_secs")
-                self._admit_draft_many_fn = self._timed(
-                    self._admit_draft_many_fn, "prefill_secs")
 
     # --- degradation ladder (called via resilience.degrade_step) ------------
 
@@ -775,16 +784,9 @@ class ServingEngine:
         return self.spec_accepted / self.spec_drafted if self.spec_drafted \
             else 0.0
 
-    def _timed(self, fn, attr: str):
-        import time
-
-        def wrapped(*a, **kw):
-            t0 = time.perf_counter()
-            out = jax.block_until_ready(fn(*a, **kw))
-            setattr(self, attr,
-                    getattr(self, attr) + time.perf_counter() - t0)
-            return out
-        return wrapped
+    def counters(self) -> Dict[str, int]:
+        """Every engine counter (``COUNTERS``) by name."""
+        return {k: int(getattr(self, k)) for k in COUNTERS}
 
     # --- static-analysis surface (repro.analysis.contracts) -----------------
 
@@ -894,14 +896,16 @@ class ServingEngine:
         logits, new_cache = self.mod.decode_step(params, cache, tokens,
                                                  self.cfg, **self._mkw(),
                                                  **self._attn_kw["decode"])
-        logits = logits + poison[:, None, None]
-        bad = active & ~jnp.all(jnp.isfinite(logits), axis=(1, 2))
-        ok = active & ~bad
-        nxt = _sample(key, logits[:, 0], self.temperature).astype(jnp.int32)
-        nxt = jnp.where(ok, nxt, tokens[:, 0])       # freeze inactive + bad
-        emitted = emitted + ok.astype(jnp.int32)
-        done = ok & ((emitted >= budget) | (nxt == self._eos()))
-        new_cache["len"] = jnp.where(ok, new_cache["len"], cache["len"])
+        with jax.named_scope("tick.sample"):
+            logits = logits + poison[:, None, None]
+            bad = active & ~jnp.all(jnp.isfinite(logits), axis=(1, 2))
+            ok = active & ~bad
+            nxt = _sample(key, logits[:, 0],
+                          self.temperature).astype(jnp.int32)
+            nxt = jnp.where(ok, nxt, tokens[:, 0])   # freeze inactive + bad
+            emitted = emitted + ok.astype(jnp.int32)
+            done = ok & ((emitted >= budget) | (nxt == self._eos()))
+            new_cache["len"] = jnp.where(ok, new_cache["len"], cache["len"])
         return new_cache, nxt[:, None], ok & ~done, emitted, done, bad
 
     def _spec_tick(self, params, dparams, cache, dcache, tokens, active,
@@ -1081,7 +1085,10 @@ class ServingEngine:
             if len(head.admit_prompt) > self._bucket_cap:
                 # sliding-window ring overflow: padded per-row ring alignment
                 # is undefined, so this prompt takes the exact solo path
-                self._admit_solo(free.pop(0), self.queue.pop(0))
+                req = self.queue.pop(0)
+                with TraceAnnotation("serve.admit",
+                                     bucket=len(req.admit_prompt), rows=1):
+                    self._admit_solo(free.pop(0), req)
                 continue
             bucket = self._bucket_len(len(head.admit_prompt))
             batch: List[Request] = []
@@ -1095,7 +1102,9 @@ class ServingEngine:
                     rest.append(r)
             self.queue = rest
             slot_ids = [free.pop(0) for _ in batch]
-            self._admit_batch(slot_ids, batch, bucket)
+            with TraceAnnotation("serve.admit", bucket=bucket,
+                                 rows=len(batch)):
+                self._admit_batch(slot_ids, batch, bucket)
 
     # --- slot release + resilience helpers ----------------------------------
 
@@ -1203,7 +1212,7 @@ class ServingEngine:
 
     def _diagnostics(self) -> Dict[str, Any]:
         """The watchdog's dump: what is queued, who holds which slot and
-        for how much longer, and every resilience counter."""
+        for how much longer, every counter and the fallback events."""
         return {
             "queue_depth": len(self.queue),
             "queued_uids": [r.uid for r in self.queue],
@@ -1214,18 +1223,8 @@ class ServingEngine:
                        "held_ticks": self._slot_ticks[s]}
                       for s in range(self.slots)
                       if (r := self._slot_req[s]) is not None],
-            "decode_calls": self.decode_calls,
-            "prefill_calls": self.prefill_calls,
-            "shed_count": self.shed_count,
-            "deadline_miss_count": self.deadline_miss_count,
-            "preempt_count": self.preempt_count,
-            "poisoned_count": self.poisoned_count,
+            **self.counters(),
             "fallback_events": list(self.fallback_events),
-            "snapshots_written": self.snapshots_written,
-            "journal_events": self.journal_events,
-            "replayed_events": self.replayed_events,
-            "integrity_probes": self.integrity_probes,
-            "heal_count": self.heal_count,
         }
 
     def _admit_batch(self, slot_ids: List[int], reqs: List[Request],
@@ -1248,6 +1247,7 @@ class ServingEngine:
         logits0, src = self._prefill_fn(self.params, jnp.asarray(toks),
                                         jnp.asarray(lens))
         self.prefill_calls += 1
+        self.prefill_positions += n * bucket
         self._key, k = jax.random.split(self._key)
         (self.cache, self._tokens, self._active, self._emitted,
          self._budget) = self._admit_many_fn(
@@ -1271,6 +1271,7 @@ class ServingEngine:
         toks = jnp.asarray([req.admit_prompt], jnp.int32)
         logits0, src = self._prefill_fn(self.params, toks)
         self.prefill_calls += 1
+        self.prefill_positions += toks.shape[1]
         self._key, k = jax.random.split(self._key)
         (self.cache, self._tokens, self._active, self._emitted,
          self._budget) = self._admit_fn(
@@ -1289,6 +1290,8 @@ class ServingEngine:
         iff a request never became active (max_new == 1 / instant EOS) —
         and release slots whose lifetime is already over (drain finishes
         them)."""
+        self.admitted += len(reqs)
+        self.prefill_tokens += sum(len(r.admit_prompt) for r in reqs)
         self._log_event({"e": "admit", "uids": [r.uid for r in reqs],
                          "slots": list(slot_ids)})
         mask_np = np.zeros((self.slots,), bool)
@@ -1344,8 +1347,10 @@ class ServingEngine:
         emitted_mask = self._active                  # who emits this tick
         owners = tuple(self._slot_req)
         poison = self._poison_for_tick()
-        self._key, k = jax.random.split(self._key)
-        self._dispatch_tick(owners, emitted_mask, poison, k)
+        self.live_slot_ticks += sum(r is not None for r in owners)
+        with TraceAnnotation("serve.tick"):
+            self._key, k = jax.random.split(self._key)
+            self._dispatch_tick(owners, emitted_mask, poison, k)
         self.decode_calls += 1
         for s in range(self.slots):
             if self._slot_req[s] is not None:
@@ -1423,11 +1428,18 @@ class ServingEngine:
         ``spec_drafted``/``spec_accepted`` counters are folded in here."""
         if not self._pending:
             return
-        moved = jax.device_get([(toks, counts, done,
-                                 () if acc is None else acc,
-                                 () if bad is None else bad)
-                                for toks, counts, done, _, acc, _, bad
-                                in self._pending])
+        with TraceAnnotation("serve.sync.wait"):
+            moved = jax.device_get([(toks, counts, done,
+                                     () if acc is None else acc,
+                                     () if bad is None else bad)
+                                    for toks, counts, done, _, acc, _, bad
+                                    in self._pending])
+        with TraceAnnotation("serve.sync.host"):
+            self._attribute(moved)
+
+    def _attribute(self, moved):
+        """The host half of ``_sync``: give the moved tokens to their
+        requests, finish and quarantine, journal the commits."""
         quarantined: List[int] = []
         committed: Dict[int, int] = {}        # uid -> tokens attributed now
         for (toks, counts, done, acc, bad), (_, _, _, owners, _, kind, _) \

@@ -23,7 +23,11 @@ def enable_compile_cache() -> str:
     """Turn the persistent compilation cache on and return its directory.
 
     ``JAX_COMPILATION_CACHE_DIR``, where set, is JAX's own setting and is
-    left alone; otherwise the cache goes to :data:`DEFAULT_CACHE_DIR`."""
+    left alone; otherwise the cache goes to :data:`DEFAULT_CACHE_DIR`.
+    Every compile is kept, not only those of a second or more (JAX's
+    default): the serve tick and a dozen set-up programs compile in less,
+    and each process would compile them again."""
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -62,18 +62,33 @@ class TestQMatmul:
 
 
 class TestQMatvec:
-    @pytest.mark.parametrize("b,k,n", [(1, 1022, 1022), (8, 100, 64),
-                                       (128, 640, 256)])
-    def test_vs_ref(self, b, k, n):
-        ks = jax.random.split(jax.random.PRNGKey(0), 3)
-        x = _rand(ks[0], (b, k), jnp.float32)
-        q = jax.random.randint(ks[1], (k, n), -3, 4, jnp.int8)
+    # (M, K, N, bias, out_dtype): decode (M 1, 16) and bucketed prefill
+    # (16 x 64) rows; KP 154 in one K block (K 1536), partial last blocks
+    # (K 1537, 2570, 3001), K not a multiple of 10; N 256 and 8960
+    @pytest.mark.parametrize("m,k,n,bias,out_dtype", [
+        pytest.param(1, 1022, 1022, False, None, id="1-1022-1022"),
+        pytest.param(8, 100, 64, False, None, id="8-100-64"),
+        pytest.param(128, 640, 256, False, None, id="128-640-256"),
+        (1, 1536, 256, True, jnp.float32), (16, 1536, 8960, True, None),
+        (16, 1537, 256, False, jnp.bfloat16), (1024, 1536, 256, True, None),
+        (16, 2570, 256, True, None), (1, 3001, 256, False, None),
+        (16, 8960, 256, True, jnp.float32), (1, 8960, 8960, False, None),
+        (16, 95, 8960, False, None)])
+    def test_vs_ref(self, m, k, n, bias, out_dtype):
+        ks = jax.random.split(jax.random.PRNGKey(0), 4)
+        x = _rand(ks[0], (m, k), jnp.float32)
+        q = jax.random.randint(ks[1], (k, n), -4, 4, jnp.int8)
         wp = pack_matrix(q, 3)
         d = jnp.abs(_rand(ks[2], (n,), jnp.float32)) * 0.1 + 0.01
-        out = qmatvec(x, wp, d, k=k, interpret=True)
-        ref = qmatvec_ref(x, wp, d, k)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-4, atol=1e-4)
+        b = _rand(ks[3], (n,), jnp.float32) if bias else None
+        out = qmatvec(x, wp, d, k=k, bias=b, out_dtype=out_dtype,
+                      interpret=True)
+        ref = qmatvec_ref(x, wp, d, k, bias=b, out_dtype=out_dtype)
+        assert out.shape == (m, n) and out.dtype == ref.dtype
+        tol = 2e-2 if out.dtype == jnp.bfloat16 else 1e-4
+        np.testing.assert_allclose(np.asarray(out, jnp.float32),
+                                   np.asarray(ref, jnp.float32),
+                                   rtol=tol, atol=tol)
 
     @settings(max_examples=10, deadline=None)
     @given(st.integers(1, 16), st.integers(1, 200), st.integers(1, 64),

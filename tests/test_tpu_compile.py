@@ -4,7 +4,8 @@ Interpret mode (every other kernel test) cannot see what Mosaic refuses:
 block shapes whose trailing dims are not TPU tiles, operand layouts that
 differ from XLA's, VMEM overruns. Each case here lowers one kernel at the
 qwen2-1.5b serving widths (d_model 1536, d_ff 8960, vocab 151936, 12 query
-heads over 2 KV heads of 128, 4 slots, an 80-position cache) through the
+heads over 2 KV heads of 128, 4 slots, an 80-position cache; the packed
+matmuls at the benchmark's 16 slots and 16 x 64 prefill rows) through the
 TPU compiler against a described — not attached — v5e chip, and checks the
 compiled program calls the kernel (``tpu_custom_call``) under the kernel's
 own name, which the benchmark's trace reader keys on.
@@ -22,11 +23,16 @@ from repro.kernels.attn_decode.kernel import attn_decode_pallas
 from repro.kernels.attn_prefill.kernel import attn_prefill_pallas
 from repro.kernels.qmatmul.kernel import qmatmul_pallas
 from repro.kernels.qmatmul.ops import pick_blocks
-from repro.kernels.qmatvec.kernel import FIELDS, qmatvec_pallas
+from repro.kernels.qmatvec.kernel import (FIELDS, qmatvec_blocks,
+                                          qmatvec_pallas)
 
 D, FF, VOCAB = 1536, 8960, 151936
 KV, G, HD = 2, 6, 128                  # 12 query heads = 2 KV heads x 6
 SLOTS, CACHE = 4, 80                   # the serve CLI: 4 slots, 64 + 16 pos
+QP_SLOTS, QP_BUCKET = 16, 64           # the benchmark's slots and prompts
+# the seven packed matmuls of one qwen2-1.5b layer, (K, N)
+QP_MATS = {"q": (D, D), "k": (D, KV * HD), "v": (D, KV * HD), "o": (D, D),
+           "gate": (D, FF), "up": (D, FF), "down": (FF, D)}
 BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 
 
@@ -87,10 +93,11 @@ def _attn_prefill(t, s, kv_dtype):
 
 
 CASES = {
-    # batched decode (M = slots) and bucketed prefill (M = slots x 16)
-    # through the packed-container qp kernel, the wide MLP projections
-    "qmatvec_decode_up": lambda: _qmatvec(SLOTS, D, FF),
-    "qmatvec_prefill_down": lambda: _qmatvec(SLOTS * 16, FF, D),
+    # bucketed prefill (M = slots x bucket) through the packed-container
+    # qp kernel, the widest K; batched decode at the CLI's 4 slots, and at
+    # the benchmark's 16 below, each matrix
+    "qmatvec_prefill_down": lambda: _qmatvec(QP_SLOTS * QP_BUCKET, FF, D),
+    "qmatvec_decode_up_cli": lambda: _qmatvec(SLOTS, D, FF),
     # levels-form kernel: an MLP projection and the tied 8-bit readout
     "qmatmul_up": lambda: _qmatmul(SLOTS, D, FF),
     "qmatmul_readout": lambda: _qmatmul(SLOTS, D, VOCAB),
@@ -101,6 +108,9 @@ CASES = {
     "attn_prefill_bucket": lambda: _attn_prefill(16, 16, BF16),
     "attn_prefill_verify_int8": lambda: _attn_prefill(5, CACHE, I8),
 }
+CASES.update({f"qmatvec_decode_{name}":
+              (lambda k=k, n=n: _qmatvec(QP_SLOTS, k, n))
+              for name, (k, n) in QP_MATS.items()})
 
 
 # the instruction name each kernel's custom call carries: a chip trace's
@@ -136,3 +146,19 @@ def test_custom_call_carries_kernel_name(kernel, one_chip):
     assert any(ln.split(" = ", 1)[0].split()[-1].startswith(f"%{kernel}.")
                for ln in calls), calls
 
+
+@pytest.mark.parametrize("m", [1, QP_SLOTS, QP_SLOTS * QP_BUCKET])
+@pytest.mark.parametrize("name", sorted(QP_MATS))
+def test_qmatvec_blocks_cover_kp(name, m):
+    """One K block where KP is small (K 1536: KP 154 in one 160-word block,
+    not two of 128 over a 256-word pad), a divisor of KP otherwise (K 8960:
+    KP 896); N blocks divide N; decode rows ride in one M block."""
+    k, n = QP_MATS[name]
+    kp = -(-k // FIELDS)
+    bm, bn, bkp = qmatvec_blocks(m, k, n)
+    if k == D:
+        assert (kp, bkp) == (154, 160)
+    else:
+        assert kp == 896 and kp % bkp == 0 and bkp % 128 == 0
+    assert n % bn == 0 and bn % 128 == 0
+    assert bm == min(m, 256) and m % bm == 0

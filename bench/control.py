@@ -81,7 +81,7 @@ def judged(workload: str, seeds, seconds: float, control: str = "fp8",
     for seed in seeds:
         args = argparse.Namespace(workload=workload, seed=seed,
                                   seconds=seconds, trace=0)
-        res = run.run(args, bench_file, require_tpu, control=control)
+        res, _ = run.run(args, bench_file, require_tpu, control=control)
         gc.collect()
         row = {"seed": seed, "control": control, "correct": res["correct"],
                "checks": res["checks"]}

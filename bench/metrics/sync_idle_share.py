@@ -1,6 +1,6 @@
 """Share of the traced window in which the device is idle while the engine
-syncs (idle gaps that the engine's ``serve.sync.wait`` or
-``serve.sync.host`` span overlaps most), in percent (device)."""
+syncs (the part of the idle gaps that the engine's ``serve.sync.wait`` and
+``serve.sync.host`` spans cover), in percent (engine host loop)."""
 
 
 def read(rec):

@@ -117,13 +117,24 @@ class CompileCounter:
 
 
 def warm_up(eng, mix: dict, max_len: int):
-    """Compile every program the window will run: one admission per bucket
-    the mix's prompts can fall in, and decode ticks."""
+    """Compile every program the window will run (decode ticks, and one
+    admission per bucket the mix's prompts can fall in), then admit the
+    two longest buckets back to back with every program compiled, as two
+    requests that come together in the window are admitted. An admission's
+    prefill hands the cache it fills to the insert as one buffer of the
+    whole cache's size, made when the prefill is dispatched and freed when
+    the insert has run: the second admission's is made while the first's is
+    still held, which is the peak of device memory the window reaches in
+    about half of its runs, and set-up then holds it in every run. Two is
+    what ``decode_open``'s window queues at once; a cell that queues more
+    or fewer needs its own count (see bench/README.md)."""
     from bench import loop, traffic
-    for b in traffic.buckets(mix, lambda n: loop.bucket_of(n, max_len)):
-        eng.submit([1] * min(b, mix["prompt_len"]["max"]),
-                   max_new=eng.drain_every + 2)
-    eng.run_all()
+    buckets = traffic.buckets(mix, lambda n: loop.bucket_of(n, max_len))
+    for group in (buckets, buckets[:-3:-1]):
+        for b in group:
+            eng.submit([1] * min(b, mix["prompt_len"]["max"]),
+                       max_new=eng.drain_every + 2)
+        eng.run_all()
 
 
 def sample_requests(win, conf: dict, seed: int):
@@ -210,13 +221,21 @@ def check_devices(cell: dict, require_tpu: bool):
     return devs
 
 
+def counter_delta(c0: dict, c1: dict) -> dict:
+    """What each engine counter added between two readings."""
+    return {k: c1[k] - c0.get(k, 0) for k in c1}
+
+
 def run(args, bench_file: Path, require_tpu: bool = True,
-        engine_hook=None, control: str = None) -> dict:
-    """One run; returns the result object (raises CellError). ``control``
-    (``bench/control.py --judge``; never in a benchmark run) puts that
-    control's first choices in the served tokens' place: the reference in a
-    lower precision, on the same prompts and tokens, judged by the same
-    checks."""
+        engine_hook=None, control: str = None,
+        traced_seconds: float = None):
+    """One run; returns the result object and the run's record, from which
+    the metrics are read (raises CellError). ``control`` (``bench/control.py
+    --judge``; never in a benchmark run) puts that control's first choices
+    in the served tokens' place: the reference in a lower precision, on the
+    same prompts and tokens, judged by the same checks. ``traced_seconds``
+    (``bench/serve_trace.py``; never in a benchmark run) traces that much
+    of the window's tail in place of ``TRACE_SECONDS``."""
     sys.path.insert(0, str(ROOT / "src"))
     try:
         from repro.launch.compile_cache import enable_compile_cache
@@ -247,18 +266,22 @@ def run(args, bench_file: Path, require_tpu: bool = True,
         f"{len(items)} requests drawn, trace counts {traces0}")
 
     win = loop.Window(eng, mix, items, args.seconds)
+    if traced_seconds is None:
+        traced_seconds = min(TRACE_SECONDS, args.seconds / 2) \
+            if args.trace else 0.0
     tracer = devtrace.Tracer(OUT / f"trace-{args.workload}") \
-        if args.trace else None
+        if traced_seconds > 0 else None
     on_tick = None
     if tracer is not None:
-        t_from = min(TRACE_SECONDS, args.seconds / 2)
-
         def on_tick(now):
-            if not tracer.started and now >= win.t0 + args.seconds - t_from:
+            if not tracer.started and \
+                    now >= win.t0 + args.seconds - traced_seconds:
                 tracer.start()
+    c0 = eng.counters()
     counter.armed = True
     win.run(on_tick)
     counter.armed = False
+    counters = counter_delta(c0, eng.counters())
     dev = device_info(devs)
     traces1 = eng.trace_counts()
     fallbacks = list(eng.fallback_events)
@@ -296,7 +319,7 @@ def run(args, bench_file: Path, require_tpu: bool = True,
     }
     correct = all(c["value"] <= c["limit"] for c in checks.values())
 
-    rec = {"window": wrec, "setup_s": setup_s,
+    rec = {"window": wrec, "setup_s": setup_s, "counters": counters,
            "memory": {"peak_bytes": dev["memory_peak_bytes"],
                       "setup_peak_bytes": setup_peak},
            "trace": trace, "model": conf["model"], "serve": conf["serve"],
@@ -320,7 +343,7 @@ def run(args, bench_file: Path, require_tpu: bool = True,
         for r in wrec["requests"]])
     (OUT / f"{args.workload}.seed{args.seed}.trace{args.trace}.json"
      ).write_text(json.dumps(side, default=float))
-    return result
+    return result, rec
 
 
 def main(argv=None, bench_file: Path = ROOT / "BENCHMARK.json",
@@ -333,7 +356,7 @@ def main(argv=None, bench_file: Path = ROOT / "BENCHMARK.json",
     args = ap.parse_args(argv)
     sys.path.insert(0, str(ROOT))
     try:
-        result = run(args, bench_file, require_tpu, engine_hook)
+        result, _ = run(args, bench_file, require_tpu, engine_hook)
     except CellError as e:
         log(f"bench: {e}")
         return 1

@@ -7,18 +7,26 @@ from bench import devtrace
 MODULES = [["jit__tick(1)", 1000, 4000], ["jit__prefill(2)", 5000, 7000],
            ["jit__admit_many(3)", 7000, 7500], ["jit__tick(1)", 8000, 10000],
            ["jit__tick(1)", 10500, 12000]]
+# (HLO event name, t0, t1, scope): the scope as load reads it from the op
+# name in the .trace.json.gz
 RAW_OPS = [
-    ("%while.4 = (s32[]) while((s32[]) %t), body=%b", 1000, 4000),
+    ("%while.4 = (s32[]) while((s32[]) %t), body=%b", 1000, 4000,
+     "unscoped"),
     ("%qmatvec_pallas.60 = bf16[16,8960] custom-call(bf16[16,2560] %p)",
-     1000, 2000),
-    ("%fusion.16 = f32[16] fusion(f32[16] %x)", 2000, 3500),
-    ("%qmatvec_pallas.61 = bf16[4096,8960] custom-call(%p)", 5000, 6500),
-    ("%attn_prefill_pallas.3 = bf16[16] custom-call(%p)", 6500, 7000),
-    ("%copy.19 = s32[16] copy(%a)", 7000, 7200),
-    ("%qmatvec_pallas.60 = bf16[16,8960] custom-call(%p)", 8000, 9000),
-    ("%attn_decode_pallas.8 = bf16[16] custom-call(%p)", 9000, 9500),
-    ("%qmatvec_pallas.60 = bf16[16,8960] custom-call(%p)", 10500, 11500),
-    ("%reduce.3 = f32[] reduce(%p)", 12500, 13000),
+     1000, 2000, "model.mlp"),
+    ("%fusion.16 = f32[16] fusion(f32[16] %x)", 2000, 3500, "tick.sample"),
+    ("%qmatvec_pallas.61 = bf16[4096,8960] custom-call(%p)", 5000, 6500,
+     "model.mlp"),
+    ("%attn_prefill_pallas.3 = bf16[16] custom-call(%p)", 6500, 7000,
+     "model.attention"),
+    ("%copy.19 = s32[16] copy(%a)", 7000, 7200, "unscoped"),
+    ("%qmatvec_pallas.60 = bf16[16,8960] custom-call(%p)", 8000, 9000,
+     "model.attn_qkv"),
+    ("%attn_decode_pallas.8 = bf16[16] custom-call(%p)", 9000, 9500,
+     "model.attention"),
+    ("%qmatvec_pallas.60 = bf16[16,8960] custom-call(%p)", 10500, 11500,
+     "model.mlp"),
+    ("%reduce.3 = f32[] reduce(%p)", 12500, 13000, "unscoped"),
 ]
 HOST = [["engine.step", 900, 1200], ["engine.drain", 3600, 4900],
         ["engine.step", 7400, 8100], ["bench.wait_arrival", 9400, 10400]]
@@ -40,6 +48,7 @@ def test_op_names_and_programs(plain):
     assert not any(o[0] == "while" for o in ops)
     assert [o[3] for o in ops] == ["tick", "tick", "prefill", "prefill",
                                    "admit", "tick", "tick", "tick", "other"]
+    assert [o[4] for o in ops][:2] == ["model.mlp", "tick.sample"]
 
 
 def test_reduce_busy_idle_programs_kernels(plain):
@@ -48,11 +57,12 @@ def test_reduce_busy_idle_programs_kernels(plain):
     assert r["window_s"] == pytest.approx(10000 * ns)
     # busy: 1000-3500, 5000-7200, 8000-9500, 10500-11000 (clipped)
     assert r["busy_s"] == pytest.approx((2500 + 2200 + 1500 + 500) * ns)
-    # idle gaps: 3500-5000 (drain), 7200-8000 (step 7400-8100 covers 600),
-    # 9500-10500 (wait_arrival covers 900)
+    # idle gaps: 3500-5000 (drain 3600-4900 covers 1300), 7200-8000 (step
+    # 7400-8100 covers 600), 9500-10500 (wait_arrival covers 900); no span
+    # covers the other 500
     assert r["idle_by_span"] == pytest.approx(
-        {"engine.drain": 1500 * ns, "engine.step": 800 * ns,
-         "bench.wait_arrival": 1000 * ns})
+        {"engine.drain": 1300 * ns, "engine.step": 600 * ns,
+         "bench.wait_arrival": 900 * ns, "other": 500 * ns})
     progs = r["programs"]
     assert progs["tick"]["n"] == 2            # the third is cut by the window
     assert progs["tick"]["s_whole"] == pytest.approx(5000 * ns)
@@ -68,6 +78,38 @@ def test_reduce_busy_idle_programs_kernels(plain):
     assert top["tick:qmatvec"] == pytest.approx(2500 * ns)
     assert top["tick:fusion"] == pytest.approx(1500 * ns)
     assert r["breakdown"]["idle_gaps"][0][0] == "engine.drain"
+
+
+@pytest.mark.parametrize("op, kernel", [
+    ("qmatvec_pallas", "qmatvec"), ("qmatmul_pallas", "qmatmul"),
+    ("attn_decode_pallas", "attn_decode"),
+    ("attn_prefill_pallas", "attn_prefill"), ("newkern_pallas", "newkern"),
+    ("fusion", None), ("_pallas", None), ("pallas", None)])
+def test_kernel_of(op, kernel):
+    assert devtrace.kernel_of(op) == kernel
+
+
+def test_every_pallas_kernel_by_its_name(plain):
+    """A kernel no table names is reduced by its custom call's name; the
+    labels of the kernels before it are unchanged."""
+    raw = RAW_OPS + [
+        ("%newkern_pallas.7 = bf16[16,8] custom-call(%p)", 9500, 9700,
+         "model.mlp"),
+        ("%newkern_pallas.7 = bf16[16,8] custom-call(%p)", 3500, 3800,
+         "model.mlp")]
+    r = devtrace.reduce(dict(plain,
+                             ops=devtrace.attribute(sorted(MODULES), raw)))
+    k = r["kernels"]
+    assert k["tick:newkern"]["n"] == 2
+    assert k["tick:newkern"]["s"] == pytest.approx(500 * 1e-9)
+    assert set(k) == {"tick:qmatvec", "prefill:qmatvec",
+                      "prefill:attn_prefill", "tick:attn_decode",
+                      "tick:newkern"}
+    assert k["tick:qmatvec"]["n"] == 3
+    assert dict(r["breakdown"]["device_ops"])["tick:newkern"] == \
+        pytest.approx(500 * 1e-9)
+    assert dict(r["breakdown"]["tick_scopes"])["model.mlp:newkern"] == \
+        pytest.approx(500 * 1e-9)
 
 
 def test_metric_readers_on_the_reduced_trace(plain):

@@ -1,7 +1,7 @@
-"""The reduction of the engine's own spans and scopes, on the hand-worked
-trace of test_devtrace.py with ``serve.*`` spans and scoped ops added (times
-in ns); the three readers of what the engine records; and one instrumented
-window on the CPU."""
+"""``devtrace``'s reduction of the engine's own spans and scopes, on the
+hand-worked trace of test_devtrace.py with ``serve.*`` spans added (times in
+ns); the three readers of what the engine records; and one window of
+``serve_trace.py`` on the CPU."""
 import json
 
 import pytest
@@ -15,39 +15,35 @@ NS = 1e-9
 SPANS = [["serve.sync.wait", 3400, 4400], ["serve.sync.host", 4400, 4900],
          ["serve.admit", 5000, 7300], ["serve.tick", 7400, 7900],
          ["serve.tick", 8000, 8100]]
-# one per op left after control flow, in devtrace.attribute's order
-SCOPES = ["model.mlp", "tick.sample", "model.mlp", "model.attention",
-          "unscoped", "model.attn_qkv", "model.attention", "model.mlp",
-          "unscoped"]
 
 
 @pytest.fixture
 def plain():
-    ops = devtrace.attribute(sorted(MODULES), RAW_OPS)
     return {"window": [1000, 11000], "host": HOST, "modules": MODULES,
-            "ops": ops, "spans": SPANS,
-            "scopes": [[sc, a, b, prog]
-                       for sc, (_, a, b, prog) in zip(SCOPES, ops)]}
+            "ops": devtrace.attribute(sorted(MODULES), RAW_OPS),
+            "spans": SPANS}
 
 
 def test_idle_by_program_span(plain):
-    r = serve_trace.reduce(plain)
-    # 3500-5000: wait overlaps 900, host 500; 7200-8000: admit 100, tick
-    # 500; 9500-10500: no serve.* span
+    r = devtrace.reduce(plain)
+    # 3500-5000: wait covers 900, host 500, none 100; 7200-8000: admit
+    # 100, tick 500, none 200; 9500-10500: no serve.* span
     assert r["idle_by_program_span"] == pytest.approx(
-        {"serve.sync.wait": 1500 * NS, "serve.tick": 800 * NS,
-         "none": 1000 * NS})
-    assert r["breakdown"]["idle_gaps_program"][0][0] == "serve.sync.wait"
+        {"serve.sync.wait": 900 * NS, "serve.sync.host": 500 * NS,
+         "serve.admit": 100 * NS, "serve.tick": 500 * NS,
+         "none": 1300 * NS})
+    assert [k for k, _ in r["breakdown"]["idle_gaps_program"][:2]] == \
+        ["none", "serve.sync.wait"]
 
 
 def test_program_idle_adds_up_to_devtrace_idle(plain):
-    mine = serve_trace.reduce(plain)["idle_by_program_span"]
-    theirs = devtrace.reduce(plain)["idle_by_span"]
+    r = devtrace.reduce(plain)
+    mine, theirs = r["idle_by_program_span"], r["idle_by_span"]
     assert sum(mine.values()) == pytest.approx(sum(theirs.values()))
 
 
 def test_scope_seconds(plain):
-    r = serve_trace.reduce(plain)
+    r = devtrace.reduce(plain)
     assert r["scope_s"] == pytest.approx(
         {"tick:model.mlp": 1500 * NS, "tick:tick.sample": 1500 * NS,
          "prefill:model.mlp": 1500 * NS, "prefill:model.attention": 500 * NS,
@@ -59,9 +55,13 @@ def test_scope_seconds(plain):
          "model.attention:attn_decode": 500 * NS})
 
 
-def test_nothing_to_reduce():
-    assert serve_trace.reduce({"window": None, "ops": [], "spans": [],
-                               "scopes": []}) is None
+def test_nothing_to_reduce(plain):
+    assert devtrace.reduce({"window": None, "host": [], "modules": [],
+                            "ops": [], "spans": SPANS}) is None
+    # no serve.* span in the trace: no idle by engine span
+    r = devtrace.reduce(dict(plain, spans=[]))
+    assert "idle_by_program_span" not in r
+    assert "idle_gaps_program" not in r["breakdown"]
 
 
 @pytest.mark.parametrize("op_name, scope", [
@@ -80,7 +80,7 @@ def test_nothing_to_reduce():
     ("", "unscoped"),
 ])
 def test_scope_of(op_name, scope):
-    assert serve_trace.scope_of(op_name) == scope
+    assert devtrace.scope_of(op_name) == scope
 
 
 def test_op_names_from_the_json_beside_the_xplane(tmp_path):
@@ -95,10 +95,10 @@ def test_op_names_from_the_json_beside_the_xplane(tmp_path):
     ]
     with gzip.open(tmp_path / "h.trace.json.gz", "wt") as f:
         json.dump({"traceEvents": events}, f)
-    names = serve_trace.op_names(tmp_path / "h.xplane.pb")
+    names = devtrace.op_names(tmp_path / "h.xplane.pb")
     assert names == {("fusion.16", "49215862500"):
                      "jit(_tick)/model.embed/broadcast_in_dim:"}
-    assert serve_trace.op_names(tmp_path / "none.xplane.pb") == {}
+    assert devtrace.op_names(tmp_path / "none.xplane.pb") == {}
 
 
 def _read(name, rec):
@@ -119,10 +119,11 @@ def test_counter_readers():
 
 
 def test_sync_idle_reader(plain):
-    tr = dict(devtrace.reduce(plain), **serve_trace.reduce(plain))
-    # 1500 ns of idle under the syncs in a 10000 ns window
-    assert _read("sync_idle_share", {"trace": tr}) == pytest.approx(15.0)
-    assert _read("sync_idle_share", {"trace": devtrace.reduce(plain)}) is None
+    tr = devtrace.reduce(plain)
+    # 1400 ns of idle under the syncs in a 10000 ns window
+    assert _read("sync_idle_share", {"trace": tr}) == pytest.approx(14.0)
+    no_spans = devtrace.reduce(dict(plain, spans=[]))
+    assert _read("sync_idle_share", {"trace": no_spans}) is None
     assert _read("sync_idle_share", {"trace": None}) is None
 
 
@@ -137,11 +138,12 @@ def test_load_finds_spans_in_a_cpu_trace(tmp_path):
                 pass
             with TraceAnnotation("engine.step"):
                 pass
-    tr = serve_trace.load(sorted(tmp_path.rglob("*.xplane.pb"))[-1])
+    tr = devtrace.load(sorted(tmp_path.rglob("*.xplane.pb"))[-1])
     assert sorted(n for n, _, _ in tr["spans"]) == ["serve.admit",
                                                      "serve.tick"]
+    assert [n for n, _, _ in tr["host"]] == ["engine.step"]
     assert tr["window"] is not None
-    assert len(tr["scopes"]) == len(tr["ops"])
+    assert all(len(o) == 5 for o in tr["ops"])
 
 
 def _probe(capsys, argv, require_tpu=False):
@@ -155,7 +157,7 @@ def test_probe_window_on_cpu(capsys):
     rc, res = _probe(capsys, ["--workload", "tiny.decode_open", "--seed",
                               "2147483749", "--seconds", "2",
                               "--traced-seconds", "1"])
-    assert rc == 0
+    assert rc == 0 and res["correct"]
     c, m = res["counters"], res["metrics"]
     assert c["decode_calls"] > 0 and c["admitted"] > 0
     assert m["prefill_row_use"]["value"] == pytest.approx(

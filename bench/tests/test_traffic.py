@@ -93,3 +93,41 @@ def test_bucket_rule_is_the_engines():
         for n in (1, 7, 8, 9, 16, 17, 100, 256, 1000):
             assert loop.bucket_of(n, cap) == \
                 ServingEngine._bucket_len(eng, n)
+
+
+@pytest.mark.parametrize("mix", ALL)
+def test_the_last_block_is_the_same_for_every_seed(mix):
+    """What arrives in a window's last seconds, and is cut by its close, is
+    one fixed block; the requests before it come in the seed's order."""
+    spec = traffic.load(MIXES / f"{mix}.json")
+    runs = [traffic.generate(spec, s, 51, 1000) for s in (1, 2**33 + 5)]
+    n = len(runs[0])
+    tail = n // -(-n // traffic.BLOCK)
+
+    def shape(r):
+        gaps = np.diff([0.0] + [x.due for x in r])
+        return [(round(g, 9), len(x.prompt), x.max_new)
+                for g, x in zip(gaps, r)]
+    a, b = (shape(r) for r in runs)
+    assert a[-tail:] == b[-tail:]
+    assert a[:-tail] != b[:-tail]
+
+
+@pytest.mark.parametrize("mix", ALL)
+def test_the_last_block_ranks_lengths_and_gaps_apart(mix):
+    """The fixed last block orders prompt lengths, answer lengths and gaps
+    each its own way: the longest prompt does not always carry the longest
+    answer after the longest gap."""
+    spec = traffic.load(MIXES / f"{mix}.json")
+    r = traffic.generate(spec, 2**33 + 5, 51, 1000)
+    n = len(r)
+    tail = n // -(-n // traffic.BLOCK)
+
+    def ranks(v):
+        return tuple(np.argsort(np.argsort(v[-tail:], kind="stable"),
+                                kind="stable"))
+    orders = [ranks([len(x.prompt) for x in r]),
+              ranks([x.max_new for x in r])]
+    if spec["loop"] == "open":
+        orders.append(ranks(np.diff([0.0] + [x.due for x in r])))
+    assert len(set(orders)) == len(orders)

@@ -58,3 +58,13 @@ def qmatvec_roofline_s(s: Shapes, m: int, peaks: dict) -> float:
         total += max(ops / peaks["bf16_flops"],
                      nbytes / peaks["hbm_bytes_per_s"])
     return total
+
+
+def prompt_flops(s: Shapes, n: int) -> int:
+    """Model operations of prefilling one prompt of ``n`` tokens: 2 per
+    layer weight per token, causal QK^T and PV over each token and those
+    before it, and the readout at its last token."""
+    layer_weights = s.layers * sum(k * n_ for _, k, n_, _ in s.matrices())
+    return 2 * layer_weights * n \
+        + 2 * s.layers * s.heads * s.head_dim * n * (n + 1) \
+        + 2 * s.vocab * s.d

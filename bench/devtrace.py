@@ -8,6 +8,7 @@ plain form that tests can write by hand:
     {"window": [t0, t1],                       # the bench.traced host span
      "host": [[name, t0, t1], ...],            # bench.* and engine.* spans
      "spans": [[name, t0, t1], ...],           # the engine's serve.* spans
+     "admits": [[t0, t1, bucket, rows], ...],  # serve.admit, with its args
      "modules": [[name, t0, t1], ...],         # XLA Modules line
      "ops": [[op, t0, t1, program, scope], ...]}   # XLA Ops line
 
@@ -26,7 +27,8 @@ read from the ``.trace.json.gz`` that the profiler writes beside the
 ops (``while``, ``conditional``, ``call``) span the ops of their bodies and
 are left out. ``reduce`` turns that into device busy time, idle gaps by
 host span and by engine span, time per program family, per kernel and per
-scope, and the ``breakdown`` of the result line.
+scope, each admission's prefill with its bucket, and the ``breakdown`` of
+the result line.
 """
 from __future__ import annotations
 
@@ -47,6 +49,7 @@ HOST_SPANS = ("bench.wait_arrival", "engine.submit", "engine.step",
 # the engine's own spans (src/repro/serving/engine.py)
 PROGRAM_SPANS = ("serve.admit", "serve.tick", "serve.sync.wait",
                  "serve.sync.host")
+ADMIT_SPAN = "serve.admit"     # args bucket, rows; one prefill dispatched
 KERNEL_SUFFIX = "_pallas"
 CONTROL_FLOW = ("while", "conditional", "call")
 SCOPE_PREFIXES = ("model.", "tick.")
@@ -137,8 +140,8 @@ def load(path) -> dict:
     """The plain form of one ``.xplane.pb`` (see the module doc)."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(str(path))
-    out = {"window": None, "host": [], "spans": [], "modules": [],
-           "ops": []}
+    out = {"window": None, "host": [], "spans": [], "admits": [],
+           "modules": [], "ops": []}
     device = None
     for plane in pd.planes:
         if plane.name.startswith("/device:") and device is None \
@@ -154,6 +157,11 @@ def load(path) -> dict:
                     out["host"].append([ev.name, ev.start_ns, ev.end_ns])
                 elif ev.name in PROGRAM_SPANS:
                     out["spans"].append([ev.name, ev.start_ns, ev.end_ns])
+                    if ev.name == ADMIT_SPAN:
+                        args = dict(ev.stats)
+                        out["admits"].append([ev.start_ns, ev.end_ns,
+                                              int(args["bucket"]),
+                                              int(args["rows"])])
     names = op_names(path) if device is not None else {}
     raw_ops = []
     for line in (device.lines if device is not None else ()):
@@ -220,6 +228,39 @@ def idle_by(gaps, spans, none: str) -> Dict[str, float]:
     return dict(out)
 
 
+def prefill_runs(tr: dict, w0: float, w1: float) -> Optional[list]:
+    """Each prefill program that ran wholly inside [w0, w1], with the
+    bucket and rows of the ``serve.admit`` span that dispatched it, its
+    device seconds and its kernels' seconds and calls. Each span dispatches
+    one prefill and the device runs programs in the order they came, so the
+    trace's j-th prefill is its j-th span's, provided that nothing
+    dispatched before the trace began was still to run (``bench/run.py``
+    waits for the device before it starts a trace). None where the trace
+    holds more prefills than spans, so that the two cannot be paired."""
+    runs = [m for m in sorted(tr["modules"], key=lambda m: m[1])
+            if _family(m[0]) == "prefill"]
+    admits = sorted(tr.get("admits") or [])
+    if len(runs) > len(admits):
+        return None
+    ops = [o for o in tr["ops"] if o[3] == "prefill"]
+    starts = [o[1] for o in ops]
+    out = []
+    for (_, a, b), (_, _, bucket, rows) in zip(runs, admits):
+        if a < w0 or b > w1:
+            continue
+        kernels: Dict[str, Dict[str, float]] = {}
+        for op in ops[bisect.bisect_left(starts, a):
+                      bisect.bisect_right(starts, b)]:
+            kernel = kernel_of(op[0])
+            if kernel and op[2] <= b:
+                k = kernels.setdefault(kernel, {"s": 0.0, "n": 0})
+                k["s"] += (op[2] - op[1]) * 1e-9
+                k["n"] += 1
+        out.append({"bucket": bucket, "rows": rows, "s": (b - a) * 1e-9,
+                    "kernels": kernels})
+    return out
+
+
 def _top(d: Dict[str, float]) -> list:
     return [[k, v] for k, v in sorted(d.items(), key=lambda kv: -kv[1])
             ][:10]
@@ -230,8 +271,9 @@ def reduce(tr: dict) -> Optional[dict]:
     span (``idle_by_span``) and, where the trace holds the engine's spans,
     by engine span (``idle_by_program_span``); device time by program
     family, by kernel (time and calls), by ``program:scope`` and, in the
-    tick program, by ``scope:op``; and the breakdown's top-10 lists. None
-    when the trace holds no window or no device op."""
+    tick program, by ``scope:op``; each prefill wholly inside the window
+    with its admission's bucket (``prefill_runs``); and the breakdown's
+    top-10 lists. None when the trace holds no window or no device op."""
     if tr["window"] is None or not tr["ops"]:
         return None
     w0, w1 = tr["window"]
@@ -276,6 +318,7 @@ def reduce(tr: dict) -> Optional[dict]:
     idle = idle_by(gaps, tr["host"], "other")
     out = {"window_s": (w1 - w0) * ns, "busy_s": busy_ns * ns,
            "idle_by_span": idle, "programs": programs, "kernels": kernels,
+           "prefill_runs": prefill_runs(tr, w0, w1),
            "scope_s": dict(scope_s),
            "tick_scope_ops": dict(tick_scope_ops),
            "breakdown": {"device_ops": _top(by_op), "idle_gaps": _top(idle),

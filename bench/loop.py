@@ -34,6 +34,7 @@ class Track:
     last: Optional[float] = None
     gaps: List[float] = dataclasses.field(default_factory=list)
     tokens: int = 0                  # tokens that became visible in window
+    admitted: Optional[float] = None  # host clock after the admitting step
     tick_tokens: int = 0             # of those, made by decode ticks
     context: int = 0                 # sum of attended positions of those
     finished: Optional[float] = None
@@ -127,9 +128,11 @@ class Window:
             if on_tick is not None:
                 on_tick(now)
             self._submit_due(now)
-            ticks = eng.decode_calls
+            ticks, admitted = eng.decode_calls, eng.admitted
             with TraceAnnotation("engine.step"):
                 eng.step()
+            if eng.admitted != admitted:
+                self._stamp_admitted(self.clock())
             ticked = eng.decode_calls != ticks
             if not ticked or eng.decode_calls % every == 0:
                 with TraceAnnotation("engine.drain"):
@@ -139,6 +142,13 @@ class Window:
                 self._wait(t_end)
         self.t1 = self.clock()
         self.c1 = (eng.decode_calls, eng.prefill_calls)
+
+    def _stamp_admitted(self, now: float):
+        """Stamp the requests that the last step took off the queue."""
+        queued = {id(r) for r in self.eng.queue}
+        for tr in self.inflight.values():
+            if tr.admitted is None and id(tr.req) not in queued:
+                tr.admitted = now
 
     def _wait(self, t_end: float):
         if self.spec["loop"] != "open":
@@ -156,6 +166,8 @@ class Window:
             reqs.append({
                 "uid": tr.req.uid, "due": tr.due - t0,
                 "prompt_len": tr.prompt_len, "max_new": tr.req.max_new,
+                "admitted": None if tr.admitted is None
+                else tr.admitted - t0,
                 "first": None if tr.first is None else tr.first - t0,
                 "finished": None if tr.finished is None else tr.finished - t0,
                 "status": tr.req.status if tr.finished is not None else None,

@@ -7,7 +7,8 @@ per layer RMSNorm, Q/K/V projections with bias, rotary embedding
 1/sqrt(head_dim), output projection, residual, RMSNorm, SwiGLU MLP,
 residual; a final RMSNorm; a readout through the embedding table when it is
 tied, or through a head. Everything runs in float32 under
-``default_matmul_precision("highest")``.
+``default_matmul_precision("highest")``, a few requests at a time and
+attention in blocks of queries, so that it fits one chip at 4k prompts.
 
 The weights are the benchmark's (``bench.weights``), regenerated from the
 seed one layer at a time and dequantized here. The serving policy's
@@ -40,6 +41,11 @@ import numpy as np
 from bench import weights as W
 
 PRECISIONS = ("f32", "fp8", "kv8")
+# requests through the layers at once, and queries per attention block:
+# at 4 x 4223 positions a block's float32 scores are 4 x 12 x 512 x 4223 x
+# 4 B = 0.42 GB, where all queries at once would be 3.4 GB a layer
+ROWS_PER_PASS = 4
+Q_BLOCK = 512
 
 
 def _round(x, precision):
@@ -83,8 +89,38 @@ def _act8(h, region_a, row_a):
     return jnp.clip(jnp.round(h / scale), -127, 127) * scale
 
 
-@partial(jax.jit, static_argnames=("dims", "precision"))
-def _layer(x, lw, pos, mask, region_a, row_a, *, dims, precision):
+def _attend(qg, k, v, mask, r):
+    """Causal grouped-query attention of the queries ``qg`` (B, Q, KV, G, D)
+    over every key and value (B, S, KV, D); ``mask`` (B, Q, S)."""
+    sc = jnp.einsum("bqkgd,bskd->bkgqs", qg, k)
+    sc = jnp.where(mask[:, None, None], sc, -jnp.inf)
+    p = r(jax.nn.softmax(sc, -1))
+    return jnp.einsum("bkgqs,bskd->bqkgd", p, v)
+
+
+def _attention(qg, k, v, mask, r, q_block):
+    """``_attend`` over blocks of ``q_block`` queries, one block at a time,
+    so that the float32 scores held at once are (B, KV, G, q_block, S) and
+    not (B, KV, G, S, S); each query's arithmetic is the same either way.
+    ``q_block`` None: all queries in one block."""
+    b, s = qg.shape[:2]
+    if q_block is None or q_block >= s:
+        return _attend(qg, k, v, mask, r)
+    nb = -(-s // q_block)
+    pad = nb * q_block - s
+    # padded queries see every key, so that no softmax is over nothing
+    qp = jnp.pad(qg, ((0, 0), (0, pad)) + ((0, 0),) * 3)
+    mp = jnp.pad(mask, ((0, 0), (0, pad), (0, 0)), constant_values=True)
+    qb = jnp.swapaxes(qp.reshape(b, nb, q_block, *qg.shape[2:]), 0, 1)
+    mb = jnp.swapaxes(mp.reshape(b, nb, q_block, mask.shape[-1]), 0, 1)
+    ob = jax.lax.map(lambda a: _attend(a[0], k, v, a[1], r), (qb, mb))
+    return jnp.swapaxes(ob, 0, 1).reshape(b, nb * q_block,
+                                          *ob.shape[3:])[:, :s]
+
+
+@partial(jax.jit, static_argnames=("dims", "precision", "q_block"))
+def _layer(x, lw, pos, mask, region_a, row_a, *, dims, precision,
+           q_block=None):
     heads, kv_heads, hd, eps, theta = dims
     b, s, _ = x.shape
     r = partial(_round, precision=precision)
@@ -96,10 +132,7 @@ def _layer(x, lw, pos, mask, region_a, row_a, *, dims, precision):
     k, v = _kv_round(k, precision), _kv_round(v, precision)
     g = heads // kv_heads
     qg = q.reshape(b, s, kv_heads, g, hd) / np.sqrt(hd)
-    sc = jnp.einsum("bqkgd,bskd->bkgqs", qg, k)
-    sc = jnp.where(mask[:, None, None], sc, -jnp.inf)
-    p = r(jax.nn.softmax(sc, -1))
-    o = r(jnp.einsum("bkgqs,bskd->bqkgd", p, v).reshape(b, s, heads * hd))
+    o = r(_attention(qg, k, v, mask, r, q_block).reshape(b, s, heads * hd))
     x = r(x + r(o @ lw["wo"]))
     hn = r(_rmsnorm(x, lw["ln2"], eps))
     h = r(jax.nn.silu(r(hn @ lw["gate"])) * r(hn @ lw["up"]))
@@ -164,12 +197,24 @@ class Reference:
             score_at[i, 1:] = amax + np.arange(bmax)
         return toks, pos, mask, region_a, row_a, score_at
 
-    def hidden(self, reqs, precision: str = "f32", shape=None) -> jax.Array:
+    def hidden(self, reqs, precision: str = "f32", shape=None,
+               rows: int = ROWS_PER_PASS,
+               q_block: int = Q_BLOCK) -> jax.Array:
         """Final-normed hidden states at every scored position,
-        (rows, bmax + 1, D) float32."""
+        (rows, bmax + 1, D) float32. The batch goes through the layers
+        ``rows`` requests at a time, and attention ``q_block`` queries at a
+        time (None: all at once), so that what is held at once fits beside
+        nothing else on one chip at the cell's longest prompts."""
         assert precision in PRECISIONS, precision
+        layout = self._layout(reqs, shape)
+        n = layout[0].shape[0]
+        return jnp.concatenate([
+            self._hidden_rows([a[i:i + rows] for a in layout], precision,
+                              q_block) for i in range(0, n, rows)])
+
+    def _hidden_rows(self, layout, precision, q_block) -> jax.Array:
         s = self.s
-        toks, pos, mask, region_a, row_a, score_at = self._layout(reqs, shape)
+        toks, pos, mask, region_a, row_a, score_at = layout
         r = partial(_round, precision=precision)
         with jax.default_matmul_precision("highest"):
             eq, ed = W.embed_table(self.root, s)
@@ -178,8 +223,8 @@ class Reference:
             dims = (s.heads, s.kv_heads, s.head_dim, self.eps, self.theta)
             args = tuple(jnp.asarray(a) for a in (pos, mask, region_a, row_a))
             for layer in range(s.layers):
-                x = _layer(x, _layer_weights(self.root, layer, s), *args, dims=dims,
-                           precision=precision)
+                x = _layer(x, _layer_weights(self.root, layer, s), *args,
+                           dims=dims, precision=precision, q_block=q_block)
             x = jnp.take_along_axis(x, jnp.asarray(score_at)[..., None], 1)
             fn = W.norm_scale(self.root, "final_norm", 0, s.d)
             return r(_rmsnorm(x, fn, self.eps))

@@ -51,7 +51,9 @@ def log(msg: str):
 def load_cell(name: str, bench_file: Path) -> dict:
     """The cell's entry, configuration, mix and metrics, found by name. The
     mix is ``traffic/<name>.json`` beside ``bench_file`` where that
-    directory exists (the tests' own mixes), else in ``bench/traffic``."""
+    directory exists (the tests' own mixes), else in ``bench/traffic``. A
+    metric with ``workloads`` is the listed cells'; a per-layer metric is
+    also only that of cells that report the end-to-end metric it moves."""
     spec = json.loads(bench_file.read_text())
     cells = {w["name"]: w for w in spec["workloads"]}
     if name not in cells:
@@ -67,16 +69,30 @@ def load_cell(name: str, bench_file: Path) -> dict:
     mixes = bench_file.parent / "traffic"
     if not mixes.is_dir():
         mixes = BENCH / "traffic"
+    end_to_end = [m for m in spec["end_to_end"] if mine(m)]
+    reported = {m["name"] for m in end_to_end}
     return {"cell": cell, "config": config,
             "traffic": traffic.load(mixes / f"{cell['traffic']}.json"),
-            "end_to_end": [m for m in spec["end_to_end"] if mine(m)],
-            "per_layer": [m for m in spec["per_layer"] if mine(m)]}
+            "end_to_end": end_to_end,
+            "per_layer": [m for m in spec["per_layer"]
+                          if mine(m) and m.get("moves", "") in reported
+                          | {""}]}
+
+
+def metric_file(name: str) -> Path:
+    """``bench/metrics/<name>.py``. A metric split by the end-to-end metric
+    it moves, ``<base>.<suffix>`` with no file of its own, is read by
+    ``<base>.py`` (``prefill_share.ttft`` by ``prefill_share.py``)."""
+    path = BENCH / "metrics" / f"{name}.py"
+    if not path.is_file() and "." in name:
+        path = BENCH / "metrics" / f"{name.rsplit('.', 1)[0]}.py"
+    return path
 
 
 def read_metric(name: str, rec: dict):
-    """Run ``bench/metrics/<name>.py``'s ``read(rec)``; None = nothing to
-    read, and the metric is left out."""
-    path = BENCH / "metrics" / f"{name}.py"
+    """Run the metric's file's ``read(rec)`` (``metric_file``); None =
+    nothing to read, and the metric is left out."""
+    path = metric_file(name)
     spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
                                                   path)
     mod = importlib.util.module_from_spec(spec)
@@ -116,21 +132,25 @@ class CompileCounter:
             self.events.append(event)
 
 
-def warm_up(eng, mix: dict, max_len: int):
+WARM_ADMISSIONS = 2       # queued admissions warmed up, where unstated
+
+
+def warm_up(eng, mix: dict, max_len: int, queued: int = WARM_ADMISSIONS):
     """Compile every program the window will run (decode ticks, and one
     admission per bucket the mix's prompts can fall in), then admit the
-    two longest buckets back to back with every program compiled, as two
+    ``queued`` longest buckets back to back with every program compiled, as
     requests that come together in the window are admitted. An admission's
     prefill hands the cache it fills to the insert as one buffer of the
     whole cache's size, made when the prefill is dispatched and freed when
-    the insert has run: the second admission's is made while the first's is
-    still held, which is the peak of device memory the window reaches in
-    about half of its runs, and set-up then holds it in every run. Two is
-    what ``decode_open``'s window queues at once; a cell that queues more
-    or fewer needs its own count (see bench/README.md)."""
+    the insert has run: each later admission's is made while the earlier
+    ones' are still held, which is the peak of device memory the window
+    reaches when it queues that many, and set-up then holds it in every
+    run. The count is the configuration's ``warm_admissions``, 2 where the
+    file has none: what ``decode_open``'s window queues at once (see
+    bench/README.md)."""
     from bench import loop, traffic
     buckets = traffic.buckets(mix, lambda n: loop.bucket_of(n, max_len))
-    for group in (buckets, buckets[:-3:-1]):
+    for group in (buckets, buckets[::-1][:queued]):
         for b in group:
             eng.submit([1] * min(b, mix["prompt_len"]["max"]),
                        max_new=eng.drain_every + 2)
@@ -205,7 +225,8 @@ def build_engine(conf: dict, mix: dict, seed: int, engine_hook=None):
         attn_mode=serve["attn_mode"], degrade=False)
     if engine_hook is not None:
         engine_hook(eng)
-    warm_up(eng, mix, serve["max_len"])
+    warm_up(eng, mix, serve["max_len"],
+            conf.get("warm_admissions", WARM_ADMISSIONS))
     return eng
 
 
@@ -269,14 +290,20 @@ def run(args, bench_file: Path, require_tpu: bool = True,
     if traced_seconds is None:
         traced_seconds = min(TRACE_SECONDS, args.seconds / 2) \
             if args.trace else 0.0
-    tracer = devtrace.Tracer(OUT / f"trace-{args.workload}") \
-        if traced_seconds > 0 else None
-    on_tick = None
+    # one directory per run, so that runs side by side do not share one
+    tracer = devtrace.Tracer(OUT / f"trace-{args.workload}.seed{args.seed}"
+                             ) if traced_seconds > 0 else None
+    on_tick, traced_from = None, []
     if tracer is not None:
         def on_tick(now):
             if not tracer.started and \
                     now >= win.t0 + args.seconds - traced_seconds:
+                # nothing dispatched before the trace is left to run, so
+                # that each prefill it holds is paired with its admission
+                # (devtrace.prefill_runs)
+                jax.block_until_ready(eng.cache)
                 tracer.start()
+                traced_from.append(win.clock() - win.t0)
     c0 = eng.counters()
     counter.armed = True
     win.run(on_tick)
@@ -286,6 +313,9 @@ def run(args, bench_file: Path, require_tpu: bool = True,
     traces1 = eng.trace_counts()
     fallbacks = list(eng.fallback_events)
     wrec = win.record()
+    if tracer is not None and tracer.started:
+        # and what the window dispatched runs to its end inside the trace
+        jax.block_until_ready(eng.cache)
     trace = tracer.stop() if tracer is not None else None
     log(f"window {wrec['window_s']:.3f}s: {wrec['decode_calls']} ticks, "
         f"{wrec['prefill_calls']} prefill calls, {wrec['attempted']} "
@@ -322,7 +352,9 @@ def run(args, bench_file: Path, require_tpu: bool = True,
     rec = {"window": wrec, "setup_s": setup_s, "counters": counters,
            "memory": {"peak_bytes": dev["memory_peak_bytes"],
                       "setup_peak_bytes": setup_peak},
-           "trace": trace, "model": conf["model"], "serve": conf["serve"],
+           "trace": trace,
+           "traced_from_s": traced_from[0] if traced_from else None,
+           "model": conf["model"], "serve": conf["serve"],
            "shapes": shapes, "peaks": peaks.get(devs[0].device_kind)}
     wanted = cell["per_layer"] if args.trace else cell["end_to_end"]
     metrics = {}

@@ -132,3 +132,26 @@ def test_no_window_or_no_device_reads_nothing():
                             "ops": []}) is None
     assert devtrace.reduce({"window": [0, 10], "host": [], "modules": [],
                             "ops": []}) is None
+
+
+def test_prefill_runs_pair_each_prefill_with_its_admission(plain):
+    # one prefill program (5000-7000) and the span that dispatched it
+    tr = dict(plain, admits=[[4800, 4900, 64, 3]])
+    runs = devtrace.reduce(tr)["prefill_runs"]
+    assert len(runs) == 1
+    run = runs[0]
+    assert (run["bucket"], run["rows"]) == (64, 3)
+    assert run["s"] == pytest.approx(2000e-9)
+    assert run["kernels"]["qmatvec"] == {"s": pytest.approx(1500e-9),
+                                         "n": 1}
+    assert run["kernels"]["attn_prefill"]["n"] == 1
+    # a later admission whose prefill had not run when the trace stopped
+    more = devtrace.reduce(dict(tr, admits=tr["admits"] + [[9000, 9100,
+                                                            256, 1]]))
+    assert more["prefill_runs"] == runs
+    # a prefill cut by the window is left out
+    cut = devtrace.reduce(dict(tr, window=[6000, 11000]))
+    assert cut["prefill_runs"] == []
+    # a prefill with no span to pair it with: nothing is paired
+    assert devtrace.reduce(dict(tr, admits=[]))["prefill_runs"] is None
+    assert devtrace.reduce(plain)["prefill_runs"] is None

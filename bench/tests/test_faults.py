@@ -43,6 +43,17 @@ def half_batch_left_out(eng):
     _wrap_tick(eng, change)
 
 
+def first_token_altered(eng):
+    """Admission prefill's first token of each request is replaced by the
+    next id: its logits come out shifted by one along the vocabulary."""
+    prefill = eng._prefill_fn
+
+    def broken(*args):
+        logits0, src = prefill(*args)
+        return jnp.roll(logits0, 1, axis=-1), src
+    eng._prefill_fn = broken
+
+
 def compiles_in_window(eng):
     """A program that first compiles inside the window."""
     def change(e, before, out):
@@ -71,7 +82,7 @@ def test_sound_run_is_correct(capsys):
 
 @pytest.mark.parametrize("fault,check", [
     (state_unchanged, "served_gap"), (token_altered, "served_gap"),
-    (half_batch_left_out, "served_gap"),
+    (half_batch_left_out, "served_gap"), (first_token_altered, "served_gap"),
     (compiles_in_window, "window_compiles"), (falls_back, "fallbacks")])
 def test_fault_is_not_correct(capsys, fault, check):
     rc, res = harness(capsys, seed=4242, engine_hook=fault)

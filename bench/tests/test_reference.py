@@ -93,3 +93,19 @@ def test_fixed_shape_pads_without_changing_gaps(tiny_conf):
     b = R.gaps(tiny_conf["model"], 4, reqs, shape=(4, 64, 10))
     assert a["tokens"] == b["tokens"] == 6
     assert b["served"] == pytest.approx(a["served"], abs=1e-5)
+
+
+def test_query_blocks_and_row_groups_change_no_number(tiny_conf):
+    """Attention a few queries at a time, and the batch a few requests at a
+    time, as the reference runs at 4k prompts, against all at once."""
+    rng = np.random.default_rng(3)
+    reqs = [(rng.integers(1, 512, n).tolist(),
+             rng.integers(1, 512, t).tolist(), bk)
+            for n, t, bk in ((37, 9, 64), (5, 3, 8), (60, 12, 64))]
+    ref = R.Reference(tiny_conf["model"], 6)
+    whole = ref.hidden(reqs, rows=len(reqs), q_block=None)
+    for rows, q_block in ((1, 16), (2, 7), (3, 200)):
+        got = ref.hidden(reqs, rows=rows, q_block=q_block)
+        assert got.shape == whole.shape
+        np.testing.assert_allclose(np.asarray(got), np.asarray(whole),
+                                   rtol=0, atol=1e-5)

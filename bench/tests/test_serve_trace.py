@@ -146,6 +146,20 @@ def test_load_finds_spans_in_a_cpu_trace(tmp_path):
     assert all(len(o) == 5 for o in tr["ops"])
 
 
+def test_load_keeps_the_admissions_bucket_and_rows(tmp_path):
+    import jax
+    from jax.profiler import TraceAnnotation
+    with jax.profiler.trace(str(tmp_path)):
+        with TraceAnnotation("bench.traced"):
+            for bucket, rows in ((256, 1), (4096, 3)):
+                with TraceAnnotation("serve.admit", bucket=bucket,
+                                     rows=rows):
+                    jax.numpy.ones(4).block_until_ready()
+    tr = devtrace.load(sorted(tmp_path.rglob("*.xplane.pb"))[-1])
+    assert [a[2:] for a in tr["admits"]] == [[256, 1], [4096, 3]]
+    assert all(a[0] <= a[1] for a in tr["admits"])
+
+
 def _probe(capsys, argv, require_tpu=False):
     rc = serve_trace.main(argv, bench_file=DATA / "BENCHMARK.json",
                           require_tpu=require_tpu)

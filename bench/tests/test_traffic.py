@@ -84,6 +84,41 @@ def test_buckets_cover_the_prompt_range():
         [16, 32, 64, 128, 256]
 
 
+def test_prompt_open_draws_inside_its_ranges_and_the_4k_buckets():
+    spec = traffic.load(MIXES / "prompt_open.json")
+    bucket = lambda n: loop.bucket_of(n, 4224)          # noqa: E731
+    assert traffic.buckets(spec, bucket) == [256, 512, 1024, 2048, 4096]
+    for seed in (1, 2**33 + 5):
+        items = traffic.generate(spec, seed, 51, 151936)
+        plen = [len(x.prompt) for x in items]
+        olen = [x.max_new for x in items]
+        assert 256 <= min(plen) and max(plen) <= 4096
+        assert 8 <= min(olen) and max(olen) <= 128
+        assert {bucket(n) for n in plen} <= {256, 512, 1024, 2048, 4096}
+        assert max(plen) + max(olen) <= 4224
+
+
+@pytest.mark.parametrize("order, same", [("fixed", True), ("seed", False)])
+def test_a_fixed_order_is_every_seeds(order, same):
+    """``"order": "fixed"`` sends the same lengths and gaps in the same
+    order for every seed, and only the token ids change; the seed's order
+    is the default."""
+    spec = dict(traffic.load(MIXES / "prompt_open.json"), order=order)
+    if order == "seed":
+        del spec["order"]
+    a, b = (traffic.generate(spec, s, 51, 151936) for s in (3, 2**33 + 9))
+    shape = [[(x.due, len(x.prompt), x.max_new) for x in r] for r in (a, b)]
+    assert (shape[0] == shape[1]) == same
+    assert [x.prompt for x in a] != [x.prompt for x in b]
+
+
+def test_an_unknown_order_is_refused(tmp_path):
+    spec = json.loads((MIXES / "prompt_open.json").read_text())
+    (tmp_path / "m.json").write_text(json.dumps(dict(spec, order="trace")))
+    with pytest.raises(ValueError, match="order"):
+        traffic.load(tmp_path / "m.json")
+
+
 def test_bucket_rule_is_the_engines():
     from types import SimpleNamespace
 
@@ -98,7 +133,8 @@ def test_bucket_rule_is_the_engines():
 @pytest.mark.parametrize("mix", ALL)
 def test_the_last_block_is_the_same_for_every_seed(mix):
     """What arrives in a window's last seconds, and is cut by its close, is
-    one fixed block; the requests before it come in the seed's order."""
+    one fixed block; the requests before it come in the seed's order, or
+    in the one fixed order of an ``"order": "fixed"`` mix."""
     spec = traffic.load(MIXES / f"{mix}.json")
     runs = [traffic.generate(spec, s, 51, 1000) for s in (1, 2**33 + 5)]
     n = len(runs[0])
@@ -110,7 +146,7 @@ def test_the_last_block_is_the_same_for_every_seed(mix):
                 for g, x in zip(gaps, r)]
     a, b = (shape(r) for r in runs)
     assert a[-tail:] == b[-tail:]
-    assert a[:-tail] != b[:-tail]
+    assert (a[:-tail] == b[:-tail]) == (spec.get("order") == "fixed")
 
 
 @pytest.mark.parametrize("mix", ALL)

@@ -38,6 +38,15 @@ once for the answer lengths and once for the gaps, so that the three are
 not ranked alike in the last block either. An open loop makes
 n = rate x seconds requests; a closed loop cycles through a pool of
 ``pool`` requests.
+
+A mix with ``"order": "fixed"`` takes the whole order, every block's and
+not only the last, from a generator of its own (``ORDER_SEED``), the same
+for every seed, as a trace is replayed: the seed then draws the prompts'
+token ids alone. Where a queue forms behind long prefills, which requests
+come due together decides how long they wait, and the seed's order moved
+the time to first token of ``prompt_open``'s 61 requests, its mean by 11
+to 13% and its p95 by 15 to 18% from seed to seed, where two runs of one
+seed agreed within 2%.
 """
 from __future__ import annotations
 
@@ -52,6 +61,7 @@ import numpy as np
 
 BLOCK = 8              # consecutive requests that hold a spread of the mix
 TAIL_SEED = 0          # orders the last block, the same for every seed
+ORDER_SEED = 1         # orders the other blocks of an "order": "fixed" mix
 
 @dataclasses.dataclass
 class Item:
@@ -66,6 +76,8 @@ def load(path) -> dict:
     spec = json.loads(Path(path).read_text())
     if spec["loop"] not in ("open", "closed"):
         raise ValueError(f"{path}: loop must be open or closed")
+    if spec.get("order", "seed") not in ("seed", "fixed"):
+        raise ValueError(f"{path}: order must be seed or fixed")
     return spec
 
 
@@ -108,12 +120,16 @@ def generate(spec: dict, seed: int, seconds: float, vocab: int) -> List[Item]:
     """The run's requests in the order they are sent."""
     n = count(spec, seconds)
     rng = np.random.default_rng(int(seed) & ((1 << 63) - 1))
+    order = np.random.default_rng(ORDER_SEED) \
+        if spec.get("order") == "fixed" else rng
     tail = np.random.default_rng(TAIL_SEED)
-    plen = blocked(quantile_lengths(spec["prompt_len"], n), rng, tail, BLOCK)
-    olen = blocked(quantile_lengths(spec["output_len"], n), rng, tail, BLOCK)
+    plen = blocked(quantile_lengths(spec["prompt_len"], n), order, tail,
+                   BLOCK)
+    olen = blocked(quantile_lengths(spec["output_len"], n), order, tail,
+                   BLOCK)
     if spec["loop"] == "open":
-        due = np.cumsum(blocked(exp_gaps(spec["rate_per_s"], n), rng, tail,
-                                BLOCK))
+        due = np.cumsum(blocked(exp_gaps(spec["rate_per_s"], n), order,
+                                tail, BLOCK))
     else:
         due = np.zeros(n)
     return [Item(float(due[i]),

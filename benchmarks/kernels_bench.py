@@ -132,13 +132,24 @@ def _parity(case, form, leaf, x, out):
     return {"case": f"{case}.{form}", "max_abs_err": err, "ok": ok}
 
 
+def _stored(x):
+    """(B, S, KV, D) -> the cache's stored layout, one layer: (1, B, S, KV*D)."""
+    return x.reshape(x.shape[0], x.shape[1], -1)[None]
+
+
+def _kv8(x):
+    """(B, S, KV, D) -> int8 (B, S, KV, D) and its per-token (B, S) scales."""
+    from repro.models.transformer import _quantize_kv
+    q, sc = _quantize_kv(x.reshape(x.shape[0], x.shape[1], -1))
+    return q.reshape(x.shape), sc
+
+
 def attn_cases(smoke: bool = False):
     """Fused decode-attention parity: kernel vs ref.py vs decode_attention,
     bf16-class (f32 on CPU) and int8 cache, mixed per-row valid lengths."""
     from repro.kernels.attn_decode.ops import attn_decode
     from repro.kernels.attn_decode.ref import attn_decode_ref
     from repro.models.attention import decode_attention
-    from repro.models.transformer import _quantize_kv
 
     b, s, h, kv, d = ATTN_SMOKE if smoke else ATTN_FULL
     ks = jax.random.split(jax.random.PRNGKey(2), 3)
@@ -146,17 +157,21 @@ def attn_cases(smoke: bool = False):
     kc = jax.random.normal(ks[1], (b, s, kv, d))
     vc = jax.random.normal(ks[2], (b, s, kv, d))
     lens = (jnp.arange(b) * (s // b) % s + 1).astype(jnp.int32)  # mixed rows
-    kq, ksc = _quantize_kv(kc)
-    vq, vsc = _quantize_kv(vc)
+    kq, ksc = _kv8(kc)
+    vq, vsc = _kv8(vc)
 
     rows, parity = [], []
     reps = 3 if smoke else 10
     shape = f"shape={b}x{s}x{h}x{kv}x{d}"
-    for name, args in (("bf16", (q, kc, vc, lens, None, None)),
-                       ("int8", (q, kq, vq, lens, ksc, vsc))):
+    # the oracle reads (B, S, KV, D); kernel and einsum the stored layout
+    for name, oargs, args in (
+            ("bf16", (q, kc, vc, lens, None, None),
+             (q, _stored(kc), _stored(vc), lens, None, None)),
+            ("int8", (q, kq, vq, lens, ksc, vsc),
+             (q, _stored(kq), _stored(vq), lens, ksc[None], vsc[None]))):
         f_kn = jax.jit(lambda *a: attn_decode(*a, interpret=True))
         out = f_kn(*args)
-        ref = attn_decode_ref(*args)
+        ref = attn_decode_ref(*oargs)
         ein = decode_attention(*args, mode="ref")
         vkb = _vmem_kb(f_kn, *args)
         for oracle, o in (("ref", ref), ("einsum", ein)):
@@ -184,7 +199,6 @@ def attn_prefill_cases(smoke: bool = False):
     from repro.kernels.attn_prefill.ops import attn_prefill
     from repro.kernels.attn_prefill.ref import attn_prefill_ref
     from repro.models.attention import verify_attention
-    from repro.models.transformer import _quantize_kv
 
     h, kv, d = PF_HEADS_SMOKE if smoke else PF_HEADS_FULL
     g = h // kv
@@ -205,7 +219,8 @@ def attn_prefill_cases(smoke: bool = False):
         f_kn = jax.jit(lambda *a: attn_prefill(
             a[0], a[1], a[2], a[3], k_scale=a[4] if len(a) > 4 else None,
             v_scale=a[5] if len(a) > 5 else None, interpret=True))
-        args = (q, k, v, hi) + (() if ks_ is None else (ks_, vs_))
+        args = ((q, _stored(k), _stored(v), hi)
+                + (() if ks_ is None else (ks_[None], vs_[None])))
         out = f_kn(*args)
         ref = oracle(q, k, v, hi, ks_, vs_)
         err = float(jnp.max(jnp.abs(out - ref)))
@@ -230,8 +245,8 @@ def attn_prefill_cases(smoke: bool = False):
         lens = jnp.maximum((jnp.arange(b) + 1) * t // b, 1).astype(jnp.int32)
         pos = jnp.arange(t, dtype=jnp.int32)
         hi = jnp.minimum(pos[None, :] + 1, lens[:, None])
-        kq, ksc = _quantize_kv(kc)
-        vq, vsc = _quantize_kv(vc)
+        kq, ksc = _kv8(kc)
+        vq, vsc = _kv8(vc)
         for name, args in (("bf16", (q, kc, vc, hi)),
                            ("int8", (q, kq, vq, hi, ksc, vsc))):
             f_kn, full_args, shape = one(f"attn_prefill.T{t}.{name}", *args)
@@ -250,16 +265,16 @@ def attn_prefill_cases(smoke: bool = False):
         vc = jax.random.normal(ks3[2], (b, s, kv, d))
         pos0 = ((jnp.arange(b) * (s // b)) % (s - t)).astype(jnp.int32)
         valid = pos0[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :] + 1
-        kq, ksc = _quantize_kv(kc)
-        vq, vsc = _quantize_kv(vc)
+        kq, ksc = _kv8(kc)
+        vq, vsc = _kv8(vc)
         for name, args in (("bf16", (q, kc, vc, valid)),
                            ("int8", (q, kq, vq, valid, ksc, vsc))):
             f_kn, full_args, shape = one(f"attn_verify.T{t}.{name}", *args)
             out = f_kn(*full_args)
             # also gate against the PRODUCTION guarded-einsum verify path
-            scales = args[4:] if len(args) > 4 else (None, None)
-            ein = verify_attention(args[0], args[1], args[2], args[3],
-                                   *scales, mode="ref")
+            ein = verify_attention(*full_args[:4], *(full_args[4:] or
+                                                     (None, None)),
+                                   mode="ref")
             err = float(jnp.max(jnp.abs(out - ein)))
             ok = bool(np.allclose(np.asarray(out), np.asarray(ein),
                                   rtol=1e-4, atol=1e-4))
@@ -268,7 +283,7 @@ def attn_prefill_cases(smoke: bool = False):
             f_ein = jax.jit(lambda a0, a1, a2, a3, *sc: verify_attention(
                 a0, a1, a2, a3, *sc, mode="ref"))
             rows.append((f"kernel.cpu.attn_verify.T{t}.{name}.einsum",
-                         _time(f_ein, *args, reps=reps), shape))
+                         _time(f_ein, *full_args, reps=reps), shape))
             if smoke:
                 rows.append((f"kernel.cpu.attn_verify.T{t}.{name}"
                              f".kernel.interpret",

@@ -197,10 +197,10 @@ def cache_specs(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh,
                 cache_tree: Any) -> Any:
     """KV-cache / SSM-state specs for serving.
 
-    Transformer cache leaves: (L, B, S, KV, D) — batch over dp when it
+    Transformer cache leaves: (L, B, S, KV*D) — batch over dp when it
     divides, **sequence over model** (the only way a 1.1TB 32k x 128 cache
     fits per-device HBM; softmax over the sharded axis becomes an XLA
-    all-reduce pair, see DESIGN §8). Hybrid kv: (n_apps, B, S, KV, D).
+    all-reduce pair, see DESIGN §8). Hybrid kv: (n_apps, B, S, KV*D).
     SSM states: (L, B, H, P, N) — heads over model.
     """
     dp = dp_axes(mesh)

@@ -1,5 +1,6 @@
-"""Pallas TPU kernel: fused single-token GQA decode attention over a
-(B, S, KV, D) cache — bf16/f32 or int8 with per-token scales.
+"""Pallas TPU kernel: fused single-token GQA decode attention over one
+layer of the stacked (L, B, S, KV*D) cache — bf16/f32 or int8 with
+per-token scales.
 
 Decode attention is the paper's memory-bound regime applied to the KV cache:
 per generated token the whole valid cache is read once and O(S*D) FLOPs are
@@ -25,13 +26,17 @@ of the paper's on-chip dataflow (weights/scores never leave the chip):
   * M-blocking over the batch: ``bm`` slot rows ride per program, so the
     engine's batched-slots decode shape (B = slots) runs as one batched
     dot_general per (M-block, kv-head, S-block).
+  * In place: the cache is the model's stacked cache as stored, the layer
+    a scalar-prefetched index in the K/V (and scale) index maps. The
+    decode tick carries that cache through its layer loop and writes only
+    the new rows into it, so no tick slices, reshapes or copies it.
 
 Grid: (B/bm, KV, S/bs), S innermost ("arbitrary" — sequential accumulation
 into the scratch carry); B and KV are parallel. One q block is (bm, G, D)
 for a single kv head (GQA group G = H // KV), K/V blocks are (bm, bs, D):
-kv head j read as lane block j of the cache's free (B, S, KV*D) view, so
-the block's trailing dims are TPU tiles (``D`` must be a multiple of 128
-on the chip; interpret mode takes any ``D``).
+kv head j is lane block j of the stored (L, B, S, KV*D) layout, so the
+block's trailing dims are TPU tiles (``D`` must be a multiple of 128 on the
+chip; interpret mode takes any ``D``).
 
 Numerics match ``attn_decode_ref`` (ref.py): fp32 scores and softmax
 statistics, probabilities cast to the compute dtype for PV, fp32
@@ -53,13 +58,14 @@ __all__ = ["attn_decode_pallas", "NEG_INF"]
 NEG_INF = -1e30
 
 
-def _kernel(lmax_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, len_ref, o_ref,
-            acc_ref, m_ref, l_ref, *, bs: int, quantized: bool):
+def _kernel(lmax_ref, layer_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
+            len_ref, o_ref, acc_ref, m_ref, l_ref, *, bs: int,
+            quantized: bool):
     """One (bm, G) q tile against one (bm, bs) cache block.
 
-    Refs: q (bm, 1, G, D); k/v (bm, bs, D) — one kv head's lanes of the
-    (B, S, KV*D) cache view; ks/vs (bm, 1, bs) fp32 scales (None when not
-    quantized); len (bm, 1, 1) int32; out (bm, 1, G, D).
+    Refs: q (bm, 1, G, D); k/v (bm, bs, D) — one kv head's lanes of one
+    layer of the (L, B, S, KV*D) cache; ks/vs (bm, bs) fp32 scales (None
+    when not quantized); len (bm, 1, 1) int32; out (bm, 1, G, D).
     Scratch: acc (bm, G, D) fp32; m/l (bm, G, 1) fp32 — the online-softmax
     carry, valid across the innermost S grid dimension.
     """
@@ -84,7 +90,7 @@ def _kernel(lmax_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, len_ref, o_ref,
             dimension_numbers=(((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
         if quantized:
-            sc = sc * ks_ref[...]                       # (bm, 1, bs) bcast
+            sc = sc * ks_ref[...][:, None, :]           # (bm, 1, bs) bcast
         pos = start + jax.lax.broadcasted_iota(
             jnp.int32, (sc.shape[0], 1, bs), 2)         # (bm, 1, bs)
         sc = jnp.where(pos < len_ref[...], sc, NEG_INF)  # len (bm, 1, 1)
@@ -98,7 +104,7 @@ def _kernel(lmax_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, len_ref, o_ref,
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
         v = v_ref[...]                                  # (bm, bs, D)
         if quantized:
-            p = (p * vs_ref[...]).astype(q.dtype)
+            p = (p * vs_ref[...][:, None, :]).astype(q.dtype)
             v = v.astype(q.dtype)
         else:
             p = p.astype(v.dtype)
@@ -113,102 +119,103 @@ def _kernel(lmax_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, len_ref, o_ref,
         o_ref[:, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
+def divisor_block(n: int, want: int, align: int) -> int:
+    """The largest divisor of ``n`` that is at most ``want`` and a multiple
+    of ``align``, else ``n`` itself (one block over the whole dim). The
+    attention kernels block the stacked cache with it: a block that divides
+    the cache needs no padded copy of it."""
+    for blk in range(min(want, n), 0, -1):
+        if n % blk == 0 and blk % align == 0:
+            return blk
+    return n
+
+
 @functools.partial(jax.jit,
                    static_argnames=("bm", "bs", "interpret"))
 def attn_decode_pallas(q: jnp.ndarray, k_cache: jnp.ndarray,
                        v_cache: jnp.ndarray, cache_len: jnp.ndarray,
-                       k_scale: jnp.ndarray | None = None,
+                       layer=0, k_scale: jnp.ndarray | None = None,
                        v_scale: jnp.ndarray | None = None, *,
                        bm: int = 8, bs: int = 128,
                        interpret: bool = False) -> jnp.ndarray:
-    """q (B, KV, G, D) PRE-SCALED by 1/sqrt(D); k/v cache (B, S, KV, D);
-    cache_len (B,) int32; optional per-token scales (B, S) fp32 for an int8
-    cache. Returns (B, KV, G, D) in q's dtype.
+    """q (B, KV, G, D) PRE-SCALED by 1/sqrt(D); k/v cache (L, B, S, KV*D),
+    the stacked cache as stored, kv head j in lane block j; cache_len (B,)
+    int32; ``layer`` the int32 index of the layer to read; optional
+    per-token scales (L, B, S) fp32 for an int8 cache. Returns
+    (B, KV, G, D) in q's dtype.
 
-    ``bm`` rows x ``bs`` cache positions per program; both are clamped and
-    the inputs zero-padded, with padded rows masked via cache_len = 0.
+    The cache is read in place: the layer is selected in the index maps
+    (scalar-prefetched), and the blocks divide the cache (``divisor_block``:
+    ``bm`` rows and ``bs`` positions at most, tile-aligned on the chip), so
+    no slice, view or padded copy of the cache is made.
     """
     b, kv, g, d = q.shape
-    s = k_cache.shape[1]
+    s = k_cache.shape[2]
     quantized = k_scale is not None
     lens = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32), (b,))
 
-    bm = min(bm, b)
-    bs = min(bs, s)
-    bp = -(-b // bm) * bm
-    sp = -(-s // bs) * bs
-    if bp != b:
-        q = jnp.pad(q, ((0, bp - b),) + ((0, 0),) * 3)
-        k_cache = jnp.pad(k_cache, ((0, bp - b),) + ((0, 0),) * 3)
-        v_cache = jnp.pad(v_cache, ((0, bp - b),) + ((0, 0),) * 3)
-        lens = jnp.pad(lens, (0, bp - b))               # pad rows: len 0
-    if sp != s:
-        k_cache = jnp.pad(k_cache, ((0, 0), (0, sp - s), (0, 0), (0, 0)))
-        v_cache = jnp.pad(v_cache, ((0, 0), (0, sp - s), (0, 0), (0, 0)))
-    if quantized:
-        k_scale = jnp.pad(jnp.asarray(k_scale, jnp.float32),
-                          ((0, bp - b), (0, sp - s)))
-        v_scale = jnp.pad(jnp.asarray(v_scale, jnp.float32),
-                          ((0, bp - b), (0, sp - s)))
-    nb, ns = bp // bm, sp // bs
+    # on the chip the scale blocks (bm, bs) sit on the (B, S) tile dims
+    bm = divisor_block(b, bm, 1 if interpret else 8)
+    bs = divisor_block(s, bs, 1 if interpret else 128)
+    nb, ns = b // bm, s // bs
     # per-M-block max valid length, scalar-prefetched: the index maps clamp
     # the S block index with it, so fully-invalid blocks re-target the last
     # valid block — same index as the previous grid step => the pipeline
     # skips the HBM->VMEM copy (the "don't stream the whole ring" part)
     lmax = jnp.max(lens.reshape(nb, bm), axis=1)
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
     len3 = lens[:, None, None]
-    # free row-major views whose trailing block dims the TPU accepts:
-    # kv head j of the (B, S, KV, D) cache is lane block j of (B, S, KV*D)
-    k_cache = k_cache.reshape(bp, sp, kv * d)
-    v_cache = v_cache.reshape(bp, sp, kv * d)
 
-    def kv_idx(i, j, s_blk, lmax_ref):
+    def _sblk(i, s_blk, lmax_ref):
         nblk = jnp.maximum((lmax_ref[i] + bs - 1) // bs, 1)
-        return (i, jnp.minimum(s_blk, nblk - 1), j)
+        return jnp.minimum(s_blk, nblk - 1)
 
-    def sc_idx(i, j, s_blk, lmax_ref):
-        nblk = jnp.maximum((lmax_ref[i] + bs - 1) // bs, 1)
-        return (i, 0, jnp.minimum(s_blk, nblk - 1))
+    def kv_idx(i, j, s_blk, lmax_ref, layer_ref):
+        return (layer_ref[0], i, _sblk(i, s_blk, lmax_ref), j)
+
+    def sc_idx(i, j, s_blk, lmax_ref, layer_ref):
+        return (layer_ref[0], i, _sblk(i, s_blk, lmax_ref))
+
+    def row_idx(i, j, s_blk, lmax_ref, layer_ref):
+        return (i, j, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((bm, 1, g, d), lambda i, j, s_blk, lmax: (i, j, 0, 0)),
-        pl.BlockSpec((bm, bs, d), kv_idx),
-        pl.BlockSpec((bm, bs, d), kv_idx),
+        pl.BlockSpec((bm, 1, g, d), row_idx),
+        pl.BlockSpec((None, bm, bs, d), kv_idx),
+        pl.BlockSpec((None, bm, bs, d), kv_idx),
     ]
     args = [q, k_cache, v_cache]
     if quantized:
-        in_specs += [pl.BlockSpec((bm, 1, bs), sc_idx),
-                     pl.BlockSpec((bm, 1, bs), sc_idx)]
-        args += [k_scale[:, None], v_scale[:, None]]
-    in_specs.append(
-        pl.BlockSpec((bm, 1, 1), lambda i, j, s_blk, lmax: (i, 0, 0)))
+        in_specs += [pl.BlockSpec((None, bm, bs), sc_idx),
+                     pl.BlockSpec((None, bm, bs), sc_idx)]
+        args += [k_scale, v_scale]
+    in_specs.append(pl.BlockSpec((bm, 1, 1),
+                                 lambda i, j, s_blk, lmax, layer: (i, 0, 0)))
     args.append(len3)
 
     if quantized:
         kernel = functools.partial(_kernel, bs=bs, quantized=True)
     else:                  # no scale operands: splice None refs back in
-        def kernel(lmax_ref, q_ref, k_ref, v_ref, len_ref, o_ref,
+        def kernel(lmax_ref, layer_ref, q_ref, k_ref, v_ref, len_ref, o_ref,
                    acc_ref, m_ref, l_ref):
-            return _kernel(lmax_ref, q_ref, k_ref, v_ref, None, None,
-                           len_ref, o_ref, acc_ref, m_ref, l_ref,
+            return _kernel(lmax_ref, layer_ref, q_ref, k_ref, v_ref, None,
+                           None, len_ref, o_ref, acc_ref, m_ref, l_ref,
                            bs=bs, quantized=False)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(nb, kv, ns),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, 1, g, d),
-                               lambda i, j, s_blk, lmax: (i, j, 0, 0)),
+        out_specs=pl.BlockSpec((bm, 1, g, d), row_idx),
         scratch_shapes=[
             pltpu.VMEM((bm, g, d), jnp.float32),        # acc
             pltpu.VMEM((bm, g, 1), jnp.float32),        # running max
             pltpu.VMEM((bm, g, 1), jnp.float32),        # running sum
         ],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bp, kv, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kv, g, d), q.dtype),
         interpret=interpret,
-    )(lmax, *args)
-    return out[:b]
+    )(lmax, layer, *args)

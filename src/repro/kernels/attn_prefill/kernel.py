@@ -1,6 +1,6 @@
 """Pallas TPU kernel: blocked online-softmax prefill/verify attention —
-(B, T, KV, G, D) queries against (B, S, KV, D) keys/values, bf16/f32 or
-int8 with per-token scales, per-(row, query) visibility bounds.
+(B, T, KV, G, D) queries against one layer of (L, B, S, KV*D) keys/values,
+bf16/f32 or int8 with per-token scales, per-(row, query) visibility bounds.
 
 This is the flash-attention analogue of the paper's on-chip dataflow applied
 to the two multi-token serving paths: bucketed-prefill admission (T = the
@@ -32,8 +32,9 @@ score storage and it never leaves VMEM:
 Grid: (B, T/bt, KV, S/bs), S innermost ("arbitrary" — sequential
 accumulation into the scratch carry). One q block is (bt, G, D) for a
 single kv head; K/V blocks are (bs, D), kv head j read as lane block j of
-the free (B, S, KV*D) view (``D`` must be a multiple of 128 on the chip;
-interpret mode takes any ``D``).
+the stored (L, B, S, KV*D) layout, the layer a scalar-prefetched index, so
+verify reads the decode cache in place (``D`` must be a multiple of 128 on
+the chip; interpret mode takes any ``D``).
 
 Numerics match ``attn_prefill_ref`` (ref.py): fp32 scores and softmax
 statistics, probabilities cast to the compute dtype for PV, fp32
@@ -51,19 +52,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.attn_decode.kernel import divisor_block
+
 __all__ = ["attn_prefill_pallas", "NEG_INF"]
 
 NEG_INF = -1e30
 
 
-def _kernel(hmax_ref, lmin_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-            lo_ref, hi_ref, o_ref, acc_ref, m_ref, l_ref, *, bs: int,
+def _kernel(hmax_ref, lmin_ref, layer_ref, q_ref, k_ref, v_ref, ks_ref,
+            vs_ref, lo_ref, hi_ref, o_ref, acc_ref, m_ref, l_ref, *, bs: int,
             quantized: bool):
     """One (bt, G) q tile of one batch row against one (bs,) K/V block.
 
-    Refs: q (1, bt, 1, G, D); k/v (1, bs, D) — one kv head's lanes of the
-    (B, S, KV*D) view; ks/vs (1, 1, bs) fp32 scales (None when not
-    quantized); lo/hi (1, bt, 1, 1) int32; out (1, bt, 1, G, D).
+    Refs: q (1, bt, 1, G, D); k/v (bs, D) — one kv head's lanes of one row
+    of one layer of the (L, B, S, KV*D) cache; ks/vs (B, bs) fp32 scales of
+    every row (None when not quantized: a one-row block is no TPU tile, so
+    the kernel picks row ``i``); lo/hi (1, bt, 1, 1) int32; out
+    (1, bt, 1, G, D).
     Scratch: acc (bt, G, D) fp32; m/l (bt, G, 1) fp32 — the online-softmax
     carry, valid across the innermost S grid dimension.
     """
@@ -83,13 +88,13 @@ def _kernel(hmax_ref, lmin_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
     @pl.when((start < hmax_ref[i, t]) & (start + bs > lmin_ref[i, t]))
     def _compute():
         q = q_ref[0, :, 0]                              # (bt, G, D)
-        k = k_ref[0]                                    # (bs, D)
+        k = k_ref[...]                                  # (bs, D)
         sc = jax.lax.dot_general(                       # (bt, G, bs) fp32
             q, k.astype(q.dtype),
             dimension_numbers=(((2,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         if quantized:
-            sc = sc * ks_ref[0]                         # (1, bs) bcast
+            sc = sc * ks_ref[pl.ds(i, 1), :]            # (1, bs) bcast
         pos = start + jax.lax.broadcasted_iota(
             jnp.int32, (sc.shape[0], 1, bs), 2)         # (bt, 1, bs)
         valid = (pos < hi_ref[0]) & (pos >= lo_ref[0])  # lo/hi (bt, 1, 1)
@@ -102,9 +107,9 @@ def _kernel(hmax_ref, lmin_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
         p = jnp.where(alive, jnp.exp(sc - m_new), 0.0)  # (bt, G, bs)
         corr = jnp.where(alive, jnp.exp(m_prev - m_new), 1.0)
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        v = v_ref[0]                                    # (bs, D)
+        v = v_ref[...]                                  # (bs, D)
         if quantized:
-            p = (p * vs_ref[0]).astype(q.dtype)
+            p = (p * vs_ref[pl.ds(i, 1), :]).astype(q.dtype)
             v = v.astype(q.dtype)
         else:
             p = p.astype(v.dtype)
@@ -122,43 +127,37 @@ def _kernel(hmax_ref, lmin_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
 @functools.partial(jax.jit,
                    static_argnames=("bt", "bs", "interpret"))
 def attn_prefill_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                        lo: jnp.ndarray, hi: jnp.ndarray,
+                        lo: jnp.ndarray, hi: jnp.ndarray, layer=0,
                         k_scale: jnp.ndarray | None = None,
                         v_scale: jnp.ndarray | None = None, *,
                         bt: int = 128, bs: int = 128,
                         interpret: bool = False) -> jnp.ndarray:
-    """q (B, T, KV, G, D) PRE-SCALED by 1/sqrt(D); k/v (B, S, KV, D);
+    """q (B, T, KV, G, D) PRE-SCALED by 1/sqrt(D); k/v (L, B, S, KV*D), the
+    stacked cache as stored (kv head j in lane block j), read at layer
+    ``layer`` (int32; a prompt's own K/V is one layer, ``k[None]``);
     lo/hi (B, T) int32 per-query visibility bounds (query t of row b sees
-    positions lo <= p < hi); optional per-token scales (B, S) fp32 for an
-    int8 K/V source. Returns (B, T, KV, G, D) in q's dtype.
+    positions lo <= p < hi); optional per-token scales (L, B, S) fp32 for
+    an int8 K/V source. Returns (B, T, KV, G, D) in q's dtype.
 
-    ``bt`` query rows x ``bs`` key positions per program; both are clamped
-    and the inputs zero-padded, with padded query rows masked via hi = 0
-    (the empty-row guard zeroes their output).
+    ``bt`` query rows x ``bs`` key positions per program. Queries are
+    zero-padded to a multiple of ``bt``, padded rows masked via hi = 0 (the
+    empty-row guard zeroes their output); ``bs`` is a divisor of S
+    (``divisor_block``), so K/V are read in place, never copied.
     """
     b, t, kv, g, d = q.shape
-    s = k.shape[1]
+    s = k.shape[2]
     quantized = k_scale is not None
     lo = jnp.broadcast_to(jnp.asarray(lo, jnp.int32), (b, t))
     hi = jnp.broadcast_to(jnp.asarray(hi, jnp.int32), (b, t))
 
     bt = min(bt, t)
-    bs = min(bs, s)
+    bs = divisor_block(s, bs, 1 if interpret else 128)
     tp = -(-t // bt) * bt
-    sp = -(-s // bs) * bs
     if tp != t:
         q = jnp.pad(q, ((0, 0), (0, tp - t)) + ((0, 0),) * 3)
         lo = jnp.pad(lo, ((0, 0), (0, tp - t)))
         hi = jnp.pad(hi, ((0, 0), (0, tp - t)))         # pad queries: hi 0
-    if sp != s:
-        k = jnp.pad(k, ((0, 0), (0, sp - s), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, sp - s), (0, 0), (0, 0)))
-    if quantized:
-        k_scale = jnp.pad(jnp.asarray(k_scale, jnp.float32),
-                          ((0, 0), (0, sp - s)))
-        v_scale = jnp.pad(jnp.asarray(v_scale, jnp.float32),
-                          ((0, 0), (0, sp - s)))
-    nt, ns = tp // bt, sp // bs
+    nt, ns = tp // bt, s // bs
     # per-(row, q-block) visibility bounds, scalar-prefetched: the index
     # maps clamp the S block index into [first needed, last needed], so
     # blocks past the causal frontier / padded tail (or before the sliding
@@ -166,39 +165,36 @@ def attn_prefill_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     # step => the pipeline skips the HBM->VMEM copy
     hmax = jnp.max(hi.reshape(b, nt, bt), axis=-1)
     lmin = jnp.min(lo.reshape(b, nt, bt), axis=-1)
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
 
     def _sblk(i, tt, s_blk, hmax_ref, lmin_ref):
         nhi = jnp.maximum((hmax_ref[i, tt] + bs - 1) // bs, 1)
         return jnp.minimum(jnp.maximum(s_blk, lmin_ref[i, tt] // bs),
                            nhi - 1)
 
-    def kv_idx(i, tt, j, s_blk, hmax_ref, lmin_ref):
-        return (i, _sblk(i, tt, s_blk, hmax_ref, lmin_ref), j)
+    def kv_idx(i, tt, j, s_blk, hmax_ref, lmin_ref, layer_ref):
+        return (layer_ref[0], i, _sblk(i, tt, s_blk, hmax_ref, lmin_ref), j)
 
-    def sc_idx(i, tt, j, s_blk, hmax_ref, lmin_ref):
-        return (i, 0, _sblk(i, tt, s_blk, hmax_ref, lmin_ref))
+    def sc_idx(i, tt, j, s_blk, hmax_ref, lmin_ref, layer_ref):
+        return (layer_ref[0], 0, _sblk(i, tt, s_blk, hmax_ref, lmin_ref))
 
-    def q_idx(i, tt, j, s_blk, hmax_ref, lmin_ref):
+    def q_idx(i, tt, j, s_blk, hmax_ref, lmin_ref, layer_ref):
         return (i, tt, j, 0, 0)
 
-    def b_idx(i, tt, j, s_blk, hmax_ref, lmin_ref):
+    def b_idx(i, tt, j, s_blk, hmax_ref, lmin_ref, layer_ref):
         return (i, tt, 0, 0)
 
-    # free row-major views whose trailing block dims the TPU accepts: kv
-    # head j of (B, S, KV, D) is lane block j of (B, S, KV*D); scales and
     # bounds get unit trailing dims so each block spans the full array dim
-    k = k.reshape(b, sp, kv * d)
-    v = v.reshape(b, sp, kv * d)
     in_specs = [
         pl.BlockSpec((1, bt, 1, g, d), q_idx),
-        pl.BlockSpec((1, bs, d), kv_idx),
-        pl.BlockSpec((1, bs, d), kv_idx),
+        pl.BlockSpec((None, None, bs, d), kv_idx),
+        pl.BlockSpec((None, None, bs, d), kv_idx),
     ]
     args = [q, k, v]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1, bs), sc_idx),
-                     pl.BlockSpec((1, 1, bs), sc_idx)]
-        args += [k_scale[:, None], v_scale[:, None]]
+        in_specs += [pl.BlockSpec((None, b, bs), sc_idx),
+                     pl.BlockSpec((None, b, bs), sc_idx)]
+        args += [k_scale, v_scale]
     in_specs += [pl.BlockSpec((1, bt, 1, 1), b_idx),
                  pl.BlockSpec((1, bt, 1, 1), b_idx)]
     args += [lo[..., None, None], hi[..., None, None]]
@@ -206,14 +202,14 @@ def attn_prefill_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if quantized:
         kernel = functools.partial(_kernel, bs=bs, quantized=True)
     else:                  # no scale operands: splice None refs back in
-        def kernel(hmax_ref, lmin_ref, q_ref, k_ref, v_ref, lo_ref, hi_ref,
-                   o_ref, acc_ref, m_ref, l_ref):
-            return _kernel(hmax_ref, lmin_ref, q_ref, k_ref, v_ref, None,
-                           None, lo_ref, hi_ref, o_ref, acc_ref, m_ref,
-                           l_ref, bs=bs, quantized=False)
+        def kernel(hmax_ref, lmin_ref, layer_ref, q_ref, k_ref, v_ref,
+                   lo_ref, hi_ref, o_ref, acc_ref, m_ref, l_ref):
+            return _kernel(hmax_ref, lmin_ref, layer_ref, q_ref, k_ref,
+                           v_ref, None, None, lo_ref, hi_ref, o_ref,
+                           acc_ref, m_ref, l_ref, bs=bs, quantized=False)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, nt, kv, ns),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bt, 1, g, d), q_idx),
@@ -228,5 +224,5 @@ def attn_prefill_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, tp, kv, g, d), q.dtype),
         interpret=interpret,
-    )(hmax, lmin, *args)
+    )(hmax, lmin, layer, *args)
     return out[:, :t]

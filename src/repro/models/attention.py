@@ -1,7 +1,9 @@
 """Attention: GQA with chunked online-softmax (memory O(seq·chunk), never
 materializes the full score matrix) + sliding-window path + decode step.
 
-Shapes: q (B, Lq, H, D); k/v (B, Lkv, KV, D); GQA groups G = H // KV.
+Shapes: q (B, Lq, H, D); k/v (B, Lkv, KV, D) for prompt attention; the
+decode and verify entries read the stacked cache as stored, (L, B, S, KV*D)
+with a layer index; GQA groups G = H // KV.
 The chunked scan keeps running (max, sum, acc) per q position — the standard
 flash-attention recurrence expressed in pure JAX (``jax.lax.scan`` over KV
 chunks). XLA fuses each chunk's QK^T+softmax+PV; on TPU the same structure is
@@ -207,7 +209,10 @@ def prefill_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         if window:
             lo = jnp.broadcast_to(jnp.maximum(pos - (window - 1), 0)[None],
                                   (b, t))
-        return attn_prefill(q, k, v, hi, lo=lo, interpret=interpret)
+        # the prompt's own K/V as a one-layer cache in the stored layout
+        return attn_prefill(q, k.reshape(b, t, -1)[None],
+                            v.reshape(b, t, -1)[None], hi, lo=lo,
+                            interpret=interpret)
     if window:
         return sliding_window_attention(q, k, v, window=window, chunk=chunk)
     return chunked_attention(q, k, v, causal=True, chunk=chunk)
@@ -215,14 +220,15 @@ def prefill_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
 def verify_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                      v_cache: jnp.ndarray, valid: jnp.ndarray,
-                     k_scale=None, v_scale=None, *, mode: str = "auto",
+                     k_scale=None, v_scale=None, *, layer=0,
+                     mode: str = "auto",
                      interpret: Optional[bool] = None) -> jnp.ndarray:
     """Multi-token decode attention for speculative verify. q: (B, T, H, D)
-    against a (B, S, KV, D) cache; ``valid`` (B, T) is the number of visible
-    cache entries per query (its own just-written position included), so the
-    T draft positions are causally masked against each other AND against the
-    live prefix — the bucketed-prefill masking rule applied to the decode
-    cache. Term-for-term the T>1 generalization of :func:`decode_attention`'s
+    against layer ``layer`` of the stacked (L, B, S, KV*D) cache; ``valid``
+    (B, T) is the number of visible cache entries per query (its own
+    just-written position included), so the T draft positions are causally
+    masked against each other AND against the live prefix — the
+    bucketed-prefill masking rule applied to the decode cache. Term-for-term the T>1 generalization of :func:`decode_attention`'s
     reference path (same contractions, same int8 per-token scale factoring),
     which keeps verify logits aligned with the sequential decode logits.
 
@@ -237,7 +243,10 @@ def verify_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     if resolve_attn_mode(mode) == "kernel":
         from repro.kernels.attn_prefill.ops import attn_prefill
         return attn_prefill(q, k_cache, v_cache, valid, k_scale=k_scale,
-                            v_scale=v_scale, interpret=interpret)
+                            v_scale=v_scale, layer=layer,
+                            interpret=interpret)
+    k_cache, v_cache, k_scale, v_scale = _layer_of(
+        k_cache, v_cache, k_scale, v_scale, layer, d)
     s, kvh = k_cache.shape[1], k_cache.shape[2]
     g = h // kvh
     scale = 1.0 / (d ** 0.5)
@@ -260,30 +269,46 @@ def verify_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     return out.reshape(b, t, h, d).astype(q.dtype)
 
 
+def _layer_of(k_cache, v_cache, k_scale, v_scale, layer, d):
+    """The reference paths' view of layer ``layer`` of a stacked
+    (L, B, S, KV*D) cache: (B, S, KV, D) K/V and (B, S) scales. A slice
+    and a relayout, so it stays off the kernel path."""
+    _, b, s, kvd = k_cache.shape
+    k_cache = k_cache[layer].reshape(b, s, kvd // d, d)
+    v_cache = v_cache[layer].reshape(b, s, kvd // d, d)
+    if k_scale is not None:
+        k_scale, v_scale = k_scale[layer], v_scale[layer]
+    return k_cache, v_cache, k_scale, v_scale
+
+
 def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
                      cache_len: jnp.ndarray, k_scale=None, v_scale=None, *,
-                     mode: str = "auto",
+                     layer=0, mode: str = "auto",
                      interpret: Optional[bool] = None) -> jnp.ndarray:
-    """One-token attention against a (B, S, KV, D) cache. q: (B, 1, H, D).
+    """One-token attention against layer ``layer`` (int32, may be traced)
+    of the stacked (L, B, S, KV*D) cache. q: (B, 1, H, D).
 
     ``cache_len``: scalar or (B,) number of valid cache entries. O(S) compute,
     bound by cache bandwidth — the paper's memory-bound regime on TPU.
 
-    int8 cache support: pass per-token ``k_scale``/``v_scale`` (B, S); the
+    int8 cache support: pass per-token ``k_scale``/``v_scale`` (L, B, S); the
     scales factor exactly through the score and value contractions, so the
     einsums read the int8 arrays directly (half the bf16 cache traffic).
 
     ``mode`` selects the implementation: 'kernel' runs the fused Pallas
     kernel (``repro.kernels.attn_decode``: blocked online softmax in VMEM —
     no (..., S) score tensor in HBM — per-row valid-length block skipping,
-    int8 dequant fused into the epilogue; interpret mode off-TPU, for
-    tests), 'ref' the einsum path below, 'auto' (default) kernel on TPU.
+    int8 dequant fused into the epilogue, the layer read in place; interpret
+    mode off-TPU, for tests), 'ref' the einsum path below, 'auto' (default)
+    kernel on TPU.
     """
     if resolve_attn_mode(mode) == "kernel":
         from repro.kernels.attn_decode.ops import attn_decode
         return attn_decode(q, k_cache, v_cache, cache_len, k_scale, v_scale,
-                           interpret=interpret)
+                           layer=layer, interpret=interpret)
     b, _, h, d = q.shape
+    k_cache, v_cache, k_scale, v_scale = _layer_of(
+        k_cache, v_cache, k_scale, v_scale, layer, d)
     s, kvh = k_cache.shape[1], k_cache.shape[2]
     g = h // kvh
     scale = 1.0 / (d ** 0.5)

@@ -18,12 +18,22 @@ from repro.core import quant_dense
 from repro.core.precision import QuantPolicy
 from repro.distributed.context import constrain
 from repro.models import mamba2, transformer
+from repro.models.attention import decode_attention, verify_attention
 from repro.models.layers import (embed_init, embed_lookup, logits_readout,
                                  rmsnorm, rmsnorm_init)
 
 __all__ = ["init", "forward", "init_cache", "prefill", "decode_step",
            "verify_step", "rollback_cache", "spec_state_snapshot",
            "insert_prefill", "insert_prefill_many"]
+
+
+def _unit_layer(kv):
+    """One group's K/V leaves (B, S, KV*D) as the attention entries' stacked
+    operands: a leading unit layer axis (a free view), read at layer 0.
+    Returns (k, v, k_scale, v_scale), the scales None for a float cache."""
+    return (kv["k"][None], kv["v"][None],
+            kv["k_scale"][None] if "k_scale" in kv else None,
+            kv["v_scale"][None] if "v_scale" in kv else None)
 
 
 def _counts(cfg: ModelConfig) -> Tuple[int, int]:
@@ -130,12 +140,13 @@ def _logits(params, h, cfg, policy, deltas, mm: str = "auto"):
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16,
                quantized: bool = False):
     """Decode state: per-layer mamba states + one KV cache per shared-block
-    application. ``quantized``: int8 KV entries + per-(group,batch,position)
-    fp32 scales — the transformer family's §Perf H-kv8 cache, extended to
-    the hybrid attention applications (half the KV bytes per slot)."""
+    application, (G, B, S, KV*D) in the transformer family's stored layout.
+    ``quantized``: int8 KV entries + per-(group,batch,position) fp32 scales
+    — the transformer family's §Perf H-kv8 cache, extended to the hybrid
+    attention applications (half the KV bytes per slot)."""
     n_groups, n_tail = _counts(cfg)
     one = mamba2.block_state(cfg, batch)
-    kv_shape = (n_groups, batch, max_len, cfg.num_kv_heads, cfg.head_dim)
+    kv_shape = (n_groups, batch, max_len, cfg.num_kv_heads * cfg.head_dim)
     if quantized:
         kv = {"k": jnp.zeros(kv_shape, jnp.int8),
               "v": jnp.zeros(kv_shape, jnp.int8),
@@ -201,12 +212,12 @@ def prefill(params, batch, cfg: ModelConfig, *, policy: QuantPolicy,
     h, (gstates, ks, vs) = jax.lax.scan(group_body, h, (params["groups"], gd))
     state = init_cache(cfg, bsz, max_len, dtype, quantized=quantize_cache)
     state["groups"] = gstates
-    pad = max_len - s
-    ks = jnp.pad(ks, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-    vs = jnp.pad(vs, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+    pad = ((0, 0), (0, 0), (0, max_len - s), (0, 0))
+    ks = jnp.pad(ks, pad)
+    vs = jnp.pad(vs, pad)
     if quantize_cache:
-        qk, sk = jax.vmap(transformer._quantize_kv)(ks)   # over group dim
-        qv, sv = jax.vmap(transformer._quantize_kv)(vs)
+        qk, sk = transformer._quantize_kv(ks)
+        qv, sv = transformer._quantize_kv(vs)
         state["kv"] = {"k": qk, "v": qv, "k_scale": sk, "v_scale": sv}
     else:
         state["kv"]["k"] = ks.astype(dtype)
@@ -240,7 +251,6 @@ def decode_step(params, state, tokens: jnp.ndarray, cfg: ModelConfig, *,
     n_groups, n_tail = _counts(cfg)
     b = tokens.shape[0]
     pos = jnp.broadcast_to(state["len"], (b,)).astype(jnp.int32)   # (B,)
-    quantized = "k_scale" in state["kv"]
     h = embed_lookup(params["embed"], tokens, policy=policy,
                      delta=_dget(deltas, "embed", "w"), dtype=dtype)
     inv_freq = transformer.rope_freqs(cfg.head_dim, cfg.rope_theta)
@@ -255,47 +265,23 @@ def decode_step(params, state, tokens: jnp.ndarray, cfg: ModelConfig, *,
         return hh, st2
 
     def group_body(hh, xs):
-        if quantized:
-            gp, gd, gst, kc, vc, ks_, vs_ = xs
-        else:
-            gp, gd, gst, kc, vc = xs
-            ks_ = vs_ = None
+        gp, gd, gst, kv = xs
         hh, gst2 = jax.lax.scan(mamba_body, hh, (gp, gd, gst))
         hn = rmsnorm(shared["ln1"], hh, cfg.norm_eps)
         q, k, v = transformer._qkv(shared, hn, cfg, policy, sdelta, positions,
                                    inv_freq, matmul_mode)
-        if quantized:
-            kq, ksc = transformer._quantize_kv(k)
-            vq, vsc = transformer._quantize_kv(v)
-            kc = kc.at[rows, pos].set(kq[:, 0])
-            vc = vc.at[rows, pos].set(vq[:, 0])
-            ks_ = ks_.at[rows, pos].set(ksc[:, 0])
-            vs_ = vs_.at[rows, pos].set(vsc[:, 0])
-        else:
-            kc = kc.at[rows, pos].set(k[:, 0].astype(kc.dtype))
-            vc = vc.at[rows, pos].set(v[:, 0].astype(vc.dtype))
-        from repro.models.attention import decode_attention
-        o = decode_attention(q, kc, vc, pos + 1, k_scale=ks_, v_scale=vs_,
-                             mode=attn_mode)
+        kv = transformer._kv_write(kv, (rows, pos), k[:, 0], v[:, 0])
+        kc, vc, ks_, vs_ = _unit_layer(kv)
+        o = decode_attention(q, kc, vc, pos + 1, ks_, vs_, mode=attn_mode)
         hh = hh + transformer._attn_out(shared, o, cfg, policy, sdelta, b, 1,
                                         matmul_mode)
         hn = rmsnorm(shared["ln2"], hh, cfg.norm_eps)
         f, _ = transformer._ffn(shared, hn, cfg, policy, sdelta, matmul_mode)
-        out_kv = (gst2, kc, vc, ks_, vs_) if quantized else (gst2, kc, vc)
-        return hh + f, out_kv
+        return hh + f, (gst2, kv)
 
     gd = _dget(deltas, "groups")
-    kv = state["kv"]
-    if quantized:
-        h, (gstates, ks, vs, ksc, vsc) = jax.lax.scan(
-            group_body, h, (params["groups"], gd, state["groups"],
-                            kv["k"], kv["v"], kv["k_scale"], kv["v_scale"]))
-        new_kv = {"k": ks, "v": vs, "k_scale": ksc, "v_scale": vsc}
-    else:
-        h, (gstates, ks, vs) = jax.lax.scan(
-            group_body, h,
-            (params["groups"], gd, state["groups"], kv["k"], kv["v"]))
-        new_kv = {"k": ks, "v": vs}
+    h, (gstates, new_kv) = jax.lax.scan(
+        group_body, h, (params["groups"], gd, state["groups"], state["kv"]))
     new_state = dict(state)
     new_state["groups"] = gstates
     new_state["kv"] = new_kv
@@ -342,14 +328,12 @@ def verify_step(params, state, tokens: jnp.ndarray, cfg: ModelConfig, *,
     n_groups, n_tail = _counts(cfg)
     b, t = tokens.shape
     pos0 = jnp.broadcast_to(state["len"], (b,)).astype(jnp.int32)  # (B,)
-    quantized = "k_scale" in state["kv"]
     h = embed_lookup(params["embed"], tokens, policy=policy,
                      delta=_dget(deltas, "embed", "w"), dtype=dtype)
     inv_freq = transformer.rope_freqs(cfg.head_dim, cfg.rope_theta)
     positions = pos0[:, None] + jnp.arange(t)[None, :]             # (B, T)
     rows = jnp.arange(b)[:, None]                                  # (B, 1)
     shared, sdelta = params["shared"], _dget(deltas, "shared")
-    from repro.models.attention import verify_attention
 
     def mamba_body(hh, xs):
         lp, ld, st = xs
@@ -358,47 +342,24 @@ def verify_step(params, state, tokens: jnp.ndarray, cfg: ModelConfig, *,
         return out, (st_final, straj)
 
     def group_body(hh, xs):
-        if quantized:
-            gp, gd, gst, kc, vc, ks_, vs_ = xs
-        else:
-            gp, gd, gst, kc, vc = xs
-            ks_ = vs_ = None
+        gp, gd, gst, kv = xs
         hh, (gst2, gtraj) = jax.lax.scan(mamba_body, hh, (gp, gd, gst))
         hn = rmsnorm(shared["ln1"], hh, cfg.norm_eps)
         q, k, v = transformer._qkv(shared, hn, cfg, policy, sdelta, positions,
                                    inv_freq, matmul_mode)
-        if quantized:
-            kq, ksc = transformer._quantize_kv(k)
-            vq, vsc = transformer._quantize_kv(v)
-            kc = kc.at[rows, positions].set(kq)
-            vc = vc.at[rows, positions].set(vq)
-            ks_ = ks_.at[rows, positions].set(ksc)
-            vs_ = vs_.at[rows, positions].set(vsc)
-        else:
-            kc = kc.at[rows, positions].set(k.astype(kc.dtype))
-            vc = vc.at[rows, positions].set(v.astype(vc.dtype))
-        o = verify_attention(q, kc, vc, positions + 1,
-                             k_scale=ks_, v_scale=vs_, mode=attn_mode)
+        kv = transformer._kv_write(kv, (rows, positions), k, v)
+        kc, vc, ks_, vs_ = _unit_layer(kv)
+        o = verify_attention(q, kc, vc, positions + 1, ks_, vs_,
+                             mode=attn_mode)
         hh = hh + transformer._attn_out(shared, o, cfg, policy, sdelta, b, t,
                                         matmul_mode)
         hn = rmsnorm(shared["ln2"], hh, cfg.norm_eps)
         f, _ = transformer._ffn(shared, hn, cfg, policy, sdelta, matmul_mode)
-        out_kv = ((gst2, gtraj, kc, vc, ks_, vs_) if quantized
-                  else (gst2, gtraj, kc, vc))
-        return hh + f, out_kv
+        return hh + f, (gst2, gtraj, kv)
 
     gd = _dget(deltas, "groups")
-    kv = state["kv"]
-    if quantized:
-        h, (gstates, gtraj, ks, vs, ksc, vsc) = jax.lax.scan(
-            group_body, h, (params["groups"], gd, state["groups"],
-                            kv["k"], kv["v"], kv["k_scale"], kv["v_scale"]))
-        new_kv = {"k": ks, "v": vs, "k_scale": ksc, "v_scale": vsc}
-    else:
-        h, (gstates, gtraj, ks, vs) = jax.lax.scan(
-            group_body, h,
-            (params["groups"], gd, state["groups"], kv["k"], kv["v"]))
-        new_kv = {"k": ks, "v": vs}
+    h, (gstates, gtraj, new_kv) = jax.lax.scan(
+        group_body, h, (params["groups"], gd, state["groups"], state["kv"]))
     new_state = dict(state)
     new_state["groups"] = gstates
     new_state["kv"] = new_kv
@@ -462,7 +423,7 @@ def rollback_cache(state, slots, new_lens, trajectory=None):
     out = dict(state)
     kv = dict(state["kv"])
     for name in ("k", "v"):
-        kv[name] = jnp.where(wipe[None, :, :, None, None], 0, kv[name])
+        kv[name] = jnp.where(wipe[None, :, :, None], 0, kv[name])
     if "k_scale" in kv:
         for name in ("k_scale", "v_scale"):
             kv[name] = jnp.where(wipe[None], 0, kv[name])

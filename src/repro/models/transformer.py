@@ -150,7 +150,8 @@ def _layer_forward(lp, ld, h, cfg: ModelConfig, policy, positions, inv_freq,
         hn = rmsnorm(lp["ln2"], h, cfg.norm_eps)
         f, aux = _ffn(lp, hn, cfg, policy, ld, mm)
         h = constrain(h + f, "act")
-    return h, aux, (k, v)
+    # K/V in the cache's stored layout, (B, S, KV*D)
+    return h, aux, (k.reshape(b, s, -1), v.reshape(b, s, -1))
 
 
 # --- full forward (train) ----------------------------------------------------------
@@ -211,12 +212,14 @@ def cache_len_for(cfg: ModelConfig, max_len: int) -> int:
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16,
                quantized: bool = False):
-    """KV cache. ``quantized``: int8 entries + per-(layer,batch,position)
-    fp32 scales — the paper's on-chip-quantization principle applied to the
-    decode cache, which dominates decode HBM traffic at long context
-    (beyond-paper, §Perf H-kv8). Scales factor exactly through attention."""
+    """KV cache, stacked over layers in the layout the attention kernels
+    read: (L, B, S, KV*D), KV head j in lane block j. ``quantized``: int8
+    entries + per-(layer,batch,position) fp32 scales (L, B, S) — the
+    paper's on-chip-quantization principle applied to the decode cache,
+    which dominates decode HBM traffic at long context (beyond-paper, §Perf
+    H-kv8). Scales factor exactly through attention."""
     s = cache_len_for(cfg, max_len)
-    shape = (cfg.num_layers, batch, s, cfg.num_kv_heads, cfg.head_dim)
+    shape = (cfg.num_layers, batch, s, cfg.num_kv_heads * cfg.head_dim)
     if quantized:
         return {"k": jnp.zeros(shape, jnp.int8),
                 "v": jnp.zeros(shape, jnp.int8),
@@ -228,12 +231,35 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16,
 
 
 def _quantize_kv(x: jnp.ndarray):
-    """(B, S, KV, D) -> (int8 values, (B, S) scales). Per-token absmax."""
-    amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=(-2, -1))
+    """(..., KV*D) -> (int8 values, (...) scales). Per-token absmax over
+    every head of the token."""
+    amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1)
     scale = jnp.maximum(amax, 1e-6) / 127.0
-    q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale[..., None, None]),
+    q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale[..., None]),
                  -127, 127).astype(jnp.int8)
     return q, scale
+
+
+def _kv_names(cache):
+    return ("k", "v") + (("k_scale", "v_scale") if "k_scale" in cache else ())
+
+
+def _kv_write(kv, idx, k, v):
+    """Write new K/V rows into the cache leaves ``kv`` at ``idx``, a tuple
+    of index arrays that broadcast to the rows' leading dims; k/v are
+    (..., KV, D). Quantized first for an int8 cache. Only the new rows are
+    written: a carried cache is updated in place."""
+    k = k.reshape(k.shape[:-2] + (-1,))
+    v = v.reshape(v.shape[:-2] + (-1,))
+    out = dict(kv)
+    if "k_scale" in kv:
+        k, ksc = _quantize_kv(k)
+        v, vsc = _quantize_kv(v)
+        out["k_scale"] = kv["k_scale"].at[idx].set(ksc)
+        out["v_scale"] = kv["v_scale"].at[idx].set(vsc)
+    out["k"] = kv["k"].at[idx].set(k.astype(kv["k"].dtype))
+    out["v"] = kv["v"].at[idx].set(v.astype(kv["v"].dtype))
+    return out
 
 
 def prefill(params, batch, cfg: ModelConfig, *, policy: QuantPolicy,
@@ -292,9 +318,9 @@ def prefill(params, batch, cfg: ModelConfig, *, policy: QuantPolicy,
         logits = _logits(params, h, cfg, policy, deltas, matmul_mode)
     with jax.named_scope("model.kv_write"):
         if cs > ks.shape[2]:
-            padw = cs - ks.shape[2]
-            ks = jnp.pad(ks, ((0, 0), (0, 0), (0, padw), (0, 0), (0, 0)))
-            vs = jnp.pad(vs, ((0, 0), (0, 0), (0, padw), (0, 0), (0, 0)))
+            padw = ((0, 0), (0, 0), (0, cs - ks.shape[2]), (0, 0))
+            ks = jnp.pad(ks, padw)
+            vs = jnp.pad(vs, padw)
         elif cfg.sliding_window and s >= cs and s % cs:
             # ring-buffer invariant: token t lives at slot t % cs. The slice
             # put token s-cs+i at slot i; roll by s % cs so it sits at
@@ -303,13 +329,25 @@ def prefill(params, batch, cfg: ModelConfig, *, policy: QuantPolicy,
             vs = jnp.roll(vs, s % cs, axis=2)
         clen = jnp.asarray(s, jnp.int32) if lengths is None else lengths
         if quantize_cache:
-            qk, sk = jax.vmap(_quantize_kv)(ks)       # over layer dim
-            qv, sv = jax.vmap(_quantize_kv)(vs)
+            qk, sk = _quantize_kv(ks)
+            qv, sv = _quantize_kv(vs)
             cache = {"k": qk, "v": qv, "k_scale": sk, "v_scale": sv,
                      "len": clen}
         else:
             cache = {"k": ks, "v": vs, "len": clen}
     return logits, cache
+
+
+def _layer_loop(body, h, params, deltas, cache):
+    """Scan ``body((h, kv), (layer params, layer deltas, layer index))``
+    over the layers with the stacked K/V leaves in the carry, so each layer
+    writes its new rows into the one cache in place and the attention
+    kernels read it by layer index. Returns (h, kv)."""
+    kv = {n: cache[n] for n in _kv_names(cache)}
+    ld = deltas.get("layers") if deltas else None
+    layers = jnp.arange(cache["k"].shape[0], dtype=jnp.int32)
+    (h, kv), _ = jax.lax.scan(body, (h, kv), (params["layers"], ld, layers))
+    return h, kv
 
 
 def decode_step(params, cache, tokens: jnp.ndarray, cfg: ModelConfig, *,
@@ -330,10 +368,14 @@ def decode_step(params, cache, tokens: jnp.ndarray, cfg: ModelConfig, *,
     implementation — the fused Pallas ``kernels.attn_decode`` kernel or the
     einsum reference (see :func:`repro.models.attention.decode_attention`);
     it reads the int8 cache (``k_scale`` present) either way.
+
+    The stacked cache rides the layer loop's carry: layer ``l`` writes only
+    its B new rows, at ``[l, rows, slot]``, and the kernel reads layer ``l``
+    by index, so with the cache donated the tick updates it in place and
+    never slices, relays out or writes back a layer.
     """
     b = tokens.shape[0]
     pos = jnp.broadcast_to(cache["len"], (b,)).astype(jnp.int32)   # (B,)
-    quantized = "k_scale" in cache
     with jax.named_scope("model.embed"):
         h = embed_lookup(params["embed"], tokens, policy=policy,
                          delta=_dget(deltas, "embed", "w"), dtype=dtype)
@@ -343,51 +385,31 @@ def decode_step(params, cache, tokens: jnp.ndarray, cfg: ModelConfig, *,
     cs = cache["k"].shape[2]
     slot = jnp.mod(pos, cs) if cfg.sliding_window else pos
     rows = jnp.arange(b)
+    valid = jnp.minimum(pos + 1, cs)
 
-    def body(hh, xs):
-        if quantized:
-            lp, ld, kc, vc, ks_, vs_ = xs
-        else:
-            lp, ld, kc, vc = xs
-            ks_ = vs_ = None
+    def body(carry, xs):
+        hh, kv = carry
+        lp, ld, layer = xs
         with jax.named_scope("model.attn_qkv"):
             hn = rmsnorm(lp["ln1"], hh, cfg.norm_eps)
             q, k, v = _qkv(lp, hn, cfg, policy, ld, positions, inv_freq,
                            matmul_mode)
         with jax.named_scope("model.kv_write"):
-            if quantized:
-                kq, ksc = _quantize_kv(k)
-                vq, vsc = _quantize_kv(v)
-                kc = kc.at[rows, slot].set(kq[:, 0])
-                vc = vc.at[rows, slot].set(vq[:, 0])
-                ks_ = ks_.at[rows, slot].set(ksc[:, 0])
-                vs_ = vs_.at[rows, slot].set(vsc[:, 0])
-            else:
-                kc = kc.at[rows, slot].set(k[:, 0].astype(kc.dtype))
-                vc = vc.at[rows, slot].set(v[:, 0].astype(vc.dtype))
+            kv = _kv_write(kv, (layer, rows, slot), k[:, 0], v[:, 0])
         with jax.named_scope("model.attention"):
-            valid = jnp.minimum(pos + 1, cs)
-            o = decode_attention(q, kc, vc, valid, k_scale=ks_, v_scale=vs_,
-                                 mode=attn_mode)
+            o = decode_attention(q, kv["k"], kv["v"], valid,
+                                 kv.get("k_scale"), kv.get("v_scale"),
+                                 layer=layer, mode=attn_mode)
         with jax.named_scope("model.attn_out"):
             hh = hh + _attn_out(lp, o, cfg, policy, ld, b, 1, matmul_mode)
         with jax.named_scope("model.mlp"):
             hn = rmsnorm(lp["ln2"], hh, cfg.norm_eps)
             f, _ = _ffn(lp, hn, cfg, policy, ld, matmul_mode)
             hh = hh + f
-        return hh, (kc, vc, ks_, vs_) if quantized else (kc, vc)
+        return (hh, kv), None
 
-    ld = deltas.get("layers") if deltas else None
-    if quantized:
-        h, (ks, vs, ksc, vsc) = jax.lax.scan(
-            body, h, (params["layers"], ld, cache["k"], cache["v"],
-                      cache["k_scale"], cache["v_scale"]))
-        new_cache = {"k": ks, "v": vs, "k_scale": ksc, "v_scale": vsc,
-                     "len": cache["len"] + 1}
-    else:
-        h, (ks, vs) = jax.lax.scan(body, h, (params["layers"], ld, cache["k"],
-                                             cache["v"]))
-        new_cache = {"k": ks, "v": vs, "len": cache["len"] + 1}
+    h, kv = _layer_loop(body, h, params, deltas, cache)
+    new_cache = dict(kv, len=cache["len"] + 1)
     with jax.named_scope("model.final_norm"):
         h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
     with jax.named_scope("model.readout"):
@@ -420,7 +442,6 @@ def verify_step(params, cache, tokens: jnp.ndarray, cfg: ModelConfig, *,
     """
     b, t = tokens.shape
     pos0 = jnp.broadcast_to(cache["len"], (b,)).astype(jnp.int32)  # (B,)
-    quantized = "k_scale" in cache
     h = embed_lookup(params["embed"], tokens, policy=policy,
                      delta=_dget(deltas, "embed", "w"), dtype=dtype)
     h = constrain(h, "dec_act")
@@ -429,46 +450,24 @@ def verify_step(params, cache, tokens: jnp.ndarray, cfg: ModelConfig, *,
     cs = cache["k"].shape[2]
     slot = jnp.mod(positions, cs) if cfg.sliding_window else positions
     rows = jnp.arange(b)[:, None]                                  # (B, 1)
+    valid = jnp.minimum(positions + 1, cs)                         # (B, T)
 
-    def body(hh, xs):
-        if quantized:
-            lp, ld, kc, vc, ks_, vs_ = xs
-        else:
-            lp, ld, kc, vc = xs
-            ks_ = vs_ = None
+    def body(carry, xs):
+        hh, kv = carry
+        lp, ld, layer = xs
         hn = rmsnorm(lp["ln1"], hh, cfg.norm_eps)
         q, k, v = _qkv(lp, hn, cfg, policy, ld, positions, inv_freq,
                        matmul_mode)
-        if quantized:
-            kq, ksc = _quantize_kv(k)
-            vq, vsc = _quantize_kv(v)
-            kc = kc.at[rows, slot].set(kq)
-            vc = vc.at[rows, slot].set(vq)
-            ks_ = ks_.at[rows, slot].set(ksc)
-            vs_ = vs_.at[rows, slot].set(vsc)
-        else:
-            kc = kc.at[rows, slot].set(k.astype(kc.dtype))
-            vc = vc.at[rows, slot].set(v.astype(vc.dtype))
-        valid = jnp.minimum(positions + 1, cs)                     # (B, T)
-        o = verify_attention(q, kc, vc, valid, k_scale=ks_, v_scale=vs_,
-                             mode=attn_mode)
+        kv = _kv_write(kv, (layer, rows, slot), k, v)
+        o = verify_attention(q, kv["k"], kv["v"], valid, kv.get("k_scale"),
+                             kv.get("v_scale"), layer=layer, mode=attn_mode)
         hh = hh + _attn_out(lp, o, cfg, policy, ld, b, t, matmul_mode)
         hn = rmsnorm(lp["ln2"], hh, cfg.norm_eps)
         f, _ = _ffn(lp, hn, cfg, policy, ld, matmul_mode)
-        out = (hh + f, (kc, vc, ks_, vs_) if quantized else (kc, vc))
-        return out
+        return (hh + f, kv), None
 
-    ld = deltas.get("layers") if deltas else None
-    if quantized:
-        h, (ks, vs, ksc, vsc) = jax.lax.scan(
-            body, h, (params["layers"], ld, cache["k"], cache["v"],
-                      cache["k_scale"], cache["v_scale"]))
-        new_cache = {"k": ks, "v": vs, "k_scale": ksc, "v_scale": vsc,
-                     "len": cache["len"] + t}
-    else:
-        h, (ks, vs) = jax.lax.scan(body, h, (params["layers"], ld, cache["k"],
-                                             cache["v"]))
-        new_cache = {"k": ks, "v": vs, "len": cache["len"] + t}
+    h, kv = _layer_loop(body, h, params, deltas, cache)
+    new_cache = dict(kv, len=cache["len"] + t)
     h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
     logits = _logits(params, h, cfg, policy, deltas, matmul_mode)
     return logits, new_cache, None
@@ -512,7 +511,7 @@ def rollback_cache(cache, slots, new_lens, trajectory=None):
     wipe = _wipe_mask(tgt, cur, cs)                                # (B, S)
     out = dict(cache)
     for name in ("k", "v"):
-        out[name] = jnp.where(wipe[None, :, :, None, None], 0, cache[name])
+        out[name] = jnp.where(wipe[None, :, :, None], 0, cache[name])
     if "k_scale" in cache:
         for name in ("k_scale", "v_scale"):
             out[name] = jnp.where(wipe[None], 0, cache[name])
@@ -529,8 +528,7 @@ def free_slots(cache, slots):
     non-finite K/V entries cannot linger). Entries with ``slots[i] >=
     batch`` are dropped (the engine's padding convention)."""
     out = dict(cache)
-    names = ("k", "v") + (("k_scale", "v_scale") if "k_scale" in cache else ())
-    for name in names:                       # leaves (L, slots, ...): axis 1
+    for name in _kv_names(cache):          # leaves (L, slots, ...): axis 1
         out[name] = cache[name].at[:, slots].set(0, mode="drop")
     out["len"] = cache["len"].at[slots].set(0, mode="drop")
     return out
@@ -564,8 +562,7 @@ def insert_prefill_many(cache, slot_map, src):
     (JAX scatter OOB semantics) — the engine points padding rows there.
     """
     out = dict(cache)
-    names = ("k", "v") + (("k_scale", "v_scale") if "k_scale" in cache else ())
-    for name in names:                       # leaves (L, slots, ...): axis 1
+    for name in _kv_names(cache):          # leaves (L, slots, ...): axis 1
         out[name] = cache[name].at[:, slot_map].set(
             src[name].astype(cache[name].dtype), mode="drop")
     out["len"] = cache["len"].at[slot_map].set(

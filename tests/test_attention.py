@@ -26,6 +26,11 @@ def ref_attn(q, k, v, causal=True, window=0):
     return jnp.einsum("bhqk,bkhd->bqhd", p, vv)
 
 
+def _stored(x):
+    """(B, S, KV, D) -> the cache's stored layout, one layer: (1, B, S, KV*D)."""
+    return x.reshape(x.shape[0], x.shape[1], -1)[None]
+
+
 def _qkv(seed, b, l, h, kv, d):
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     return (jax.random.normal(ks[0], (b, l, h, d)),
@@ -56,7 +61,8 @@ def test_decode_matches_last_position():
     s = 64
     kc = jnp.zeros((b, s, kv, d)).at[:, :l].set(k)
     vc = jnp.zeros((b, s, kv, d)).at[:, :l].set(v)
-    out = decode_attention(q[:, -1:], kc, vc, jnp.full((b,), l))
+    out = decode_attention(q[:, -1:], _stored(kc), _stored(vc),
+                           jnp.full((b,), l))
     ref = ref_attn(q, k, v)[:, -1:]
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
@@ -65,8 +71,9 @@ def test_decode_ring_permutation_invariance():
     """Ring-buffer storage order must not change decode attention."""
     b, l, h, kv, d = 1, 16, 4, 4, 8
     q, k, v = _qkv(3, b, l, h, kv, d)
-    out1 = decode_attention(q[:, -1:], k, v, jnp.full((b,), l))
+    out1 = decode_attention(q[:, -1:], _stored(k), _stored(v),
+                            jnp.full((b,), l))
     perm = jax.random.permutation(jax.random.PRNGKey(9), l)
-    out2 = decode_attention(q[:, -1:], k[:, perm], v[:, perm],
+    out2 = decode_attention(q[:, -1:], _stored(k[:, perm]), _stored(v[:, perm]),
                             jnp.full((b,), l))
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), atol=2e-5)

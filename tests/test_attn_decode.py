@@ -23,25 +23,38 @@ def _case(seed, b, s, h, kv, d):
     return q, kc, vc
 
 
+def _stored(x):
+    """(B, S, KV, D) -> the cache's stored layout, one layer: (1, B, S, KV*D)."""
+    return x.reshape(x.shape[0], x.shape[1], -1)[None]
+
+
+def _quantized(x):
+    """(B, S, KV, D) -> int8 (B, S, KV, D) and its (B, S) scales."""
+    q, sc = _quantize_kv(x.reshape(x.shape[0], x.shape[1], -1))
+    return q.reshape(x.shape), sc
+
+
 @pytest.mark.parametrize("h,kv", [(8, 8), (8, 2), (4, 1)])
 @pytest.mark.parametrize("bm,bs", [(8, 128), (2, 32), (3, 17)])
 def test_kernel_matches_ref_and_einsum(h, kv, bm, bs):
     """Mixed per-row lengths (incl. 1 and full): kernel == ref == einsum.
-    bm/bs sweep covers B and S not divisible by the block sizes."""
+    bm/bs sweep covers B and S not divisible by the requested block sizes
+    (the kernel blocks by divisors: the cache is read in place)."""
     b, s, d = 5, 100, 16
     q, kc, vc = _case(0, b, s, h, kv, d)
     lens = jnp.asarray([1, 7, 64, 100, 33], jnp.int32)
-    out = attn_decode(q, kc, vc, lens, bm=bm, bs=bs, interpret=True)
+    out = attn_decode(q, _stored(kc), _stored(vc), lens, bm=bm, bs=bs,
+                      interpret=True)
     ref = attn_decode_ref(q, kc, vc, lens)
-    ein = decode_attention(q, kc, vc, lens, mode="ref")
+    ein = decode_attention(q, _stored(kc), _stored(vc), lens, mode="ref")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ein), atol=2e-5)
 
 
 def test_kernel_scalar_cache_len():
     q, kc, vc = _case(1, 4, 64, 8, 2, 16)
-    out = attn_decode(q, kc, vc, 42, interpret=True)
-    ein = decode_attention(q, kc, vc, 42, mode="ref")
+    out = attn_decode(q, _stored(kc), _stored(vc), 42, interpret=True)
+    ein = decode_attention(q, _stored(kc), _stored(vc), 42, mode="ref")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ein), atol=2e-5)
 
 
@@ -50,16 +63,18 @@ def test_kernel_int8_cache_with_scales():
     epilogue must factor the scales exactly where decode_attention does."""
     b, s = 5, 80
     q, kc, vc = _case(2, b, s, 8, 2, 16)
-    kq, ksc = _quantize_kv(kc)
-    vq, vsc = _quantize_kv(vc)
+    kq, ksc = _quantized(kc)
+    vq, vsc = _quantized(vc)
     lens = jnp.asarray([1, 80, 13, 37, 64], jnp.int32)
-    out = attn_decode(q, kq, vq, lens, ksc, vsc, bm=2, bs=32, interpret=True)
+    out = attn_decode(q, _stored(kq), _stored(vq), lens, ksc[None],
+                      vsc[None], bm=2, bs=32, interpret=True)
     ref = attn_decode_ref(q, kq, vq, lens, ksc, vsc)
-    ein = decode_attention(q, kq, vq, lens, ksc, vsc, mode="ref")
+    ein = decode_attention(q, _stored(kq), _stored(vq), lens, ksc[None],
+                           vsc[None], mode="ref")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ein), atol=2e-5)
     # and the int8 path is actually close to the float attention it encodes
-    full = decode_attention(q, kc, vc, lens, mode="ref")
+    full = decode_attention(q, _stored(kc), _stored(vc), lens, mode="ref")
     assert float(jnp.max(jnp.abs(out - full))) < 0.1
 
 
@@ -67,8 +82,8 @@ def test_kernel_bf16_cache():
     q, kc, vc = _case(3, 4, 64, 8, 2, 16)
     kc16, vc16 = kc.astype(jnp.bfloat16), vc.astype(jnp.bfloat16)
     lens = jnp.asarray([5, 64, 17, 50], jnp.int32)
-    out = attn_decode(q, kc16, vc16, lens, interpret=True)
-    ein = decode_attention(q, kc16, vc16, lens, mode="ref")
+    out = attn_decode(q, _stored(kc16), _stored(vc16), lens, interpret=True)
+    ein = decode_attention(q, _stored(kc16), _stored(vc16), lens, mode="ref")
     assert out.dtype == q.dtype
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ein, np.float32), atol=2e-2)
@@ -79,10 +94,11 @@ def test_kernel_ring_permutation_invariance():
     (mirrors the einsum-path test in test_attention.py)."""
     b, l, h, kv, d = 1, 16, 4, 4, 8
     q, kc, vc = _case(4, b, l, h, kv, d)
-    out1 = attn_decode(q, kc, vc, jnp.full((b,), l), interpret=True)
-    perm = jax.random.permutation(jax.random.PRNGKey(9), l)
-    out2 = attn_decode(q, kc[:, perm], vc[:, perm], jnp.full((b,), l),
+    out1 = attn_decode(q, _stored(kc), _stored(vc), jnp.full((b,), l),
                        interpret=True)
+    perm = jax.random.permutation(jax.random.PRNGKey(9), l)
+    out2 = attn_decode(q, _stored(kc[:, perm]), _stored(vc[:, perm]),
+                       jnp.full((b,), l), interpret=True)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), atol=2e-5)
 
 
@@ -91,7 +107,7 @@ def test_zero_length_rows_are_zero():
     uniform v average — both kernel and ref guard the empty softmax."""
     q, kc, vc = _case(5, 3, 32, 4, 2, 8)
     lens = jnp.asarray([0, 16, 0], jnp.int32)
-    out = attn_decode(q, kc, vc, lens, interpret=True)
+    out = attn_decode(q, _stored(kc), _stored(vc), lens, interpret=True)
     ref = attn_decode_ref(q, kc, vc, lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6)
     assert not np.any(np.isnan(np.asarray(out)))
@@ -112,7 +128,8 @@ def test_attn_mode_dispatch():
     assert "auto" in ATTN_MODES
     q, kc, vc = _case(6, 2, 40, 8, 2, 16)
     lens = jnp.asarray([11, 40], jnp.int32)
-    out_k = decode_attention(q, kc, vc, lens, mode="kernel", interpret=True)
-    out_r = decode_attention(q, kc, vc, lens, mode="ref")
+    out_k = decode_attention(q, _stored(kc), _stored(vc), lens,
+                             mode="kernel", interpret=True)
+    out_r = decode_attention(q, _stored(kc), _stored(vc), lens, mode="ref")
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                atol=2e-5)

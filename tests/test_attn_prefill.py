@@ -38,6 +38,17 @@ def _case(seed, b, t, s, h, kv, d, dtype=jnp.float32):
     return q, k, v
 
 
+def _stored(x):
+    """(B, S, KV, D) -> the cache's stored layout, one layer: (1, B, S, KV*D)."""
+    return x.reshape(x.shape[0], x.shape[1], -1)[None]
+
+
+def _quantized(x):
+    """(B, S, KV, D) -> int8 (B, S, KV, D) and its (B, S) scales."""
+    q, sc = _quantize_kv(x.reshape(x.shape[0], x.shape[1], -1))
+    return q.reshape(x.shape), sc
+
+
 def _oracle(q, k, v, hi, lo=None, k_scale=None, v_scale=None):
     """attn_prefill_ref through the same GQA/pre-scale plumbing as ops.py."""
     b, t, h, d = q.shape
@@ -59,11 +70,13 @@ def _prefill_hi(lens, t):
 @pytest.mark.parametrize("bt,bs", [(16, 32), (8, 24), (7, 13), (128, 128)])
 def test_kernel_matches_oracle_blocking(h, kv, bt, bs):
     """Mixed per-row lengths (incl. 1 and full) under the bucketed-prefill
-    rule; bt/bs sweep covers T and S not divisible by the block sizes."""
+    rule; bt/bs sweep covers T and S not divisible by the requested block
+    sizes (queries are padded, keys blocked by a divisor of S)."""
     b, t, d = 3, 50, 16
     q, k, v = _case(0, b, t, t, h, kv, d)
     hi = _prefill_hi([50, 17, 1], t)
-    out = attn_prefill(q, k, v, hi, bt=bt, bs=bs, interpret=True)
+    out = attn_prefill(q, _stored(k), _stored(v), hi, bt=bt, bs=bs,
+                       interpret=True)
     ref = _oracle(q, k, v, hi)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6)
     # every REAL query position also matches the production chunked path
@@ -78,7 +91,8 @@ def test_kernel_single_row_bucket():
     """B=1 admission bucket, T=S=33 not divisible by either block size."""
     q, k, v = _case(1, 1, 33, 33, 4, 2, 8)
     hi = _prefill_hi([33], 33)
-    out = attn_prefill(q, k, v, hi, bt=8, bs=16, interpret=True)
+    out = attn_prefill(q, _stored(k), _stored(v), hi, bt=8, bs=16,
+                       interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(_oracle(q, k, v, hi)),
                                atol=2e-6)
 
@@ -86,7 +100,8 @@ def test_kernel_single_row_bucket():
 def test_kernel_bf16():
     q, k, v = _case(2, 2, 40, 40, 8, 2, 16, jnp.bfloat16)
     hi = _prefill_hi([40, 23], 40)
-    out = attn_prefill(q, k, v, hi, bt=16, bs=16, interpret=True)
+    out = attn_prefill(q, _stored(k), _stored(v), hi, bt=16, bs=16,
+                       interpret=True)
     ref = _oracle(q, k, v, hi)
     assert out.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -98,11 +113,11 @@ def test_kernel_int8_kv_with_scales():
     must factor the scales exactly where the ref einsum does."""
     b, t = 3, 41
     q, k, v = _case(3, b, t, t, 8, 2, 16)
-    kq, ksc = _quantize_kv(k)
-    vq, vsc = _quantize_kv(v)
+    kq, ksc = _quantized(k)
+    vq, vsc = _quantized(v)
     hi = _prefill_hi([41, 9, 28], t)
-    out = attn_prefill(q, kq, vq, hi, k_scale=ksc, v_scale=vsc,
-                       bt=16, bs=16, interpret=True)
+    out = attn_prefill(q, _stored(kq), _stored(vq), hi, k_scale=ksc[None],
+                       v_scale=vsc[None], bt=16, bs=16, interpret=True)
     ref = _oracle(q, kq, vq, hi, k_scale=ksc, v_scale=vsc)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6)
     # and the int8 path stays close to the float attention it encodes
@@ -118,7 +133,8 @@ def test_kernel_swa_window():
     pos = jnp.arange(t, dtype=jnp.int32)
     hi = jnp.broadcast_to(pos[None, :] + 1, (b, t))
     lo = jnp.broadcast_to(jnp.maximum(pos - (w - 1), 0)[None], (b, t))
-    out = attn_prefill(q, k, v, hi, lo=lo, bt=16, bs=16, interpret=True)
+    out = attn_prefill(q, _stored(k), _stored(v), hi, lo=lo, bt=16, bs=16,
+                       interpret=True)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(_oracle(q, k, v, hi, lo=lo)),
                                atol=2e-6)
@@ -152,6 +168,7 @@ def test_verify_attention_dispatch():
     q, _, _ = _case(6, b, t, s, 8, 2, 16)
     _, kc, vc = _case(7, b, t, s, 8, 2, 16)
     valid = jnp.asarray([[5, 6, 7], [1, 2, 3], [48, 49, 50]], jnp.int32)
+    kc, vc = _stored(kc), _stored(vc)
     out_k = verify_attention(q, kc, vc, valid, mode="kernel", interpret=True)
     out_r = verify_attention(q, kc, vc, valid, mode="ref")
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
@@ -174,7 +191,8 @@ def test_verify_attention_empty_row_guard():
     q, kc, vc = _case(8, b, t, s, 4, 2, 8)
     valid = jnp.asarray([[0, 0, 0], [4, 5, 6]], jnp.int32)
     for mode in ("ref", "kernel"):
-        out = verify_attention(q, kc, vc, valid, mode=mode, interpret=True)
+        out = verify_attention(q, _stored(kc), _stored(vc), valid, mode=mode,
+                               interpret=True)
         assert not np.any(np.isnan(np.asarray(out))), mode
         np.testing.assert_array_equal(np.asarray(out[0]), 0.0, err_msg=mode)
         assert float(jnp.max(jnp.abs(out[1]))) > 0, mode

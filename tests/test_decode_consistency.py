@@ -73,7 +73,9 @@ def test_swa_ring_buffer_wraps_correctly():
                           dtype=jnp.float32)
     logits, cache = mod.prefill(params, {"tokens": toks[:, :P]}, cfg,
                                 policy=FLOAT, dtype=jnp.float32, max_len=S)
-    assert cache["k"].shape[2] == 8            # bounded by window
+    # (L, B, S, KV*D): the position axis, bounded by the window
+    assert cache["k"].shape == (cfg.num_layers, B, 8,
+                                cfg.num_kv_heads * cfg.head_dim)
     for t in range(P, S):
         logits, cache = mod.decode_step(params, cache, toks[:, t:t + 1], cfg,
                                         policy=FLOAT, dtype=jnp.float32)

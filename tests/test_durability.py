@@ -147,6 +147,31 @@ def test_snapshot_compat_checked_loudly(tmp_path):
             other.restore(str(tmp_path / "s"))
 
 
+def test_restore_refuses_old_kv_layout(tmp_path):
+    """A snapshot whose KV cache is in the old (L, B, S, KV, D) layout —
+    same bytes, other shape — is refused, naming the leaf and both shapes,
+    never read as the stored (L, B, S, KV*D) layout."""
+    from repro import checkpoint
+    cfg, params, policy = _setup()
+    eng = _engine(params, cfg, policy)
+    _submit_all(eng)
+    eng.step()
+    eng.snapshot(str(tmp_path / "s"))
+    dev, meta = checkpoint.restore(str(tmp_path / "s"))
+    old = dict(dev["cache"])
+    for name in ("k", "v"):
+        lyr, b, s, _ = old[name].shape
+        old[name] = old[name].reshape(lyr, b, s, cfg.num_kv_heads,
+                                      cfg.head_dim)
+    checkpoint.save(str(tmp_path / "old"), 1, dict(dev, cache=old),
+                    meta=meta)
+    fresh = _engine(params, cfg, policy)
+    shape = r"\({}, 3, 64, {}, {}\)".format(cfg.num_layers,
+                                          cfg.num_kv_heads, cfg.head_dim)
+    with pytest.raises(ValueError, match=r"\['k'\].*" + shape):
+        fresh.restore(str(tmp_path / "old"))
+
+
 # --- crash + recovery ---------------------------------------------------------
 
 @pytest.mark.parametrize("crash_at", [1, 4, 9])

@@ -167,7 +167,9 @@ def test_shared_cache_allocated_once_per_slot_lens():
     eng = ServingEngine(params, cfg, policy=policy, slots=4, max_len=16,
                         dtype=jnp.float32)
     assert eng.cache["len"].shape == (4,)
-    assert eng.cache["k"].shape[1] == 4     # (L, slots, S, KV, D)
+    # (L, slots, S, KV*D): the attention kernels' layout, as stored
+    assert eng.cache["k"].shape == (cfg.num_layers, 4, 16,
+                                    cfg.num_kv_heads * cfg.head_dim)
     eng.submit(PROMPT, max_new=2)
     eng.submit(PROMPT, max_new=4)
     eng.step()

@@ -19,9 +19,11 @@ B, S, P = 2, 20, 16
 
 
 def test_quantize_kv_roundtrip():
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 8, 4, 16)) * 3
+    # one token's KV heads side by side, as the cache stores them: 4 x 16
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 8, 4 * 16)) * 3
     q, s = _quantize_kv(x)
-    back = q.astype(jnp.float32) * s[..., None, None]
+    assert s.shape == (2, 8)
+    back = q.astype(jnp.float32) * s[..., None]
     assert float(jnp.max(jnp.abs(back - x))) <= float(jnp.max(s)) / 2 + 1e-5
 
 
@@ -78,6 +80,9 @@ def test_kv8_hybrid_decode_close_to_bf16():
                                     policy=FLOAT, dtype=jnp.float32, max_len=S,
                                     quantize_cache=True)
     assert st_q["kv"]["k"].dtype == jnp.int8
+    # (G, B, S, KV*D) entries, (G, B, S) scales
+    assert st_q["kv"]["k"].shape == (cfg.num_layers // cfg.attn_every, B, S,
+                                     cfg.num_kv_heads * cfg.head_dim)
     assert st_q["kv"]["k_scale"].shape == st_q["kv"]["k"].shape[:3]
     np.testing.assert_allclose(np.asarray(logits_q), np.asarray(logits_f),
                                atol=1e-4)   # prefill logits don't read cache
@@ -122,8 +127,12 @@ def test_kv8_swa_ring_scales_rotate_with_slots():
     logits_q, cache_q = transformer.prefill(
         params, {"tokens": toks[:, :P]}, cfg, policy=FLOAT,
         dtype=jnp.float32, max_len=S, quantize_cache=True)
+    # (L, B, S, KV*D): the ring is the position axis, bounded by the window
     cs = cache_q["k"].shape[2]
-    assert cs == 8                                   # ring bounded by window
+    assert cs == 8
+    assert cache_q["k"].shape == (cfg.num_layers, B, cs,
+                                  cfg.num_kv_heads * cfg.head_dim)
+    assert cache_q["k_scale"].shape == (cfg.num_layers, B, cs)
     for t in range(P, S):
         logits_f, cache_f = transformer.decode_step(
             params, cache_f, toks[:, t:t + 1], cfg, policy=FLOAT,
@@ -152,13 +161,14 @@ def test_kv8_ring_masking_parity_kernel_vs_ref():
     b, s, h, kv, d = 4, 24, 8, 2, 16
     ks = jax.random.split(jax.random.PRNGKey(7), 3)
     q = jax.random.normal(ks[0], (b, 1, h, d))
-    kc = jax.random.normal(ks[1], (b, s, kv, d))
-    vc = jax.random.normal(ks[2], (b, s, kv, d))
+    kc = jax.random.normal(ks[1], (1, b, s, kv * d))     # one stored layer
+    vc = jax.random.normal(ks[2], (1, b, s, kv * d))
     kq, ksc = _quantize_kv(kc)
     vq, vsc = _quantize_kv(vc)
     lens = jnp.asarray([3, 24, 11, 17], jnp.int32)   # mixed ring fill
     out = attn_decode(q, kq, vq, lens, ksc, vsc, bm=2, bs=8, interpret=True)
-    ref = attn_decode_ref(q, kq, vq, lens, ksc, vsc)
+    ref = attn_decode_ref(q, kq[0].reshape(b, s, kv, d),
+                          vq[0].reshape(b, s, kv, d), lens, ksc[0], vsc[0])
     ein = decode_attention(q, kq, vq, lens, ksc, vsc, mode="ref")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ein), atol=2e-5)

@@ -31,12 +31,9 @@ Layers:
   vmem          pallas_call -> VMEM footprint estimation
   contracts     the contract-point registry (decode tick, bucketed prefill,
                 spec tick, generate loop) + the family x form x mode sweep
-  hlo           post-SPMD HLO text analysis (collective bytes, cost /
-                memory summaries) — the compiled-artifact backend
 
 Run the sweep: ``python -m repro.analysis --check`` (JSON report; CI gate).
 """
-from repro.analysis import hlo  # noqa: F401  (the HLO-level backend)
 from repro.analysis.passes import (  # noqa: F401
     Violation,
     check_carry_fixed_point,
@@ -60,5 +57,4 @@ __all__ = [
     "check_no_host_callback", "check_carry_fixed_point", "check_donation",
     "check_scan_carries", "check_vmem_budget", "forbidden_dequant_shapes",
     "lint_combo", "run_sweep", "retrace_report", "DEFAULT_VMEM_BUDGET",
-    "hlo",
 ]

@@ -1,5 +1,5 @@
 from repro.configs.base import (ARCH_IDS, LM_SHAPES, ModelConfig, ShapeConfig,
-                                TrainConfig, get_config, reduced, shape_by_name)
+                                TrainConfig, get_config, reduced)
 
 __all__ = ["ARCH_IDS", "LM_SHAPES", "ModelConfig", "ShapeConfig", "TrainConfig",
-           "get_config", "reduced", "shape_by_name"]
+           "get_config", "reduced"]

@@ -12,7 +12,7 @@ import importlib
 from typing import Optional, Tuple
 
 __all__ = ["ModelConfig", "ShapeConfig", "TrainConfig", "get_config",
-           "reduced", "LM_SHAPES", "ARCH_IDS", "shape_by_name"]
+           "reduced", "LM_SHAPES", "ARCH_IDS"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,12 +87,9 @@ class ModelConfig:
             in_dim = 2 * di + 2 * self.ssm_ngroups * ns + self.ssm_heads
             per_layer_ssm = d * in_dim + di * d                   # in/out proj
             per_layer_ssm += self.ssm_conv * (di + 2 * self.ssm_ngroups * ns)
-            if self.family == "ssm":
-                per_layer = per_layer_ssm
-            else:
-                # hybrid: every layer is ssm; ONE shared attn block extra
-                n += per_layer + 3 * d * ff if False else 0
-                per_layer = per_layer_ssm
+            # hybrid: every layer is ssm too; the ONE shared attention
+            # block is added below
+            per_layer = per_layer_ssm
         if ff and self.family not in ("moe", "hybrid"):
             # hybrid layers are pure mamba blocks — only the ONE shared
             # attention block has an FFN (added below)
@@ -137,13 +134,6 @@ LM_SHAPES: Tuple[ShapeConfig, ...] = (
     ShapeConfig("decode_32k", 32_768, 128, "decode"),
     ShapeConfig("long_500k", 524_288, 1, "decode"),
 )
-
-
-def shape_by_name(name: str) -> ShapeConfig:
-    for s in LM_SHAPES:
-        if s.name == name:
-            return s
-    raise KeyError(name)
 
 
 @dataclasses.dataclass(frozen=True)

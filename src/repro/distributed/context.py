@@ -29,31 +29,6 @@ def sharding_rules(table: Dict):
         _state.table = prev
 
 
-# --- cost-exact tracing mode ---------------------------------------------------
-# XLA's HloCostAnalysis counts while-loop bodies once. The dry-run's reduced
-# -depth cost lowerings trace under this flag so models UNROLL their inner
-# chunk loops (attention KV chunks, SSD chunks, hybrid inner layer scan) and
-# flops/bytes come out exact. Never set for real execution or full compiles.
-
-@contextlib.contextmanager
-def cost_exact_mode():
-    prev = getattr(_state, "cost_exact", False)
-    _state.cost_exact = True
-    try:
-        yield
-    finally:
-        _state.cost_exact = prev
-
-
-def is_cost_exact() -> bool:
-    return getattr(_state, "cost_exact", False)
-
-
-def inner_unroll() -> bool:
-    """unroll= argument for inner lax.scans in model code."""
-    return bool(is_cost_exact())
-
-
 def constrain(x, name: str):
     table = _table()
     if not table or name not in table:

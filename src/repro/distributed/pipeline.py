@@ -11,7 +11,7 @@ Differentiable: jax AD transposes ppermute to the reverse permutation, so
 ``jax.grad`` through ``pipeline_apply`` yields the backward pipeline
 (GPipe semantics: full activation stash, no interleaving).
 
-The production dry-run meshes use DP x TP (pod/data/model); this module is
+The production meshes use DP x TP (pod/data/model); this module is
 the composable PP option for depth-dominated models — enable by adding a
 ``stage`` axis to the mesh and scanning each stage's layers inside
 ``stage_fn``.

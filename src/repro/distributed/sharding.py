@@ -53,7 +53,7 @@ def _guarded(shape: Sequence[int], mesh: Mesh,
 def param_specs(cfg: ModelConfig, params_tree: Any, mesh: Mesh,
                 fsdp: bool = False) -> Any:
     """PartitionSpec tree mirroring ``params_tree`` (works on ShapeDtypeStruct
-    templates from jax.eval_shape — the dry-run path).
+    templates from jax.eval_shape — ``launch/steps.py``).
 
     ``fsdp=True`` additionally shards every >=2D weight over the ``data``
     axis on a free dim (ZeRO-3 semantics: XLA all-gathers per layer in

@@ -9,8 +9,8 @@ one cast back to the query dtype. Rows with ``cache_len == 0`` return zeros
 
 In exact arithmetic this equals ``models.attention.decode_attention``
 whenever every row has ``cache_len >= 1`` — which ``decode_step`` always
-guarantees — so the kernel is cross-checked against both (tests +
-``benchmarks/kernels_bench.py`` parity gate).
+guarantees — so the kernel is cross-checked against both
+(``tests/test_attn_decode.py``).
 """
 from __future__ import annotations
 

@@ -1,4 +1,4 @@
-"""Production mesh definition (MULTI-POD DRY-RUN spec, step 1).
+"""Production mesh definition, single pod and multi-pod.
 
 A FUNCTION, not a module-level constant — importing this module never touches
 jax device state. Single pod: 16x16 = 256 chips (v5e pod slice), axes

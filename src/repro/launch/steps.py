@@ -1,39 +1,25 @@
-"""Step builders for the dry-run and real launches: given (arch, shape, mesh)
-produce the jitted step with full in/out shardings plus ShapeDtypeStruct
-input templates (``input_specs`` — no device allocation anywhere).
-
-Cell kinds (DESIGN §5 regime mapping):
-  train    QAT train_step (W3A8 fake-quant, frozen per-layer deltas in state,
-           AdamW, microbatched, remat, FSDP for >=8B params)
-  prefill  serve forward with int8-level weights ("q" form — 1 B/wt stream)
-  decode   one-token serve step with container-packed weights ("qp" form —
-           the paper's 0.4 B/wt BRAM image)
-
-``quant='float'`` switches any cell to the bf16 GPU-like baseline for
-before/after comparisons.
+"""Shape-only templates for one (arch x shape) cell: ``input_specs`` gives
+ShapeDtypeStruct stand-ins for the model inputs, ``_params_template`` the
+parameters in a weight form (float, int8 levels for prefill, packed 3-bit
+containers for decode) and ``_state_template`` the training state. All go
+through ``jax.eval_shape``, so nothing is allocated on any device.
 """
 from __future__ import annotations
 
-import dataclasses
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from repro import optim as optim_lib
 from repro.configs.base import ModelConfig, ShapeConfig, TrainConfig
 from repro.core import quant_dense
 from repro.core.precision import FLOAT, W3A8, QuantPolicy
-from repro.distributed import sharding as shd
-from repro.distributed.context import cost_exact_mode, sharding_rules
-from repro.models import get_model, init_cache
+from repro.models import get_model
 from repro.models.frontends import frontend_embed_shape, text_len
-from repro.training.loop import make_train_step
 
-__all__ = ["build_cell", "input_specs", "CellSpec", "FSDP_THRESHOLD"]
+__all__ = ["input_specs"]
 
-FSDP_THRESHOLD = 6e9       # params; above this fp32 master+Adam needs ZeRO-3
 PARAM_DTYPE = jnp.float32  # master weights
 COMPUTE_DTYPE = jnp.bfloat16
 
@@ -57,21 +43,9 @@ def input_specs(cfg: ModelConfig, shape: ShapeConfig) -> Dict[str, Any]:
     return out
 
 
-@dataclasses.dataclass
-class CellSpec:
-    """Everything the dry-run needs for one (arch x shape x mesh) cell."""
-    fn: Any                  # the function to jit (already wrapped)
-    args: Tuple[Any, ...]    # ShapeDtypeStruct pytrees
-    in_shardings: Any
-    out_shardings: Any
-    donate: Tuple[int, ...] = ()
-
-
 def _policy(quant: str) -> QuantPolicy:
     return FLOAT if quant == "float" else W3A8
 
-
-# --- templates (eval_shape only — never allocates) -------------------------------
 
 def _params_template(cfg: ModelConfig, quant: str, kind: str):
     mod = get_model(cfg)
@@ -100,177 +74,3 @@ def _state_template(cfg: ModelConfig, tcfg: TrainConfig, quant: str):
         return st
 
     return jax.eval_shape(make, params)
-
-
-def _cache_template(cfg: ModelConfig, shape: ShapeConfig,
-                    kv8: bool = False):
-    if kv8:
-        if cfg.family == "ssm":
-            # no KV cache to quantize — say so instead of silently
-            # building the float state cache under a kv8-labelled cell
-            import warnings
-            warnings.warn(
-                f"kv8 requested for family 'ssm' ({cfg.name}): it has no "
-                "KV cache; building the float state cache", stacklevel=2)
-        else:      # transformer family AND hybrid both serve int8 KV now
-            return jax.eval_shape(
-                lambda: get_model(cfg).init_cache(cfg, shape.global_batch,
-                                                  shape.seq_len,
-                                                  COMPUTE_DTYPE,
-                                                  quantized=True))
-    return jax.eval_shape(
-        lambda: init_cache(cfg, shape.global_batch, shape.seq_len,
-                           COMPUTE_DTYPE))
-
-
-# --- cell builders ------------------------------------------------------------------
-
-def build_cell(cfg: ModelConfig, shape: ShapeConfig, mesh, *,
-               quant: str = "w3", tcfg: Optional[TrainConfig] = None,
-               attn_chunk: int = 1024, num_layers_override: Optional[int] = None,
-               cost_exact: bool = False, fsdp: Optional[bool] = None,
-               ssd_chunk: int = 0, kv8: bool = False) -> CellSpec:
-    if num_layers_override is not None:
-        kw = {"num_layers": num_layers_override}
-        if cfg.attn_every:
-            kw["attn_every"] = min(cfg.attn_every, max(num_layers_override, 1)) \
-                if num_layers_override else cfg.attn_every
-        cfg = dataclasses.replace(cfg, **kw)
-    if shape.kind == "train":
-        cell = _build_train(cfg, shape, mesh, quant, tcfg, attn_chunk, fsdp,
-                            ssd_chunk)
-    elif shape.kind == "prefill":
-        cell = _build_prefill(cfg, shape, mesh, quant, attn_chunk)
-    else:
-        cell = _build_decode(cfg, shape, mesh, quant, kv8)
-    if cost_exact:
-        # trace under cost-exact mode: inner chunk loops unroll so XLA's
-        # body-counted-once cost analysis sees every iteration (dryrun aux)
-        inner = cell.fn
-
-        def exact_fn(*args):
-            with cost_exact_mode():
-                return inner(*args)
-
-        cell = dataclasses.replace(cell, fn=exact_fn) if dataclasses.is_dataclass(cell) else cell
-        cell.fn = exact_fn
-    return cell
-
-
-def _rules_ctx(cfg, shape, mesh):
-    table = shd.activation_rules(cfg, shape, mesh)
-    table["__mesh__"] = mesh
-    return table
-
-
-def _build_train(cfg, shape, mesh, quant, tcfg, attn_chunk,
-                 fsdp: Optional[bool] = None, ssd_chunk: int = 0) -> CellSpec:
-    tcfg = tcfg or TrainConfig(
-        microbatches=_default_microbatches(cfg, shape, mesh))
-    policy = _policy(quant)
-    if fsdp is None:
-        fsdp = cfg.param_count() >= FSDP_THRESHOLD
-    state_t = _state_template(cfg, tcfg, quant)
-    batch_t = input_specs(cfg, shape)
-    state_specs = shd.state_specs(cfg, state_t, mesh, fsdp=fsdp)
-    batch_specs = shd.batch_specs(cfg, shape, mesh, batch_t)
-    rules = _rules_ctx(cfg, shape, mesh)
-
-    mkw = {"attn_chunk": attn_chunk}
-    if cfg.family in ("ssm", "hybrid") and ssd_chunk:
-        mkw["chunk"] = ssd_chunk
-    step_fn, _ = make_train_step(cfg, tcfg, policy, dtype=COMPUTE_DTYPE,
-                                 model_kwargs=mkw)
-
-    def wrapped(state, batch):
-        with sharding_rules(rules):
-            new_state, metrics = step_fn(state, batch)
-        return new_state, metrics
-
-    metric_specs = {k: P() for k in
-                    ("loss", "aux", "acc", "gnorm", "lr")}
-    return CellSpec(
-        fn=wrapped,
-        args=(state_t, batch_t),
-        in_shardings=(shd.tree_shardings(mesh, state_specs),
-                      shd.tree_shardings(mesh, batch_specs)),
-        out_shardings=(shd.tree_shardings(mesh, state_specs),
-                       shd.tree_shardings(mesh, metric_specs)),
-        donate=(0,),
-    )
-
-
-def _default_microbatches(cfg, shape, mesh) -> int:
-    """Keep per-device microbatch activation footprint ~<1GB."""
-    dp = shd.axis_size(mesh, shd.dp_axes(mesh))
-    per_dev_batch = max(shape.global_batch // dp, 1)
-    act_bytes = per_dev_batch * shape.seq_len * cfg.d_model * 2
-    micro = 1
-    while act_bytes / micro > (1 << 30) and micro < per_dev_batch:
-        micro *= 2
-    return micro
-
-
-def _build_prefill(cfg, shape, mesh, quant, attn_chunk) -> CellSpec:
-    policy = _policy(quant)
-    params_t = _params_template(cfg, quant, "prefill")
-    batch_t = input_specs(cfg, shape)
-    pspecs = shd.param_specs(cfg, params_t, mesh)
-    bspecs = shd.batch_specs(cfg, shape, mesh, batch_t)
-    cache_t = jax.eval_shape(
-        lambda p, b: get_model(cfg).prefill(
-            p, b, cfg, policy=policy, dtype=COMPUTE_DTYPE,
-            attn_chunk=attn_chunk, max_len=shape.seq_len)[1],
-        params_t, batch_t)
-    cspecs = shd.cache_specs(cfg, shape, mesh, cache_t)
-    rules = _rules_ctx(cfg, shape, mesh)
-    mod = get_model(cfg)
-
-    def serve_prefill(params, batch):
-        with sharding_rules(rules):
-            logits, cache = mod.prefill(params, batch, cfg, policy=policy,
-                                        dtype=COMPUTE_DTYPE,
-                                        attn_chunk=attn_chunk,
-                                        max_len=shape.seq_len)
-        return logits, cache
-
-    logits_spec = shd.activation_rules(cfg, shape, mesh)["logits"]
-    return CellSpec(
-        fn=serve_prefill,
-        args=(params_t, batch_t),
-        in_shardings=(shd.tree_shardings(mesh, pspecs),
-                      shd.tree_shardings(mesh, bspecs)),
-        out_shardings=(shd.tree_shardings(mesh, logits_spec),
-                       shd.tree_shardings(mesh, cspecs)),
-    )
-
-
-def _build_decode(cfg, shape, mesh, quant, kv8: bool = False) -> CellSpec:
-    policy = _policy(quant)
-    params_t = _params_template(cfg, quant, "decode")
-    batch_t = input_specs(cfg, shape)
-    cache_t = _cache_template(cfg, shape, kv8=kv8)
-    pspecs = shd.param_specs(cfg, params_t, mesh)
-    bspecs = shd.batch_specs(cfg, shape, mesh, batch_t)
-    cspecs = shd.cache_specs(cfg, shape, mesh, cache_t)
-    rules = _rules_ctx(cfg, shape, mesh)
-    mod = get_model(cfg)
-
-    def serve_decode(params, cache, batch):
-        with sharding_rules(rules):
-            logits, cache = mod.decode_step(params, cache, batch["tokens"],
-                                            cfg, policy=policy,
-                                            dtype=COMPUTE_DTYPE)
-        return logits, cache
-
-    logits_spec = shd.activation_rules(cfg, shape, mesh)["logits"]
-    return CellSpec(
-        fn=serve_decode,
-        args=(params_t, cache_t, batch_t),
-        in_shardings=(shd.tree_shardings(mesh, pspecs),
-                      shd.tree_shardings(mesh, cspecs),
-                      shd.tree_shardings(mesh, bspecs)),
-        out_shardings=(shd.tree_shardings(mesh, logits_spec),
-                       shd.tree_shardings(mesh, cspecs)),
-        donate=(1,),
-    )

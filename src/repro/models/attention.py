@@ -120,15 +120,13 @@ def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             "bkgqc,bckd->bkgqd", p.astype(v.dtype), vb).astype(jnp.float32)
         return (m_new, l_new, acc_new), None
 
-    from repro.distributed.context import inner_unroll
     m0 = jnp.full((b, kv, g, lq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, kv, g, lq), jnp.float32)
     a0 = jnp.zeros((b, kv, g, lq, d), jnp.float32)
     starts = jnp.arange(nchunks) * chunk
     (m, l, acc), _ = jax.lax.scan(
         body, (m0, l0, a0),
-        (kc.transpose(1, 0, 2, 3, 4), vc.transpose(1, 0, 2, 3, 4), starts),
-        unroll=True if inner_unroll() else 1)
+        (kc.transpose(1, 0, 2, 3, 4), vc.transpose(1, 0, 2, 3, 4), starts))
     out = acc / jnp.maximum(l[..., None], 1e-30)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, lq, h, d).astype(q.dtype)
 
@@ -170,9 +168,8 @@ def sliding_window_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         p = jax.nn.softmax(sc, axis=-1).astype(v.dtype)
         return _gqa_out(p, vb).reshape(b, chunk, h, d)
 
-    from repro.distributed.context import inner_unroll
-    _, out = jax.lax.scan(lambda c, i: (c, q_block(i)), None, jnp.arange(nq),
-                          unroll=True if inner_unroll() else 1)  # (nq,B,chunk,H,D)
+    _, out = jax.lax.scan(lambda c, i: (c, q_block(i)), None,
+                          jnp.arange(nq))  # (nq,B,chunk,H,D)
     out = out.transpose(1, 0, 2, 3, 4).reshape(b, nq * chunk, h, d)
     return out[:, :l].astype(q.dtype)
 

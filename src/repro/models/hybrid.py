@@ -74,8 +74,6 @@ def init(key, cfg: ModelConfig, dtype=jnp.float32) -> Dict[str, Any]:
 
 def _mamba_scan(stack, dstack, h, cfg, policy, chunk, remat: str,
                 return_state: bool = False, lengths=None, mm: str = "auto"):
-    from repro.distributed.context import inner_unroll
-
     def body(hh, xs):
         lp, ld = xs
         if return_state:
@@ -89,10 +87,7 @@ def _mamba_scan(stack, dstack, h, cfg, policy, chunk, remat: str,
 
     if remat != "none":
         body = jax.checkpoint(body, prevent_cse=False)
-    # cost-exact mode unrolls: this is the INNER loop of the hybrid group
-    # scan — the L0/G1/A1 decomposition needs its body counted A times
-    return jax.lax.scan(body, h, (stack, dstack),
-                        unroll=True if inner_unroll() else 1)
+    return jax.lax.scan(body, h, (stack, dstack))
 
 
 def forward(params, batch, cfg: ModelConfig, *, policy: QuantPolicy,

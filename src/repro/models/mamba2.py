@@ -185,10 +185,8 @@ def _ssd_chunked(x, b_mat, c_mat, dt, a_log, chunk: int, bf16: bool = False):
             xc.astype(jnp.float32))
         return new_state, y_intra + y_inter
 
-    from repro.distributed.context import inner_unroll
     s0 = jnp.zeros((bsz, h, p, n), jnp.float32)
-    s_final, ys = jax.lax.scan(body, s0, xs,
-                               unroll=True if inner_unroll() else 1)
+    s_final, ys = jax.lax.scan(body, s0, xs)
     y = ys.transpose(1, 0, 2, 3, 4).reshape(bsz, nchunks * q, h, p)
     return y[:, :l], s_final
 

@@ -1,6 +1,7 @@
 """Per-assigned-architecture smoke tests (deliverable f): a REDUCED config of
 the same family runs one forward + one train step on CPU, asserting output
-shapes and no NaNs. The FULL configs are exercised only via the dry-run."""
+shapes and no NaNs. The FULL configs are checked shape-only, in
+tests/test_sharding.py."""
 import jax
 import jax.flatten_util
 import jax.numpy as jnp

@@ -35,15 +35,22 @@ def _quantized(x):
 
 
 @pytest.mark.parametrize("h,kv", [(8, 8), (8, 2), (4, 1)])
-@pytest.mark.parametrize("bm,bs", [(8, 128), (2, 32), (3, 17)])
-def test_kernel_matches_ref_and_einsum(h, kv, bm, bs):
+@pytest.mark.parametrize("b,s,bm,bs", [
+    pytest.param(5, 100, 8, 128, id="8-128"),
+    pytest.param(5, 100, 2, 32, id="2-32"),
+    pytest.param(5, 100, 3, 17, id="3-17"),
+    pytest.param(8, 96, None, None, id="slots8-S96")])
+def test_kernel_matches_ref_and_einsum(h, kv, b, s, bm, bs):
     """Mixed per-row lengths (incl. 1 and full): kernel == ref == einsum.
     bm/bs sweep covers B and S not divisible by the requested block sizes
-    (the kernel blocks by divisors: the cache is read in place)."""
-    b, s, d = 5, 100, 16
+    (the kernel blocks by divisors: the cache is read in place); the last
+    case is an 8-slot tick at the kernel's own block sizes."""
+    d = 16
     q, kc, vc = _case(0, b, s, h, kv, d)
-    lens = jnp.asarray([1, 7, 64, 100, 33], jnp.int32)
-    out = attn_decode(q, _stored(kc), _stored(vc), lens, bm=bm, bs=bs,
+    lens = jnp.minimum(jnp.asarray([1, 7, 64, 100, 33, 96, 50, 12][:b],
+                                   jnp.int32), s)
+    blocks = {} if bm is None else {"bm": bm, "bs": bs}
+    out = attn_decode(q, _stored(kc), _stored(vc), lens, **blocks,
                       interpret=True)
     ref = attn_decode_ref(q, kc, vc, lens)
     ein = decode_attention(q, _stored(kc), _stored(vc), lens, mode="ref")

@@ -66,23 +66,31 @@ def _prefill_hi(lens, t):
 
 # --- kernel vs oracle: blocking edge cases ----------------------------------------
 
-@pytest.mark.parametrize("h,kv", [(8, 2), (4, 4), (4, 1)])
-@pytest.mark.parametrize("bt,bs", [(16, 32), (8, 24), (7, 13), (128, 128)])
-def test_kernel_matches_oracle_blocking(h, kv, bt, bs):
+@pytest.mark.parametrize("h,kv", [(8, 2), (4, 4), (4, 1), (4, 2)])
+@pytest.mark.parametrize("b,t,bt,bs", [
+    pytest.param(3, 50, 16, 32, id="16-32"),
+    pytest.param(3, 50, 8, 24, id="8-24"),
+    pytest.param(3, 50, 7, 13, id="7-13"),
+    pytest.param(3, 50, 128, 128, id="128-128"),
+    pytest.param(2, 24, None, None, id="B2-T24")])
+def test_kernel_matches_oracle_blocking(h, kv, b, t, bt, bs):
     """Mixed per-row lengths (incl. 1 and full) under the bucketed-prefill
     rule; bt/bs sweep covers T and S not divisible by the requested block
-    sizes (queries are padded, keys blocked by a divisor of S)."""
-    b, t, d = 3, 50, 16
+    sizes (queries are padded, keys blocked by a divisor of S); the last
+    case is a two-row admission at the kernel's own block sizes."""
+    d = 16
     q, k, v = _case(0, b, t, t, h, kv, d)
-    hi = _prefill_hi([50, 17, 1], t)
-    out = attn_prefill(q, _stored(k), _stored(v), hi, bt=bt, bs=bs,
+    lens = [t, 17, 1][:b]
+    hi = _prefill_hi(lens, t)
+    blocks = {} if bt is None else {"bt": bt, "bs": bs}
+    out = attn_prefill(q, _stored(k), _stored(v), hi, **blocks,
                        interpret=True)
     ref = _oracle(q, k, v, hi)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6)
     # every REAL query position also matches the production chunked path
     # (causal-only masking: j <= t < len already implies j < len there)
     chunked = chunked_attention(q, k, v, causal=True, chunk=32)
-    for row, ln in enumerate([50, 17, 1]):
+    for row, ln in enumerate(lens):
         np.testing.assert_allclose(np.asarray(out[row, :ln]),
                                    np.asarray(chunked[row, :ln]), atol=2e-5)
 
@@ -161,13 +169,18 @@ def test_prefill_attention_dispatch():
     np.testing.assert_allclose(np.asarray(sw_k), np.asarray(sw_r), atol=2e-5)
 
 
-def test_verify_attention_dispatch():
+@pytest.mark.parametrize("s,starts,h", [
+    pytest.param(50, (4, 0, 47), 8, id="B3-S50"),
+    pytest.param(48, (0, 12, 24, 45), 4, id="B4-S48")])
+def test_verify_attention_dispatch(s, starts, h):
     """verify_attention(mode='kernel') — the T-row specialization over the
-    live cache — matches the guarded-einsum ref, float and int8 cache."""
-    b, t, s = 3, 3, 50
-    q, _, _ = _case(6, b, t, s, 8, 2, 16)
-    _, kc, vc = _case(7, b, t, s, 8, 2, 16)
-    valid = jnp.asarray([[5, 6, 7], [1, 2, 3], [48, 49, 50]], jnp.int32)
+    live cache — matches the guarded-einsum ref, float and int8 cache.
+    Row i verifies T=3 drafts at positions starts[i] .. starts[i] + 2."""
+    b, t = len(starts), 3
+    q, _, _ = _case(6, b, t, s, h, 2, 16)
+    _, kc, vc = _case(7, b, t, s, h, 2, 16)
+    valid = (jnp.asarray(starts, jnp.int32)[:, None]
+             + jnp.arange(1, t + 1, dtype=jnp.int32))
     kc, vc = _stored(kc), _stored(vc)
     out_k = verify_attention(q, kc, vc, valid, mode="kernel", interpret=True)
     out_r = verify_attention(q, kc, vc, valid, mode="ref")

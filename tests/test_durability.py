@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+import _serving_mix as mix
 from repro.configs import get_config, reduced
 from repro.core import quant_dense
 from repro.core.precision import FLOAT, W3A8
@@ -174,28 +175,61 @@ def test_restore_refuses_old_kv_layout(tmp_path):
 
 # --- crash + recovery ---------------------------------------------------------
 
-@pytest.mark.parametrize("crash_at", [1, 4, 9])
-def test_crash_recovery_loses_nothing(tmp_path, crash_at):
+def _crash_cases():
+    # the toy workload on 3 slots with a snapshot every 3 ticks, killed at
+    # three ticks (ids kept as the crash tick)
+    for crash_at in (1, 4, 9):
+        yield pytest.param("toy", "qp", 3, crash_at, id=str(crash_at))
+    # 12 mixed-length requests at the reduced serving shape with a snapshot
+    # every 8 ticks, killed about mid-run (crash tick chosen in the test)
+    for form in ("qp", "w"):
+        for slots in (2, 4):
+            yield pytest.param("mixed", form, slots, None,
+                               id=f"mixed-{form}-{slots}")
+
+
+@pytest.mark.parametrize("workload,form,slots,crash_at", _crash_cases())
+def test_crash_recovery_loses_nothing(tmp_path, workload, form, slots,
+                                      crash_at):
     """Kill the engine at an arbitrary tick; recover a FRESH engine from
     the latest snapshot + journal tail. Every accepted request appears in
     the union of pre-crash drains and the recovered run, token-identical
-    to an uncrashed run at T=0 — zero accepted-token loss."""
-    cfg, params, policy = _setup()
-    ref = _reference(params, cfg, policy)
+    to an uncrashed run at T=0 — zero accepted-token loss. A crash past
+    the first periodic snapshot restores from it."""
+    if workload == "toy":
+        cfg, params, policy = _setup(form=form)
+        work, max_len, every = list(zip(PROMPTS, MAX_NEW)), 64, 3
+    else:
+        cfg, params, policy = mix.model(form)
+        max_new = 12
+        work = [(p, max_new) for p in mix.prompts(12)]
+        max_len, every = max(mix.LENGTHS) + max_new + 1, 8
+        crash_at = max(every + 1, len(work) * max_new // (2 * slots))
+
+    def run(**kw):
+        eng = _engine(params, cfg, policy, slots=slots, max_len=max_len,
+                      **kw)
+        for p, m in work:
+            eng.submit(list(p), max_new=m)
+        return eng
+
+    ref = _outputs(run().run_all(max_ticks=400))
 
     snaps, jpath = str(tmp_path / "snaps"), str(tmp_path / "wal.jsonl")
-    eng = _engine(params, cfg, policy, snapshot_dir=snaps, snapshot_every=3,
-                  journal=jpath, fault_plan=FaultPlan(crash_at_tick=crash_at))
-    _submit_all(eng)
+    eng = run(snapshot_dir=snaps, snapshot_every=every, journal=jpath,
+              fault_plan=FaultPlan(crash_at_tick=crash_at))
     delivered = {}
     with pytest.raises(InjectedCrash):
         while eng.queue or eng._occupied():
             eng.step()
             delivered.update(_outputs(eng.drain()))
 
-    fresh = _engine(params, cfg, policy, snapshot_dir=snaps, journal=jpath)
+    fresh = _engine(params, cfg, policy, slots=slots, max_len=max_len,
+                    snapshot_dir=snaps, journal=jpath)
     stats = fresh.recover()
     assert stats["replayed_events"] >= 0
+    if crash_at > every:
+        assert stats["restored_step"] is not None, "no snapshot restored"
     recovered = _outputs(fresh.run_all(max_ticks=400))
 
     merged = {**delivered, **recovered}

@@ -111,6 +111,19 @@ def test_kv8_halves_engine_cache_bytes(family):
     assert nbytes(e8) < nbytes(e16) * 0.6, (nbytes(e8), nbytes(e16))
 
 
+@pytest.mark.parametrize("family", ["dense", "hybrid"])
+def test_init_cache_kv8_is_int8_with_scales(family):
+    """``api.init_cache(kv_bits=8)`` stores K/V as int8 with one fp32 scale
+    per token, for the transformer family and for hybrid's shared KV."""
+    cfg, _, _ = _setup(family)
+    cache = model_api.init_cache(cfg, 2, 16, jnp.float32, kv_bits=8)
+    kv = cache["kv"] if family == "hybrid" else cache
+    for name in ("k", "v"):
+        assert kv[name].dtype == jnp.int8
+        assert kv[f"{name}_scale"].dtype == jnp.float32
+        assert kv[f"{name}_scale"].shape == kv[name].shape[:-1]
+
+
 def test_kv8_rejected_for_ssm():
     """No silent downgrade: a family without a KV cache must refuse
     kv_bits=8 loudly (engine AND the shared init_cache helper)."""

@@ -11,10 +11,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _serving_mix as mix
 from repro.analysis import check_no_host_callback, retrace_report
 from repro.configs import get_config, reduced
 from repro.core import quant_dense
 from repro.core.precision import FLOAT, W3A8
+from repro.models import api as model_api
 from repro.models import get_model
 from repro.serving.engine import ServingEngine, generate
 
@@ -164,6 +166,27 @@ def test_spec_request_stats():
     r0 = eng0.run_all()[0]
     assert r0.accept_hist == {1: 3} and r0.ticks == 3
     assert eng0.spec_accept_rate == 0.0
+
+
+@pytest.mark.parametrize("slots", [4, 8])
+def test_spec_commits_more_than_one_token_per_tick(slots):
+    """A w-form verifier with the packed 3-bit drafter of the same
+    checkpoint (``api.draft_of``) at spec_k 4, on mixed-length traffic at
+    the reduced serving shape, commits more than one token per slot-tick;
+    otherwise drafting is pure overhead."""
+    cfg, params, policy = mix.model("w")
+    draft_cfg, draft_params = model_api.draft_of(cfg, params, policy=W3)
+    spec_k, max_new = 4, 24
+    eng = ServingEngine(params, cfg, policy=policy, slots=slots,
+                        max_len=max(mix.LENGTHS) + max_new + 1 + spec_k,
+                        dtype=jnp.float32, spec_k=spec_k,
+                        draft_params=draft_params, draft_cfg=draft_cfg)
+    for p in mix.prompts(16):
+        eng.submit(p, max_new=max_new)
+    done = eng.run_all()
+    # each request's first token is sampled at admission, not by a tick
+    committed = sum(len(r.out) - 1 for r in done)
+    assert committed / sum(r.ticks for r in done) > 1
 
 
 def test_generate_spec_matches_generate(capsys):

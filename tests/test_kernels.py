@@ -23,8 +23,10 @@ def _rand(key, shape, dtype):
 
 
 class TestQMatmul:
+    # the last two: an 8-slot decode tick and 8 slots x a 16-token prefill
     @pytest.mark.parametrize("m,k,n", [(8, 32, 16), (128, 128, 128),
-                                       (100, 1022, 10), (257, 513, 129)])
+                                       (100, 1022, 10), (257, 513, 129),
+                                       (8, 96, 128), (128, 96, 128)])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_vs_ref(self, m, k, n, dtype):
         kx, kw, kd = jax.random.split(jax.random.PRNGKey(0), 3)
@@ -64,7 +66,8 @@ class TestQMatmul:
 class TestQMatvec:
     # (M, K, N, bias, out_dtype): decode (M 1, 16) and bucketed prefill
     # (16 x 64) rows; KP 154 in one K block (K 1536), partial last blocks
-    # (K 1537, 2570, 3001), K not a multiple of 10; N 256 and 8960
+    # (K 1537, 2570, 3001), K not a multiple of 10; N 256 and 8960; an
+    # 8-slot decode tick and 8 slots x a 16-token prefill at K 96
     @pytest.mark.parametrize("m,k,n,bias,out_dtype", [
         pytest.param(1, 1022, 1022, False, None, id="1-1022-1022"),
         pytest.param(8, 100, 64, False, None, id="8-100-64"),
@@ -73,7 +76,8 @@ class TestQMatvec:
         (16, 1537, 256, False, jnp.bfloat16), (1024, 1536, 256, True, None),
         (16, 2570, 256, True, None), (1, 3001, 256, False, None),
         (16, 8960, 256, True, jnp.float32), (1, 8960, 8960, False, None),
-        (16, 95, 8960, False, None)])
+        (16, 95, 8960, False, None), (8, 96, 128, True, None),
+        (128, 96, 128, True, None)])
     def test_vs_ref(self, m, k, n, bias, out_dtype):
         ks = jax.random.split(jax.random.PRNGKey(0), 4)
         x = _rand(ks[0], (m, k), jnp.float32)

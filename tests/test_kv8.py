@@ -3,12 +3,10 @@ within quantization tolerance; scales factor exactly through attention —
 for the transformer family AND hybrid, through the sliding-window ring, and
 through the launch-layer kv8 cache templates."""
 import dataclasses
-import warnings
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.configs import get_config, reduced
 from repro.core.precision import FLOAT
@@ -172,36 +170,3 @@ def test_kv8_ring_masking_parity_kernel_vs_ref():
     ein = decode_attention(q, kq, vq, lens, ksc, vsc, mode="ref")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ein), atol=2e-5)
-
-
-# --- launch-layer kv8 templates (launch/steps.py) ----------------------------------
-
-
-def _decode_shape():
-    from repro.configs.base import ShapeConfig
-    return ShapeConfig("dec", 16, 2, "decode")
-
-
-def test_steps_kv8_hybrid_template_is_quantized():
-    """Regression: hybrid kv8 used to silently fall through to the bf16
-    cache; now the decode cell template carries the int8 KV form."""
-    from repro.launch import steps
-
-    cfg = reduced(get_config("zamba2-1.2b"), layers=4)
-    t = steps._cache_template(cfg, _decode_shape(), kv8=True)
-    assert t["kv"]["k"].dtype == jnp.int8
-    assert "k_scale" in t["kv"] and "v_scale" in t["kv"]
-
-
-def test_steps_kv8_ssm_warns_instead_of_silent_downgrade():
-    from repro.launch import steps
-
-    cfg = reduced(get_config("mamba2-2.7b"), layers=2)
-    with pytest.warns(UserWarning, match="KV cache"):
-        t = steps._cache_template(cfg, _decode_shape(), kv8=True)
-    assert "kv" not in t                              # plain ssm state
-
-    # non-kv8 path stays warning-free for every family
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        steps._cache_template(cfg, _decode_shape(), kv8=False)

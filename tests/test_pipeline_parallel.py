@@ -1,8 +1,7 @@
 """Pipeline parallelism == sequential stage application.
 
 Runs in a SUBPROCESS with forced host devices so the main pytest process
-keeps the mandated single-device view (dryrun.py is the only in-repo place
-allowed to set XLA_FLAGS globally)."""
+keeps the mandated single-device view."""
 import subprocess
 import sys
 

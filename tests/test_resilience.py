@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _serving_mix as mix
 from repro.configs import get_config, reduced
 from repro.core import quant_dense
 from repro.core.precision import FLOAT, W3A8
@@ -391,4 +392,39 @@ def test_chaos_smoke_completes():
     assert len(done) == sum(1 for o in outs if o.accepted)
     assert all(r.status in ("ok", "deadline", "shed", "poisoned")
                for r in done)
+    assert any(r.status == "ok" for r in done)
+
+
+# --- overload at the reduced serving shape -----------------------------------
+
+@pytest.mark.parametrize("slots", [2, 4])
+@pytest.mark.parametrize("form", ["qp", "w"])
+def test_overload_never_deadlocks(form, slots):
+    """Waves of 2 x slots mixed-length requests every 4 ticks, about twice
+    what the slots serve, into a queue bounded at 2 x slots that rejects
+    the rest; deadlines cycle none / loose / tight and fair-share
+    preemption is on. The run drains without the watchdog firing (run_all
+    raises WatchdogExpired), sheds less than everything and finishes some
+    requests."""
+    cfg, params, policy = mix.model(form)
+    max_new, wave = 12, 2 * slots
+    eng = ServingEngine(params, cfg, policy=policy, slots=slots,
+                        max_len=max(mix.LENGTHS) + max_new + 1,
+                        dtype=jnp.float32, queue_limit=wave,
+                        shed_policy="reject",
+                        preempt_after=max(2, max_new // 4), max_ticks=4096)
+    deadlines = [None, 4 * max_new, max_new // 2]
+    prompts = mix.prompts(24)
+    outcomes, done = [], []
+    for i in range(0, len(prompts), wave):
+        for j, p in enumerate(prompts[i:i + wave]):
+            outcomes.append(eng.submit(
+                p, max_new=max_new,
+                deadline_ticks=deadlines[(i + j) % len(deadlines)]))
+        for _ in range(4):
+            eng.step()
+        done.extend(eng.drain())
+    done.extend(eng.run_all())
+    assert eng.shed_count < len(outcomes)
+    assert len(done) == sum(1 for o in outcomes if o.accepted)
     assert any(r.status == "ok" for r in done)

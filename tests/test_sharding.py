@@ -1,6 +1,5 @@
 """Sharding rules: divisibility guards, spec validity on the production mesh
-shapes (pure spec-level checks — no 512-device init in the test process; the
-real lowering proof lives in the dry-run)."""
+shapes (pure spec-level checks — no 512-device init in the test process)."""
 import pytest
 
 from repro.configs import ARCH_IDS, LM_SHAPES, get_config
